@@ -88,7 +88,7 @@ def _upload(arr: np.ndarray, want: np.dtype) -> jax.Array:
     """Host array -> device array of dtype ``want``, transferring the
     narrowest integer representation that holds the values and widening
     on device. Host->device bandwidth is the cold-query bottleneck
-    (PCIe on a co-located host, far worse through a tunnel); TPC-H
+    (PCIe on a co-located host); TPC-H
     integer/decimal columns typically fit 1-2 bytes, so this cuts wire
     bytes ~3-4x for the cost of one fused device cast."""
     ladder = _NARROW_LADDER.get(arr.dtype)

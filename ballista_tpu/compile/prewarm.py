@@ -1,8 +1,7 @@
 """Optional prewarm pass: start compiling while the scan parses.
 
 The cold path serializes parse -> host-to-device upload -> first compile
-(BENCH_r05: parse 1.43s + h2d 1.08s sit entirely before the first XLA
-compile). With the bucket ladder, the capacity a scan will emit is
+(parse and H2D sit entirely before the first XLA compile). With the bucket ladder, the capacity a scan will emit is
 predictable from its estimated row count BEFORE any byte is parsed — so
 a background thread can AOT-compile the scan-side fused pipeline chains
 at the predicted rung concurrently with parse/H2D.

@@ -69,3 +69,34 @@ def layered_config(
     })
     apply(cli or {})
     return out
+
+
+def install_stop_signals():
+    """Catch SIGINT/SIGTERM for a launcher's main thread; returns
+    ``wait() -> signum`` that blocks until one arrives.
+
+    A handler, not mask + ``sigwait``: ``import jax`` starts threads
+    while the package is imported, before ``main()`` can mask anything,
+    and a signal the kernel hands to one of those unmasked threads took
+    the default disposition (exit -15) — the graceful drain never ran.
+    A handler is process-wide, so it does not matter which thread the
+    signal lands on. Call from the main thread, first thing in
+    ``main()``."""
+    import signal
+    import threading
+
+    got = []
+    arrived = threading.Event()
+
+    def on_signal(signum, _frame):
+        got.append(signum)
+        arrived.set()
+
+    for sig in (signal.SIGINT, signal.SIGTERM):
+        signal.signal(sig, on_signal)
+
+    def wait() -> int:
+        arrived.wait()
+        return got[0]
+
+    return wait

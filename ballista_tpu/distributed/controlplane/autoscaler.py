@@ -283,7 +283,13 @@ class SubprocessExecutorLauncher:
     (``python -m ballista_tpu.distributed.executor_main``). Spawned
     processes inherit the environment plus any overrides; drain sends
     SIGTERM — executor_main's graceful-drain signal — to the youngest
-    live child (LIFO keeps the launch-time fleet stable)."""
+    live child (LIFO keeps the launch-time fleet stable).
+
+    A chip belongs to one process at a time: on an accelerator host a
+    child beyond the first cannot initialise the chip its sibling holds,
+    so this launcher is for CPU fleets (``env={"JAX_PLATFORMS": "cpu"}``)
+    or for children each pinned to a chip of their own through ``env``.
+    The scheduler process itself never initialises a JAX backend."""
 
     def __init__(self, scheduler_host: str, scheduler_port: int,
                  extra_args: Optional[List[str]] = None,

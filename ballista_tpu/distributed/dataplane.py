@@ -439,13 +439,11 @@ def _native_server_bin() -> Optional[str]:
         "native",
     )
     bin_path = os.path.join(native_dir, "shuffle_server")
-    if os.path.exists(bin_path):
-        return bin_path
     from ..io import native as native_scan
 
-    if native_scan._try_build() and os.path.exists(bin_path):
-        return bin_path
-    return None
+    if native_scan.stale(bin_path):
+        native_scan._try_build()
+    return bin_path if os.path.exists(bin_path) else None
 
 
 def native_dataplane_enabled(value: Optional[str] = None) -> bool:

@@ -13,7 +13,7 @@ import logging
 import signal
 import sys
 
-from .config import layered_config
+from .config import install_stop_signals, layered_config
 
 DEFAULTS = {
     "namespace": "default",
@@ -41,13 +41,7 @@ DEFAULTS = {
 
 
 def main(argv=None) -> int:
-    # sigwait below only receives a signal that is BLOCKED; without
-    # this mask SIGTERM takes the default disposition (immediate kill)
-    # and the graceful-drain path (PR 9) never runs on the real binary.
-    # Masked first thing so every thread the executor spawns inherits
-    # the block and only the main thread's sigwait consumes the signal.
-    signal.pthread_sigmask(signal.SIG_BLOCK,
-                           {signal.SIGINT, signal.SIGTERM})
+    wait_for_stop = install_stop_signals()
     ap = argparse.ArgumentParser(description="ballista-tpu executor")
     ap.add_argument("--config-file", default=None)
     ap.add_argument("--local", action="store_true",
@@ -148,7 +142,7 @@ def main(argv=None) -> int:
     if executor.health_port is not None:
         print(f"ballista-tpu executor health plane on "
               f"127.0.0.1:{executor.health_port}", flush=True)
-    stop = signal.sigwait([signal.SIGINT, signal.SIGTERM])
+    stop = wait_for_stop()
     drain = stop == signal.SIGTERM
     print(f"signal {stop}; shutting down"
           + (" (graceful drain)" if drain else ""), flush=True)

@@ -12,7 +12,7 @@ import logging
 import signal
 import sys
 
-from .config import layered_config
+from .config import install_stop_signals, layered_config
 
 DEFAULTS = {
     "namespace": "default",
@@ -36,13 +36,7 @@ DEFAULTS = {
 
 
 def main(argv=None) -> int:
-    # sigwait below only receives a signal that is BLOCKED; without
-    # this mask SIGTERM takes the default disposition (immediate kill)
-    # and the graceful-drain path never runs. Masked first thing so
-    # every thread the server spawns inherits the block and the signal
-    # can only be consumed by the main thread's sigwait.
-    signal.pthread_sigmask(signal.SIG_BLOCK,
-                           {signal.SIGINT, signal.SIGTERM})
+    wait_for_stop = install_stop_signals()
     ap = argparse.ArgumentParser(description="ballista-tpu scheduler")
     ap.add_argument("--config-file", default=None)
     for key in DEFAULTS:
@@ -158,7 +152,7 @@ def main(argv=None) -> int:
         )
         print(f"ballista-tpu Arrow Flight SQL endpoint on "
               f"{cfg['bind_host']}:{fport}", flush=True)
-    stop = signal.sigwait([signal.SIGINT, signal.SIGTERM])
+    stop = wait_for_stop()
     if stop == signal.SIGTERM:
         # graceful degradation (admission ladder's last rung): shed NEW
         # submissions while admitted work finishes, bounded by the same

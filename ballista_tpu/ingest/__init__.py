@@ -43,6 +43,7 @@ from .phases import (  # noqa: F401
     PhaseRecorder,
     bound_iter,
     phase,
+    phase_bytes,
     phase_totals,
     reset_phase_totals,
 )

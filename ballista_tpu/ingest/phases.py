@@ -30,6 +30,7 @@ from ..observability.tracing import trace_span
 _tls = threading.local()
 _totals_lock = threading.Lock()
 _totals: Dict[str, float] = {}
+_byte_totals: Dict[str, int] = {}
 
 
 class PhaseRecorder:
@@ -72,7 +73,9 @@ def bind(recorder: Optional[PhaseRecorder]):
 @contextmanager
 def phase(name: str, **attrs):
     """Time a parse/H2D block (see module docstring). Reentrant same-name
-    blocks are transparent — only the outermost records."""
+    blocks are transparent — only the outermost records. A ``bytes=``
+    attr also accumulates into :func:`phase_bytes` (host bytes handed to
+    the block, e.g. the numpy columns one H2D upload starts from)."""
     active = getattr(_tls, "active", None)
     if active is None:
         active = _tls.active = set()
@@ -91,6 +94,9 @@ def phase(name: str, **attrs):
         span.__exit__(None, None, None)
         with _totals_lock:
             _totals[name] = _totals.get(name, 0.0) + dt
+            if "bytes" in attrs:
+                _byte_totals[name] = (_byte_totals.get(name, 0)
+                                      + int(attrs["bytes"]))
         rec = getattr(_tls, "recorder", None)
         if rec is not None:
             rec.record(name, dt)
@@ -118,6 +124,15 @@ def phase_totals() -> Dict[str, float]:
     return out
 
 
+def phase_bytes() -> Dict[str, int]:
+    """Process-wide cumulative bytes per phase, for the blocks that
+    reported them (kept apart from :func:`phase_totals`, whose values
+    are all seconds)."""
+    with _totals_lock:
+        return dict(_byte_totals)
+
+
 def reset_phase_totals() -> None:
     with _totals_lock:
         _totals.clear()
+        _byte_totals.clear()

@@ -36,20 +36,37 @@ _KIND_CODES = {
 }
 
 
-def _try_build() -> bool:
-    """Build the shared library on first use if a toolchain is present.
+def stale(target: str) -> bool:
+    """True when a native binary is missing or older than what it is built
+    from. The binaries are git-ignored yet lie on disk, so a copy of the
+    tree can carry one built from older sources (or for another host);
+    a clean checkout has none."""
+    native_dir = os.path.dirname(_LIB_PATH)
+    src = "tblscan.cpp" if target == _LIB_PATH else "shuffle_server.cpp"
+    try:
+        built = os.path.getmtime(target)
+        return any(os.path.getmtime(os.path.join(native_dir, dep)) > built
+                   for dep in (src, "Makefile"))
+    except OSError:
+        return True
 
-    The .so is not checked in, so a fresh checkout (or the driver's bench
-    run) would otherwise silently fall back to the pandas reader and
-    report a parse-bound cold path. Cross-PROCESS builds (several
-    executors sharing a checkout) serialize on an flock'd lock file so
-    one g++ never rewrites the .so another process is dlopen()ing."""
+
+def _try_build() -> None:
+    """Run ``make`` in the native directory if a toolchain is present
+    (make itself skips targets that are up to date).
+
+    The binaries are not checked in, so a fresh checkout (or the
+    driver's bench run) would otherwise silently fall back to the pandas
+    reader and report a parse-bound cold path. Cross-PROCESS builds
+    (several executors sharing a checkout) serialize on an flock'd lock
+    file so one g++ never rewrites the .so another process is
+    dlopen()ing."""
     import shutil
     import subprocess
     import sys
 
     if shutil.which("make") is None or shutil.which("g++") is None:
-        return False
+        return
     native_dir = os.path.dirname(_LIB_PATH)
     lockfile = os.path.join(native_dir, ".buildlock")
     try:
@@ -58,19 +75,16 @@ def _try_build() -> bool:
         with open(lockfile, "w") as lf:
             fcntl.flock(lf, fcntl.LOCK_EX)
             try:
-                if os.path.exists(_LIB_PATH):  # another process built it
-                    return True
-                print("ballista_tpu: building native scanner "
+                print("ballista_tpu: building native components "
                       f"({native_dir})...", file=sys.stderr)
                 subprocess.run(
                     ["make", "-C", native_dir],
-                    capture_output=True, timeout=120, check=True,
+                    capture_output=True, timeout=300, check=True,
                 )
             finally:
                 fcntl.flock(lf, fcntl.LOCK_UN)
-    except Exception:  # noqa: BLE001 - build is best-effort
-        return False
-    return os.path.exists(_LIB_PATH)
+    except Exception:  # noqa: BLE001 - build is best-effort; callers
+        pass           # check for the binary they need
 
 
 def _load():
@@ -78,7 +92,9 @@ def _load():
     with _lib_lock:
         if _lib is not None:
             return _lib
-        if not os.path.exists(_LIB_PATH) and not _try_build():
+        if stale(_LIB_PATH):
+            _try_build()
+        if not os.path.exists(_LIB_PATH):
             return None
         lib = ctypes.CDLL(_LIB_PATH)
         lib.tbl_open.restype = ctypes.c_void_p

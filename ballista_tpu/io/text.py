@@ -476,7 +476,8 @@ class DelimitedSource(TableSource):
                 {k: v[start:end] for k, v in valids.items()}
                 if valids else None
             )
-            with phase("h2d", rows=end - start):
+            with phase("h2d", rows=end - start,
+                       bytes=sum(v.nbytes for v in chunk.values())):
                 batch = ColumnBatch.from_numpy(sub_schema, chunk, dicts,
                                                capacity=cap, validity=vchunk)
             yield batch

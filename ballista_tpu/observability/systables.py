@@ -73,9 +73,6 @@ KNOBS: Dict[str, tuple] = {
                                        "(jax.export) into this directory"),
     "BALLISTA_PREWARM": ("off", "AOT-compile fused stages concurrently "
                                 "with parse/H2D"),
-    "BALLISTA_XLA_CACHE": ("~/.cache/ballista-tpu-xla-<cpu-tag>",
-                           "persistent XLA compilation cache dir "
-                           "(empty = disabled)"),
     "BALLISTA_XLA_CACHE_MIN_COMPILE_SECS": ("0", "only disk-cache kernels "
                                                  "compiling at least this "
                                                  "long"),

@@ -24,10 +24,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax import lax
 
-try:  # jax >= 0.4.35 exports shard_map at top level
-    from jax import shard_map
-except ImportError:  # older jax: experimental location
-    from jax.experimental.shard_map import shard_map  # type: ignore
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..columnar import Column, ColumnBatch

@@ -481,9 +481,8 @@ def concat_batches(schema: Schema, batches: List[ColumnBatch]) -> ColumnBatch:
     return out
 
 
-# Measured cost of a blocking scalar device->host read (seconds). When the
-# accelerator is remote (e.g. tunneled), one sync costs a network
-# round-trip — far more than speculative compaction ever saves — so
+# Measured cost of a blocking scalar device->host read (seconds). Where
+# one sync costs more than speculative compaction ever saves,
 # maybe_compact only pays for a sync while syncs are known to be cheap.
 _SYNC_COST: List[float] = []
 _SYNC_COST_LIMIT = 0.005

@@ -157,7 +157,7 @@ class MeshAggExec(PhysicalPlan):
         """(stacked batch pytree) -> (stacked out batch, num_groups[n])."""
         from functools import partial
 
-        from ..parallel.mesh import shard_map  # version-guarded import
+        from jax import shard_map
 
         from ..compile import governed
         from .mesh_input import _MESH_NS_CAP
@@ -303,7 +303,7 @@ class MeshJoinExec(PhysicalPlan):
         from functools import partial as fpartial
 
         from ..kernels import join as join_k
-        from ..parallel.mesh import shard_map
+        from jax import shard_map
 
         def build():
             # whole closure construction deferred: on a governed cache hit

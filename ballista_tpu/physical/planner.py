@@ -187,8 +187,8 @@ def _create(plan: LogicalPlan, opts: PlannerOptions) -> PhysicalPlan:
         partitionable = (not plan.null_aware and threshold is not None
                          and how != "full")
         # Inner joins are symmetric and the projection below restores
-        # column order, so orient by cost (measured on TPC-H, see
-        # benchmarks/RESULTS.md). Co-partitioned mode: build the LARGER
+        # column order, so orient by cost (measured on TPC-H on the CPU
+        # backend). Co-partitioned mode: build the LARGER
         # side — output capacities ride the probe side, so probing the
         # small side keeps every downstream shape small. Merged mode:
         # build the SMALLER side — the build is concatenated and tabled

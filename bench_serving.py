@@ -488,6 +488,14 @@ def main() -> int:
     result["table_cache_hits"] = int(cc["table_cache_hits"])
     result["result_cache_hits"] = int(cc["result_cache_hits"])
     result["donated_buffers"] = int(cc["donated_buffers"])
+    # every line names the device it ran on: a CPU run is never read as
+    # a device measurement
+    import jax
+
+    devices = jax.devices()
+    result["platform"] = devices[0].platform
+    result["device_kind"] = devices[0].device_kind
+    result["device_count"] = len(devices)
     print(json.dumps(result), flush=True)
     return 0
 

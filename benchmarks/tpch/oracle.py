@@ -479,3 +479,35 @@ ORACLES = {
     "q14": q14, "q15": q15, "q16": q16, "q17": q17, "q18": q18, "q19": q19,
     "q20": q20, "q21": q21, "q22": q22,
 }
+
+
+def assert_frames_match(qname: str, got: pd.DataFrame,
+                        expected: pd.DataFrame) -> None:
+    """The repo's one comparison of an engine result with an oracle
+    frame: same columns, same row count, floats to rtol/atol 1e-6,
+    everything else exact (dates compared at day precision)."""
+
+    def normalize(df):
+        out = df.copy()
+        for c in out.columns:
+            if out[c].dtype.kind == "M":
+                out[c] = out[c].values.astype("datetime64[D]")
+        return out.reset_index(drop=True)
+
+    got, expected = normalize(got), normalize(expected)
+    # explicit raises, not ``assert``: chip_smoke.py's verdict must not
+    # depend on the interpreter running without -O
+    if list(got.columns) != list(expected.columns):
+        raise AssertionError(f"{qname}: columns {list(got.columns)} vs "
+                             f"{list(expected.columns)}")
+    if len(got) != len(expected):
+        raise AssertionError(f"{qname}: {len(got)} rows vs {len(expected)}")
+    for c in expected.columns:
+        g, e = got[c], expected[c]
+        if e.dtype.kind in "fc":
+            np.testing.assert_allclose(
+                g.astype(float), e.astype(float), rtol=1e-6, atol=1e-6,
+                err_msg=f"{qname}.{c}")
+        else:
+            np.testing.assert_array_equal(
+                g.to_numpy(), e.to_numpy(), err_msg=f"{qname}.{c}")

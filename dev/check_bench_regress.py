@@ -3,10 +3,9 @@
 
 Intended invocation — OLD is the accepted baseline round, NEW is the
 candidate (each file holds one or more JSON lines as bench.py prints
-them; the LAST well-formed line wins, matching the parent watchdog's
-salvage rule):
+them; the LAST well-formed line wins):
 
-    python dev/check_bench_regress.py BENCH_r05.json BENCH_r06.json
+    python dev/check_bench_regress.py OLD.json NEW.json
 
 Exit codes: 0 = no regression, 1 = at least one metric regressed past
 its tolerance, 2 = usage / unreadable input. Each checked metric prints
@@ -163,8 +162,7 @@ def budget_check(new: dict) -> int:
 
 def last_json_line(path: str) -> Optional[dict]:
     """The bench line in the file. Accepts both raw bench.py output
-    (JSON lines; the LAST well-formed one wins — bench prints partial
-    snapshots first, and the watchdog salvages the same way) and the
+    (JSON lines; the LAST well-formed one wins) and the
     driver's archived wrapper format (BENCH_rNN.json: one pretty-printed
     object with the bench line under ``parsed``)."""
     try:

@@ -905,11 +905,12 @@ def test_max_recoveries_knob(monkeypatch):
 
 
 def test_scheduler_binary_sigterm_drains():
-    """The REAL scheduler binary's SIGTERM path: signals must be
-    BLOCKED for sigwait to receive them — without the mask SIGTERM
-    took the default disposition (exit -15) and the drain rung never
-    ran (found driving the binary; the executor binary had the same
-    latent race around its PR 9 graceful drain)."""
+    """The REAL scheduler binary's SIGTERM path: the launcher catches
+    the signal with a process-wide handler (config.install_stop_signals)
+    — ``import jax`` starts threads before main() runs, so a mask +
+    sigwait let the signal land on an unmasked thread and take the
+    default disposition (exit -15), and the drain rung never ran. The
+    executor binary shares the helper."""
     import signal
     import subprocess
     import sys

@@ -41,6 +41,12 @@ def test_binaries_end_to_end(tmp_path):
     profile_dir = tmp_path / "profiles"
     sched_env = dict(env)
     sched_env["BALLISTA_PROFILE"] = str(profile_dir)
+    # the scheduler must never initialise a JAX backend (on a one-chip
+    # host it shares the machine with the ONE executor process that
+    # owns the chip): with a platform that does not exist, any backend
+    # init in its planning / profile-merge / health paths raises and
+    # the query below fails
+    sched_env["JAX_PLATFORMS"] = "no_such_backend"
 
     procs = []
     try:

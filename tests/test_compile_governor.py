@@ -423,6 +423,37 @@ def test_persistent_cache_min_compile_secs_defaults_to_zero():
         assert os.environ.get("BALLISTA_XLA_CACHE_MIN_COMPILE_SECS") is None
 
 
+@pytest.mark.parametrize("from_env", [True, False],
+                         ids=["env-dir", "checkout-dir"])
+def test_compile_cache_placed_from_outside(tmp_path, from_env):
+    """Where JAX_COMPILATION_CACHE_DIR is set the package sets no
+    directory in code; where it is not, the cache is the ONE fixed path
+    inside the checkout (a moved directory never hits: the path is part
+    of the cache key)."""
+    import subprocess
+    import sys
+
+    env = dict(os.environ)
+    env["JAX_PLATFORMS"] = "cpu"
+    env.pop("JAX_COMPILATION_CACHE_DIR", None)
+    if from_env:
+        env["JAX_COMPILATION_CACHE_DIR"] = str(tmp_path / "cc")
+    out = subprocess.run(
+        [sys.executable, "-c",
+         "import jax, ballista_tpu\n"
+         "print(jax.config.jax_compilation_cache_dir)\n"
+         "print(ballista_tpu.XLA_CACHE_DIR)"],
+        capture_output=True, text=True, timeout=120, env=env,
+        cwd=os.path.join(os.path.dirname(__file__), ".."))
+    assert out.returncode == 0, out.stderr[-2000:]
+    used, fixed = out.stdout.split()
+    repo = os.path.realpath(os.path.join(os.path.dirname(__file__), ".."))
+    assert os.path.realpath(fixed) == os.path.join(repo, ".xla_cache")
+    assert used == (str(tmp_path / "cc") if from_env else fixed)
+    # the directory is never created by the package when the env names it
+    assert not (tmp_path / "cc").exists()
+
+
 # ---------------------------------------------------------------------------
 # prewarm
 # ---------------------------------------------------------------------------

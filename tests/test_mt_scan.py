@@ -83,3 +83,32 @@ def test_mt_through_engine_query(tmp_path, monkeypatch):
     assert int(out["n"].sum()) == 5000
     # every 17th row has NULL a
     assert int(out["na"].sum()) == 5000 - len(range(0, 5000, 17))
+
+
+def test_native_binary_is_stale_when_missing_or_older_than_sources(
+        tmp_path, monkeypatch):
+    """The binaries are git-ignored yet lie on disk: one that is missing
+    OR older than its source / the Makefile (a copied tree, changed
+    flags) must be rebuilt, not loaded."""
+    import os
+
+    lib = tmp_path / "libtblscan.so"
+    monkeypatch.setattr(native, "_LIB_PATH", str(lib))
+    for name in ("tblscan.cpp", "shuffle_server.cpp", "Makefile"):
+        (tmp_path / name).write_text("x")
+        os.utime(tmp_path / name, (1000, 1000))
+    assert native.stale(str(lib))  # missing
+    lib.write_text("bin")
+    os.utime(lib, (2000, 2000))
+    assert not native.stale(str(lib))
+    os.utime(tmp_path / "tblscan.cpp", (3000, 3000))
+    assert native.stale(str(lib))  # older than its source
+    os.utime(tmp_path / "tblscan.cpp", (1000, 1000))
+    os.utime(tmp_path / "Makefile", (3000, 3000))
+    assert native.stale(str(lib))  # older than the build flags
+    server = tmp_path / "shuffle_server"
+    server.write_text("bin")
+    os.utime(server, (3500, 3500))
+    assert not native.stale(str(server))
+    os.utime(tmp_path / "shuffle_server.cpp", (4000, 4000))
+    assert native.stale(str(server))

@@ -7,8 +7,6 @@ programmatic golden assertions instead of eyeballing."""
 
 import os
 
-import numpy as np
-import pandas as pd
 import pytest
 
 from benchmarks.tpch import datagen, oracle
@@ -34,31 +32,9 @@ def tpch(tmp_path_factory):
     return ctx, tables
 
 
-def normalize(df: pd.DataFrame) -> pd.DataFrame:
-    out = df.copy()
-    for c in out.columns:
-        if out[c].dtype.kind == "M":
-            out[c] = out[c].values.astype("datetime64[D]")
-    return out.reset_index(drop=True)
-
-
 @pytest.mark.parametrize("qname", QUERIES)
 def test_tpch_query(tpch, qname):
     ctx, tables = tpch
     sql = open(os.path.join(QDIR, f"{qname}.sql")).read()
-    got = normalize(ctx.sql(sql).collect())
-    exp = normalize(oracle.ORACLES[qname](tables))
-
-    assert list(got.columns) == list(exp.columns), (got.columns, exp.columns)
-    assert len(got) == len(exp), f"{qname}: {len(got)} rows vs {len(exp)}"
-    for c in exp.columns:
-        g, e = got[c], exp[c]
-        if e.dtype.kind in "fc":
-            np.testing.assert_allclose(
-                g.astype(float), e.astype(float), rtol=1e-6, atol=1e-6,
-                err_msg=f"{qname}.{c}",
-            )
-        else:
-            np.testing.assert_array_equal(
-                g.to_numpy(), e.to_numpy(), err_msg=f"{qname}.{c}"
-            )
+    oracle.assert_frames_match(qname, ctx.sql(sql).collect(),
+                               oracle.ORACLES[qname](tables))

@@ -9,8 +9,6 @@ only at scale (q7's OR-collapse showed up first at SF0.05).
 
 import os
 
-import numpy as np
-import pandas as pd
 import pytest
 
 from benchmarks.tpch import datagen, oracle
@@ -59,29 +57,8 @@ def sf02_cluster(sf02_data):
     cluster.shutdown()
 
 
-def _normalize(df: pd.DataFrame) -> pd.DataFrame:
-    out = df.copy()
-    for c in out.columns:
-        if out[c].dtype.kind == "M":
-            out[c] = out[c].values.astype("datetime64[D]")
-    return out.reset_index(drop=True)
-
-
 def _assert_matches(got, exp, qname):
-    got, exp = _normalize(got), _normalize(exp)
-    assert list(got.columns) == list(exp.columns), (got.columns, exp.columns)
-    assert len(got) == len(exp), f"{qname}: {len(got)} rows vs {len(exp)}"
-    for c in exp.columns:
-        g, e = got[c], exp[c]
-        if e.dtype.kind in "fc":
-            np.testing.assert_allclose(
-                g.astype(float), e.astype(float), rtol=1e-6, atol=1e-6,
-                err_msg=f"{qname}.{c}",
-            )
-        else:
-            np.testing.assert_array_equal(
-                g.to_numpy(), e.to_numpy(), err_msg=f"{qname}.{c}"
-            )
+    oracle.assert_frames_match(qname, got, exp)
 
 
 @pytest.mark.parametrize("qname", QUERIES)

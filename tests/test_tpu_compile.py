@@ -1,0 +1,166 @@
+"""The main path's kernels compile for the chip — checked without one.
+
+The TPU's compiler is installed in the sandbox and compiles for a
+DESCRIBED v5e (``jax.experimental.topologies``), so these tests guard
+every PR against what interpret mode and the CPU backend cannot see: a
+Mosaic refusal, a program that does not fit HBM, a collective that does
+not partition. Nothing runs, so they say nothing about results or
+times. One file on purpose: only one process may hold the TPU library,
+the worker that is handed this file loads it, and a second file could
+land on another worker and skip in silence. The topology is described
+inside a fixture — never at import (every xdist worker imports every
+test file).
+
+Sort-bearing programs stay at the 1,024-row rung: measured with this
+compiler (jax 0.9.0 / libtpu 0.0.34), every program holding a
+``lax.sort`` over >= 64K rows takes 17-190 s to compile for the TPU
+(int32 pair sort 17 s at 64K and 28 s at 1M rows; int64 join build 120 s
+at 3M rows; the aggregate's multi-operand sort 41 s at 16K rows and
+189 s at 1M), against 0.5 s at 1,024 rows — see ROADMAP queue S.
+"""
+
+import functools
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from jax.sharding import SingleDeviceSharding
+
+from ballista_tpu.kernels import aggregate, join, mesh_shuffle, pallas_agg
+from ballista_tpu.kernels.aggregate import AggInput
+
+
+@pytest.fixture(scope="module")
+def topo():
+    import os
+
+    from jax.experimental import topologies
+
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 - no TPU compiler here
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(scope="module")
+def no_disk_cache():
+    """A compile for a described chip is written to the persistent cache
+    but cannot be read back without one (the next run warns and
+    recompiles), so keep these out of it."""
+    from jax.experimental.compilation_cache import compilation_cache
+
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", True)
+    compilation_cache.reset_cache()
+
+
+def _compile(fn, *shapes):
+    return jax.jit(fn).lower(*shapes).compile()
+
+
+def _shape(sharding, n, dtype):
+    return jax.ShapeDtypeStruct((n,), dtype, sharding=sharding)
+
+
+def test_dense_xla_aggregate_q1_at_8m_rows(one_chip, no_disk_cache):
+    """q1's partial aggregate (four scaled-int64 sums + count over the
+    flag x status groups) at the 1<<23-row batch."""
+    n = 1 << 23
+
+    def q1_partial(gids, live, qty, price, disc_price, charge):
+        aggs = [AggInput("sum", v, None)
+                for v in (qty, price, disc_price, charge)]
+        aggs.append(AggInput("count", None, None))
+        return aggregate._dense_grouped_xla(gids, live, aggs, 8)
+
+    s = functools.partial(_shape, one_chip, n)
+    compiled = _compile(q1_partial, s(jnp.int32), s(jnp.bool_),
+                        *[s(jnp.int64)] * 4)
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes + mem.argument_size_in_bytes < 16e9
+
+
+def test_pallas_aggregate_has_custom_call(one_chip, no_disk_cache):
+    n = 1 << 20
+
+    def sums(gids, live, a, b, c, d):
+        return pallas_agg.dense_grouped_sums(gids, live, [a, b, c, d], 8)
+
+    s = functools.partial(_shape, one_chip, n)
+    compiled = _compile(sums, s(jnp.int32), s(jnp.bool_),
+                        *[s(jnp.int64)] * 4)
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.mark.parametrize("dense", [True, False], ids=["dense", "sorted"])
+def test_join_probe_unique_at_1m_rows(one_chip, no_disk_cache, dense):
+    n = 1 << 20
+    s = functools.partial(_shape, one_chip, n)
+    scalar = functools.partial(jax.ShapeDtypeStruct, (), sharding=one_chip)
+
+    def probe(sorted_keys, order, num_live, dense_rows, dense_base,
+              probe_keys, probe_live):
+        table = join.BuildTable(
+            sorted_keys, order, num_live,
+            dense_rows if dense else None,
+            dense_base if dense else None)
+        return join.probe_unique(table, probe_keys, probe_live)
+
+    _compile(probe, s(jnp.int64), s(jnp.int32), scalar(jnp.int32),
+             s(jnp.int32), scalar(jnp.int64), s(jnp.int64), s(jnp.bool_))
+
+
+def test_sort_based_aggregate_at_first_rung(one_chip, no_disk_cache):
+    """Two-key ``grouped_aggregate`` (the multi-operand lax.sort form) at
+    1,024 rows only — see the module docstring for why not larger."""
+    n = 1024
+
+    def agg(k64, k32, live, v):
+        return aggregate.grouped_aggregate(
+            [k64, k32], live,
+            [AggInput("sum", v, None), AggInput("count", None, None)],
+            group_capacity=n)
+
+    s = functools.partial(_shape, one_chip, n)
+    _compile(agg, s(jnp.int64), s(jnp.int32), s(jnp.bool_), s(jnp.int64))
+
+
+def test_join_build_sorted_at_first_rung(one_chip, no_disk_cache):
+    """The int64 argsort join build at 1,024 rows only (module docstring:
+    120 s at 3M rows)."""
+    s = functools.partial(_shape, one_chip, 1024)
+    _compile(join.build_sorted_with_unique, s(jnp.int64), s(jnp.bool_))
+
+
+def test_mesh_all_to_all_rows_on_four_chips(topo, no_disk_cache):
+    """The ICI shuffle as ONE program across the four described chips:
+    the compiler must place an all-to-all, not gather to one device."""
+    from jax import shard_map
+
+    n_dev, cap = 4, 1024
+    mesh = Mesh(np.asarray(topo.devices[:n_dev]), ("data",))
+    sharded = NamedSharding(mesh, P("data"))
+
+    @functools.partial(shard_map, mesh=mesh, in_specs=(P("data"),) * 4,
+                       out_specs=(P("data"), P("data"), P("data")),
+                       check_vma=False)
+    def exchange(keys, vals, live, dest):
+        cols, out_live, counts = mesh_shuffle.all_to_all_rows(
+            [keys, vals], live, dest, "data", n_dev, dest_capacity=cap)
+        return cols[0], out_live, counts
+
+    s = functools.partial(_shape, sharded, n_dev * cap)
+    compiled = _compile(exchange, s(jnp.int64), s(jnp.int64), s(jnp.bool_),
+                        s(jnp.int32))
+    assert "all-to-all" in compiled.as_text()
