@@ -46,6 +46,21 @@ UNMAPPED_ALLOWLIST = {
     # scheduler dispatch bookkeeping: control-plane time, not part of
     # any single query's attributable wall
     "scheduler.task_dispatch",
+    # the served hand-off's polls and the sleeps between them: the
+    # threads are asleep or in a control-plane round trip, so the spans
+    # name idle gaps in a device trace (perfbench/xplane.py) and count
+    # in tracing.span_totals(); the wall time they cost a query reaches
+    # the ledger as dispatch_wait / report_wait / client_poll_wait,
+    # which the scheduler accumulates itself (ledger.HANDOFF_PHASES)
+    "executor.poll_wait",
+    "executor.poll",
+    "client.poll_wait",
+    "client.poll",
+    # marker events (dur=0) read as counts from tracing.span_totals():
+    # a straggler duplicated (ballista_tasks_speculated_total) and a
+    # completion report sent without its profile window
+    "scheduler.speculate",
+    "executor.profile_dropped",
     # cancellation marker event (dur=0): lifecycle, not latency
     "lifecycle.cancel",
     # adaptive re-planning markers: they fire INSIDE windows that are

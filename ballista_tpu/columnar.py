@@ -500,21 +500,49 @@ class ColumnBatch:
     # -- host materialization ----------------------------------------------
 
     def to_pydict(self) -> Dict[str, np.ndarray]:
-        """Compact to host: logical values of live rows only.
+        """Compact to host: logical values of live rows only. In three
+        steps, so that a collect can tell waiting for the device from
+        moving the result from decoding it
+        (execution.collect_physical)."""
+        self.wait_ready()
+        return self.decode_host(self.fetch_host())
 
-        All device buffers are fetched in ONE ``jax.device_get`` (async
-        copies issued together, then awaited) — per-column ``np.asarray``
-        would serialize a device->host round-trip per array, which
-        dominates query latency when the accelerator is remote."""
+    def _device_buffers(self) -> tuple:
+        return (self.selection,
+                [c.values for c in self.columns],
+                [c.validity for c in self.columns])
+
+    def wait_ready(self) -> None:
+        """Block until the programs that produce this batch's buffers
+        have run: the ``device.block`` span of a result fetch. The
+        device-to-host copies are asked for first, as ``device_get``
+        itself would, so they queue behind the programs and overlap the
+        wait instead of starting after it."""
         from .observability.tracing import trace_span
 
+        buffers = self._device_buffers()
+        for leaf in jax.tree_util.tree_leaves(buffers):
+            start_copy = getattr(leaf, "copy_to_host_async", None)
+            if start_copy is not None:
+                start_copy()
         with trace_span("device.block", site="batch.to_pydict",
                         columns=len(self.columns)):
-            sel, vals, valids = jax.device_get((
-                self.selection,
-                [c.values for c in self.columns],
-                [c.validity for c in self.columns],
-            ))
+            jax.block_until_ready(buffers)
+
+    def fetch_host(self) -> tuple:
+        """The device buffers on the host, ``(selection, values,
+        validities)``, in ONE ``jax.device_get`` (async copies issued
+        together, then awaited) — per-column ``np.asarray`` would
+        serialize a device->host round-trip per array, which dominates
+        query latency when the accelerator is remote."""
+        # the copy alone: callers run wait_ready(), the span, first
+        # ballista: ignore[sync-span]
+        return jax.device_get(self._device_buffers())
+
+    def decode_host(self, fetched: tuple) -> Dict[str, np.ndarray]:
+        """What :meth:`fetch_host` brought, as logical values of the
+        live rows by column name."""
+        sel, vals, valids = fetched
         mask = np.asarray(sel)
         out: Dict[str, np.ndarray] = {}
         for f, col, v, va in zip(self.schema.fields, self.columns, vals,

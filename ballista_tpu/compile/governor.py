@@ -28,7 +28,9 @@ compile time. The governor replaces them all:
 
 from __future__ import annotations
 
+import functools
 import os
+import re
 import threading
 import time
 from collections import OrderedDict
@@ -316,6 +318,28 @@ class _BoundGoverned:
         return self.gf.warm(*args, **kwargs)
 
 
+def program_name(key: tuple) -> str:
+    """The name a governed key gives its program: the key's family (its
+    leading namespace string, ``join.unique`` -> ``join_unique``), so an
+    XLA module and a device trace read ``jit_join_unique`` where the
+    built function was an inner ``run``. No shapes, no literals; under
+    40 characters."""
+    slug = re.sub(r"[^a-z0-9]+", "_", str(key[0]).lower() if key else "")
+    return slug.strip("_")[:39] or "governed"
+
+
+def _named(fn: Callable, key: tuple) -> Callable:
+    """``fn`` under its key's program name. A wrapper, not a rename: a
+    build may return a function that others share (a kernel module's
+    ``build_dense``), and jax reads the name when it traces."""
+    @functools.wraps(fn)
+    def program(*args, **kwargs):
+        return fn(*args, **kwargs)
+
+    program.__name__ = program.__qualname__ = program_name(key)
+    return program
+
+
 def _render_key(key: tuple) -> str:
     try:
         return repr(key)[:200]
@@ -382,7 +406,7 @@ class CompileGovernor:
             # creation traces nothing); the first insert wins.
             import jax
 
-            gf = GovernedFunction(key, jax.jit(build(),
+            gf = GovernedFunction(key, jax.jit(_named(build(), key),
                                                **(jit_kwargs or {})))
             if aot and not jit_kwargs:
                 from .aot import make_entry

@@ -14,6 +14,7 @@ import numpy as np
 
 from ..errors import (AdmissionRejected, ClusterError, PlanError,
                       QueryCancelled)
+from ..observability import trace_span
 from ..proto import ballista_pb2 as pb
 from .. import serde
 from .dataplane import fetch_partition_bytes
@@ -185,7 +186,9 @@ def wait_for_job(host: str, port: int, job_id: str,
     try:
         deadline = time.time() + timeout
         while True:
-            result = client.GetJobStatus(pb.GetJobStatusParams(job_id=job_id))
+            with trace_span("client.poll", job=job_id):
+                result = client.GetJobStatus(
+                    pb.GetJobStatusParams(job_id=job_id))
             which = result.status.WhichOneof("status")
             if which == "completed":
                 # terminal callback: the tracker's frozen final
@@ -244,7 +247,9 @@ def wait_for_job(host: str, port: int, job_id: str,
                     f"job {job_id} timed out after {timeout:.1f}s",
                     job_id=job_id,
                 )
-            time.sleep(POLL_SECS)
+            # what a terminal job waits for before its client reads it
+            with trace_span("client.poll_wait", job=job_id):
+                time.sleep(POLL_SECS)
     finally:
         client.close()
 

@@ -27,7 +27,7 @@ from typing import Dict, Optional
 
 from ..errors import QueryCancelled
 from ..lifecycle import CancelToken, bind_token, check_cancel
-from ..observability import trace_span
+from ..observability import trace_event, trace_span
 from ..observability.metrics import collect_plan_metrics, metrics_enabled
 from ..proto import ballista_pb2 as pb
 from .. import serde
@@ -314,7 +314,7 @@ class Executor:
             # profiles are advisory payload; the final flush is about
             # never losing the REPORTS
             if st.HasField("completed") and st.completed.HasField("profile"):
-                st.completed.ClearField("profile")
+                self._drop_profile(st)
             params.task_status.append(st)
         self._client.PollWork(params)
 
@@ -342,14 +342,36 @@ class Executor:
                         "poll still failing (%d consecutive; %s: %s); "
                         "next retry in %.2fs", failures,
                         type(e).__name__, e, wait)
-                self._stop.wait(wait)
+                self._poll_wait(wait)
                 continue
             if failures:
                 log.info("scheduler reachable again after %d failed "
                          "poll(s)", failures)
             failures = 0
             backoff = 0.0
-            self._stop.wait(POLL_INTERVAL_SECS)
+            self._poll_wait(POLL_INTERVAL_SECS)
+
+    def _poll_wait(self, seconds: float):
+        """The sleep between two polls, as an ``executor.poll_wait``
+        span: what a ready task waits for before it is picked up, and a
+        finished one before it is reported. With no task in flight and
+        no report pending when it ends, the span stays out of the
+        flight recorder (profiler annotation and span totals only): an
+        idle cluster must not turn the ring over with its waits."""
+        with trace_span("executor.poll_wait",
+                        executor=self.id[:8]) as span:
+            self._stop.wait(seconds)
+            span.record = self._inflight > 0 or bool(self._pending_status)
+
+    def _drop_profile(self, st) -> None:
+        """Send a completion report without its profile window, and
+        count it (``span_totals()["executor.profile_dropped"]``): with
+        the window go the task's ledger deltas, ``report_wait`` among
+        them."""
+        st.completed.ClearField("profile")
+        pid = st.partition_id
+        trace_event("executor.profile_dropped", executor=self.id[:8],
+                    task=f"{pid.job_id}/{pid.stage_id}/{pid.partition_id}")
 
     def _poll_once(self):
         can_accept = self._slots.acquire(blocking=False)
@@ -387,11 +409,13 @@ class Executor:
         # reports it carried (pending was already cleared) and hang the
         # job. Reports always go; overflow profiles are dropped.
         budget = _POLL_PROFILE_BUDGET_BYTES
+        sending = time.time()
         for st in pending:
             if st.HasField("completed") and st.completed.HasField("profile"):
+                self._stamp_report_wait(st, sending)
                 sz = st.completed.profile.ByteSize()
                 if sz > budget:
-                    st.completed.ClearField("profile")
+                    self._drop_profile(st)
                 else:
                     budget -= sz
             params.task_status.append(st)
@@ -401,7 +425,12 @@ class Executor:
         for tp in self._maybe_sample_progress():
             params.task_progress.append(tp)
         try:
-            result = self._client.PollWork(params)
+            # the round trip itself; kept out of the flight recorder
+            # when it carried no report and brought no task
+            with trace_span("executor.poll", executor=self.id[:8],
+                            reports=len(pending)) as span:
+                result = self._client.PollWork(params)
+                span.record = bool(pending) or result.HasField("task")
         except Exception:
             # report re-delivery: a failed poll (scheduler down, RPC
             # fault) must not LOSE the completion/failure reports it
@@ -423,6 +452,25 @@ class Executor:
             self._draining = True
         if result.HasField("task"):
             self._run_task(result.task)
+
+    def _stamp_report_wait(self, st, sending: float) -> None:
+        """``ledger.report_wait`` on a completion's profile: seconds on
+        this clock from the task's end (its window's ``t0`` +
+        ``wall_seconds``) to the send of the poll that carries the
+        report. Rides the free-form ``TaskProfile.phases`` dict; a
+        re-sent report is stamped anew."""
+        import json
+
+        from ..observability.ledger import task_phase_key
+
+        try:
+            prof = st.completed.profile
+            phases = json.loads(prof.phases_json or b"{}")
+            phases[task_phase_key("report_wait")] = round(
+                max(sending - (prof.t0 + prof.wall_seconds), 0.0), 6)
+            prof.phases_json = json.dumps(phases, default=str).encode()
+        except Exception:  # noqa: BLE001 - observability only
+            log.debug("report_wait not stamped", exc_info=True)
 
     def _maybe_sample_progress(self):
         """TaskProgress records for this poll, or [] (plane disabled,

@@ -26,7 +26,8 @@ import grpc
 
 from ..errors import ClusterError
 from ..execution import plan_logical
-from ..observability import trace_span
+from ..observability import ledger as obs_ledger
+from ..observability import span_totals, trace_event, trace_span
 from ..proto import ballista_pb2 as pb
 from ..testing.faults import fault_point
 from .. import serde
@@ -354,6 +355,8 @@ class SchedulerService:
             ("ballista_jobs_failed_total", {}, st.jobs_failed),
             ("ballista_jobs_cancelled_total", {}, st.jobs_cancelled),
             ("ballista_tasks_dispatched_total", {}, self.tasks_dispatched),
+            ("ballista_tasks_speculated_total", {},
+             span_totals().get("scheduler.speculate", {}).get("count", 0)),
             ("ballista_ready_queue_depth", {}, st.ready_queue_depth()),
             ("ballista_slow_queries_total", {}, st.query_log.slow_total),
             # admission plane: queue depth + the decision counters
@@ -578,10 +581,12 @@ class SchedulerService:
         # (no ring scan, no artifact work), so it runs inline and the
         # job's rows are queryable the moment its status is terminal
         try:
-            from ..observability import ledger as obs_ledger
-
             with self._ledger_lock:
                 stamps = self._ledger_stamps.pop(job_id, {})
+            # the hand-off the state accumulated for the job, as wall
+            # time on this clock (client_poll_wait joins the recorded
+            # row when the client first reads the status)
+            stamps.update(self.state.take_handoff(job_id))
             obs_ledger.record_ledger(obs_ledger.assemble_job_ledger(
                 job_id, float(summary.get("wall_seconds", 0.0)),
                 status.state, stamps,
@@ -1065,6 +1070,7 @@ class SchedulerService:
         # handler
         _cancel_memo: dict = {}
         for ts in request.task_status:
+            report_wait = 0.0
             jid = ts.partition_id.job_id
             cancelled = _cancel_memo.get(jid)
             if cancelled is None:
@@ -1083,8 +1089,14 @@ class SchedulerService:
                     self.profiles.add_task_profile(
                         ts.partition_id.job_id, prof,
                         nbytes=len(ts.completed.profile.records_json))
+                    try:
+                        report_wait = float((prof.get("phases") or {}).get(
+                            obs_ledger.task_phase_key("report_wait"), 0.0))
+                    except (TypeError, ValueError):
+                        report_wait = 0.0
             st = _task_status_from_proto(ts)
             jobs_touched.add(st.partition.job_id)
+            self.state.task_reported(st.partition)
             if not self.state.accept_report_version(st):
                 # the task was cut from a stage version an adaptive
                 # re-plan superseded: its output layout no longer
@@ -1092,7 +1104,7 @@ class SchedulerService:
                 # any stranded current-version twin)
                 continue
             if st.state == "completed":
-                self.state.task_completed(st)
+                self.state.task_completed(st, report_wait=report_wait)
             elif st.state == "failed" and self.state.is_completed(st.partition):
                 # the losing speculative duplicate failed AFTER the
                 # original completed: the recorded result stands — a
@@ -1137,6 +1149,10 @@ class SchedulerService:
                 if task is not None:
                     log.warning("speculating straggler task %s on executor "
                                 "%s", task.key(), meta.id)
+                    # counted by name (tracing.span_totals), exported as
+                    # ballista_tasks_speculated_total
+                    trace_event("scheduler.speculate", task=task.key(),
+                                job=task.job_id, executor=meta.id[:8])
             if task is not None:
                 try:
                     # a SPAN (not an instant): its duration is the real
@@ -1237,6 +1253,22 @@ class SchedulerService:
 
     # -- RPC: GetJobStatus --------------------------------------------------
 
+    def _note_terminal_read(self, job_id: str) -> None:
+        """``client_poll_wait``: the first read of a terminal status
+        closes the time the job waited to be read, on this clock, and
+        adds it to the ledger row its terminal hook recorded. (A read
+        that overtakes the hook, by under a millisecond, finds no row
+        and is not counted.)"""
+        terminal_at = self.state.take_terminal_at(job_id)
+        if terminal_at is None:
+            return
+        try:
+            obs_ledger.process_ledger_log().add_phase(
+                job_id, "client_poll_wait",
+                max(time.time() - terminal_at, 0.0))
+        except Exception:  # noqa: BLE001 - observability only
+            log.exception("client_poll_wait not recorded for %s", job_id)
+
     def GetJobStatus(self, request: pb.GetJobStatusParams, context=None):
         # lifecycle reap rides status polls too: with every executor
         # down there are no PollWork calls, but a waiting client still
@@ -1246,6 +1278,9 @@ class SchedulerService:
         self.state.reap_expired_jobs()
         self.admission.pump()
         st = self.state.get_job_status(request.job_id)
+        if st is not None and st.state in ("completed", "failed",
+                                           "cancelled"):
+            self._note_terminal_read(request.job_id)
         result = pb.GetJobStatusResult()
         if st is None:
             result.status.failed.error = f"unknown job {request.job_id}"

@@ -237,6 +237,16 @@ class SchedulerState:
         self._cancelled_jobs: Dict[str, float] = {}
         self._job_deadlines: Dict[str, float] = {}
         self._last_deadline_scan = 0.0
+        # hand-off phases of the latency ledger (observability/ledger.py
+        # HANDOFF_PHASES), on this process's clock, guarded by
+        # self._lock. Per live job: the tasks handed out and not yet
+        # reported, since when it has had a ready task and none out,
+        # and the wall seconds accumulated (_handoff_tick,
+        # _handoff_report). Per terminal job nobody has read yet: when
+        # it became terminal (take_terminal_at; bounded, a client that
+        # went away never reads).
+        self._handoff: Dict[str, dict] = {}
+        self._terminal_at: Dict[str, float] = {}
         # distributed profiler: per-job logical-plan digests (so a slow
         # query is identifiable after the fact without re-planning) and
         # the terminal-transition hook the scheduler service installs —
@@ -343,6 +353,14 @@ class SchedulerState:
     # -- jobs ---------------------------------------------------------------
 
     def save_job_status(self, job_id: str, status: JobStatus):
+        if status.state in ("completed", "failed", "cancelled") and \
+                job_id in self._job_started:
+            # stamped BEFORE the status is readable, so the first read
+            # of it always finds its stamp (client_poll_wait)
+            with self._lock:
+                while len(self._terminal_at) >= 1024:
+                    self._terminal_at.pop(next(iter(self._terminal_at)))
+                self._terminal_at[job_id] = time.time()
         self.kv.put(self._k("jobs", job_id), pickle.dumps(status))
         # health plane bookkeeping: time the queued -> terminal window
         # and push a summary into the query ring buffer exactly once
@@ -412,6 +430,8 @@ class SchedulerState:
                                       job_id)
                 systables.record_query(summary,
                                        query_log=self.query_log)
+            with self._lock:  # the hook took it; without one, drop it
+                self._handoff.pop(job_id, None)
 
     def get_job_status(self, job_id: str) -> Optional[JobStatus]:
         v = self.kv.get(self._k("jobs", job_id))
@@ -786,6 +806,74 @@ class SchedulerState:
         for p in range(n):
             if p not in started and p not in queued:
                 self._ready.append(PartitionId(job_id, stage_id, p))
+        # a task's ready time is now, if none of the job's is out
+        self._handoff_tick(job_id)
+
+    # -- hand-off phases (observability/ledger.HANDOFF_PHASES) ---------------
+
+    def _handoff_tick(self, job_id: str, handed: Optional[PartitionId] = None,
+                      reported: Optional[PartitionId] = None) -> None:
+        """``dispatch_wait``: accumulate the wall time during which the
+        job has at least one ready task and none handed out and
+        unreported: the pickup latency the executors' poll sets. Called
+        (under self._lock) wherever either side of that changes."""
+        h = self._handoff.get(job_id)
+        if h is None:
+            h = self._handoff[job_id] = {
+                "out": set(), "since": None, "dispatch_wait": 0.0,
+                "report_wait": 0.0, "reported_until": 0.0}
+        if handed is not None:
+            h["out"].add(handed)
+        if reported is not None:
+            h["out"].discard(reported)
+        now = time.time()
+        if not h["out"] and any(p.job_id == job_id for p in self._ready):
+            if h["since"] is None:
+                h["since"] = now
+        elif h["since"] is not None:
+            h["dispatch_wait"] += now - h["since"]
+            h["since"] = None
+
+    def task_reported(self, pid: PartitionId) -> None:
+        """An executor's report for ``pid`` arrived (any outcome): it is
+        no longer out. Called BEFORE the report is acted on, so that
+        dependents it unlocks become ready with none out."""
+        with self._lock:
+            if pid.job_id in self._handoff:
+                self._handoff_tick(pid.job_id, reported=pid)
+
+    def _handoff_report(self, job_id: str, report_wait: float) -> None:
+        """``report_wait`` of the report that completed a stage (the one
+        its dependents waited for): the executor's seconds from the
+        task's end to the send of the poll that carried it, laid on this
+        clock to end now, and counted only where no earlier stage's
+        report already covers it, so the phase is wall time."""
+        h = self._handoff.get(job_id)
+        if h is None or report_wait <= 0:
+            return
+        now = time.time()
+        start = max(now - report_wait, h["reported_until"])
+        if now > start:
+            h["report_wait"] += now - start
+        h["reported_until"] = now
+
+    def take_handoff(self, job_id: str) -> Dict[str, float]:
+        """The job's accumulated ``dispatch_wait`` and ``report_wait``,
+        closed now (the terminal hook's, once a job)."""
+        with self._lock:
+            h = self._handoff.pop(job_id, None)
+        if h is None:
+            return {}
+        if h["since"] is not None:
+            h["dispatch_wait"] += time.time() - h["since"]
+        return {"dispatch_wait": h["dispatch_wait"],
+                "report_wait": h["report_wait"]}
+
+    def take_terminal_at(self, job_id: str) -> Optional[float]:
+        """When the job became terminal, for the FIRST status read that
+        returns it (``client_poll_wait``); None for every later one."""
+        with self._lock:
+            return self._terminal_at.pop(job_id, None)
 
     def ready_queue_depth(self) -> int:
         with self._lock:
@@ -805,7 +893,9 @@ class SchedulerState:
                 need = self._stage_mesh.get((pid.job_id, pid.stage_id), 0)
                 if need and num_devices and num_devices < need:
                     continue
-                return self._ready.pop(i)
+                self._ready.pop(i)
+                self._handoff_tick(pid.job_id, handed=pid)
+                return pid
         return None
 
     def is_completed(self, pid: PartitionId) -> bool:
@@ -813,12 +903,14 @@ class SchedulerState:
                                 pid.partition_id))
         return v is not None and pickle.loads(v).state == "completed"
 
-    def task_completed(self, st: TaskStatus):
+    def task_completed(self, st: TaskStatus, report_wait: float = 0.0):
         """Record completion; if a whole stage just completed, unlock its
         dependents (event-driven, replacing the reference's full scan).
         First result wins: when speculation duplicated the task, the
         second completion report is dropped so consumers keep fetching
-        from the location already recorded."""
+        from the location already recorded. ``report_wait``: the
+        executor's seconds between the task's end and the send of this
+        report, counted for the job only if it completes the stage."""
         job_id = st.partition.job_id
         stage_id = st.partition.stage_id
         with self._lock:
@@ -835,6 +927,7 @@ class SchedulerState:
             done = [t for t in stage_tasks if t.state == "completed"]
             if n is None or len(done) < n:
                 return
+            self._handoff_report(job_id, report_wait)
             # stage complete: enqueue dependents whose deps are all complete
             # (_enqueue_stage only picks up still-pending tasks, so this is
             # safe to re-trigger after recovery resets)
@@ -951,6 +1044,9 @@ class SchedulerState:
 
     def _reset_task(self, pid: PartitionId):
         self.save_task_status(TaskStatus(pid))
+        with self._lock:
+            if pid.job_id in self._handoff:  # lost, so no longer out
+                self._handoff[pid.job_id]["out"].discard(pid)
 
     def recover_fetch_failure(self, st: TaskStatus) -> bool:
         """Attempt recovery from a consumer task that failed with a tagged
@@ -1095,6 +1191,7 @@ class SchedulerState:
                         self._speculated.add(key)
                         # a successful scan doesn't delay the next one
                         self._last_spec_scan = 0.0
+                        self._handoff_tick(job_id, handed=key)
                         return t.partition
         return None
 

@@ -134,6 +134,7 @@ def collect_physical(phys: PhysicalPlan) -> Dict[str, np.ndarray]:
     is gated off."""
     from .ingest import iter_partitions
     from .lifecycle import check_cancel
+    from .observability.ledger import ledger_phase
 
     parts: List[Dict[str, np.ndarray]] = []
     for batch in iter_partitions(
@@ -141,7 +142,14 @@ def collect_physical(phys: PhysicalPlan) -> Dict[str, np.ndarray]:
         # cooperative cancellation: a fired token (ctx.cancel, the
         # slow-query killer) stops the collect at a batch boundary
         check_cancel()
-        parts.append(batch.to_pydict())
+        # to_pydict's three steps, each where the ledger can see it: the
+        # wait for the device (a device.block span), the device-to-host
+        # fetch (result_transfer), masking and decoding (host_decode)
+        batch.wait_ready()
+        with ledger_phase("result_transfer"):
+            fetched = batch.fetch_host()
+        with ledger_phase("host_decode"):
+            parts.append(batch.decode_host(fetched))
     if not parts:
         return {f.name: np.asarray([]) for f in phys.output_schema().fields}
     return concat_pydicts(parts)

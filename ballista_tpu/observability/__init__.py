@@ -32,6 +32,7 @@ from .tracing import (  # noqa: F401
     flow,
     ring_records,
     set_process_identity,
+    span_totals,
     trace_enabled,
     trace_event,
     trace_span,

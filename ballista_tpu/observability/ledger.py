@@ -7,13 +7,23 @@ rerun. Unlike the profiler's lane decomposition (export.compute_lanes,
 which needs a trace/profile session), the ledger is assembled from
 cheap stamps and counters that are already maintained on the hot path:
 
-- the client stamps its envelope phases (``host_decode``,
-  ``result_transfer``) through the thread-local collect window;
+- the client stamps its envelope phases (``planning``, ``host_decode``,
+  ``result_transfer``) through the thread-local collect window; each
+  stamp is the duration of a ``client.<phase>`` span (:func:`ledger_phase`);
 - the scheduler stamps ``admission_wait`` / ``queue_wait`` /
   ``planning`` around the gate, the admission queue and the planner;
 - executors ship per-task phase deltas back on ``CompletedTask`` as
   ``ledger.<phase>`` keys riding the existing ``TaskProfile.phases``
   dict (no proto change), summed at job-terminal time;
+- the hand-off between them is three phases of its own
+  (:data:`HANDOFF_PHASES`), each wall time on one clock and each
+  accumulated where it is measured, never summed over tasks:
+  ``dispatch_wait`` (scheduler clock: the job had a ready task and none
+  handed out and unreported), ``report_wait`` (executor clock: a task's
+  end to the send of the ``PollWork`` that carried its report, counted
+  only for the report that completed a stage) and ``client_poll_wait``
+  (scheduler clock: the terminal transition to the first status read
+  that returned it);
 - the standalone recorder extracts the same phases from the
   flight-recorder window it already mines for lanes.
 
@@ -34,6 +44,8 @@ from collections import deque
 from contextlib import contextmanager
 from typing import Dict, Iterable, List, Optional
 
+from .tracing import trace_span
+
 # The fixed phase schema. Every ledger carries every phase (0.0 when a
 # path doesn't exercise it) so downstream consumers never key-check.
 LEDGER_PHASES = (
@@ -47,7 +59,15 @@ LEDGER_PHASES = (
     "cache_lookup",      # table/result cache probes (hit or miss)
     "host_decode",       # result bytes -> host arrays -> DataFrame
     "result_transfer",   # client-side result partition fetches
+    "dispatch_wait",     # scheduler: ready tasks waiting for a poll
+    "report_wait",       # executor: a finished task waiting for a poll
+    "client_poll_wait",  # scheduler: a terminal job waiting to be read
 )
+
+# The hand-off phases: the scheduler accumulates each for the job as
+# wall time (state.py, scheduler.py), so the per-task sums leave them
+# out (merge_task_phases) and no window is mined for them.
+HANDOFF_PHASES = ("dispatch_wait", "report_wait", "client_poll_wait")
 
 # Span name -> ledger phase, for phases extracted from flight-recorder
 # windows (per-task on executors, per-collect standalone). The
@@ -57,7 +77,14 @@ LEDGER_SPANS = {
     "shuffle.fetch": "shuffle_fetch",
     "dataplane.write": "shuffle_write",
     "cache.lookup": "cache_lookup",
+    # opened by ledger_phase, which stamps the span's own duration into
+    # the collect window: known here by name, never summed out of a
+    # window a second time (span_phase_sums skips STAMPED_SPANS)
+    "client.planning": "planning",
+    "client.host_decode": "host_decode",
+    "client.result_transfer": "result_transfer",
 }
+STAMPED_SPANS = frozenset(n for n in LEDGER_SPANS if n.startswith("client."))
 
 _TRUTHY_OFF = ("0", "off", "false", "no")
 
@@ -111,13 +138,16 @@ def stamp(phase: str, seconds: float) -> None:
 
 @contextmanager
 def ledger_phase(phase: str):
-    """Accumulate the block's wall time into the active collect window
-    (no-op when no window is bound — a perf_counter pair either way)."""
-    t0 = time.perf_counter()
+    """Run the block under a ``client.<phase>`` span and accumulate that
+    span's duration into the active collect window (the stamp is a
+    no-op when no window is bound): one clock reading for both, so the
+    ledger and the trace cannot drift."""
+    span = trace_span("client." + phase)
     try:
-        yield
+        with span:
+            yield
     finally:
-        stamp(phase, time.perf_counter() - t0)
+        stamp(phase, span.dur)
 
 
 # -- assembly -----------------------------------------------------------------
@@ -126,7 +156,8 @@ def span_phase_sums(records: Iterable[dict]) -> Dict[str, float]:
     """Sum LEDGER_SPANS durations out of a flight-recorder window."""
     out: Dict[str, float] = {}
     for r in records:
-        phase = LEDGER_SPANS.get(r.get("name"))
+        name = r.get("name")
+        phase = None if name in STAMPED_SPANS else LEDGER_SPANS.get(name)
         if phase is not None:
             out[phase] = out.get(phase, 0.0) + float(r.get("dur", 0.0))
     return out
@@ -154,12 +185,16 @@ def task_ledger_phases(records: Iterable[dict], wall_seconds: float,
 def merge_task_phases(payloads: Iterable[dict]) -> Dict[str, float]:
     """Sum the ``ledger.*`` deltas out of per-task profile payloads
     (one entry per completed task, any number of executors — summing is
-    the merge: phases are disjoint slices of task wall time)."""
+    the merge: phases are disjoint slices of task wall time). A task's
+    ``ledger.report_wait`` is left out: the scheduler counts it once a
+    stage, as wall time (HANDOFF_PHASES)."""
     out: Dict[str, float] = {}
     for p in payloads or ():
         for key, v in (p.get("phases") or {}).items():
             if key.startswith("ledger."):
                 phase = key[len("ledger."):]
+                if phase in HANDOFF_PHASES:
+                    continue
                 try:
                     out[phase] = out.get(phase, 0.0) + float(v)
                 except (TypeError, ValueError):
@@ -226,6 +261,23 @@ class LedgerLog:
         entry.setdefault("recorded_at", time.time())
         with self._lock:
             self._ring.append(entry)
+
+    def add_phase(self, job_id: str, phase: str, seconds: float) -> bool:
+        """Add wall time that followed the recorded ``wall_seconds`` of
+        ``job_id``'s newest entry (``client_poll_wait``: the job was
+        terminal, nobody had read it yet): the phase and the wall grow
+        by the same seconds, so the row still reconstructs."""
+        with self._lock:
+            for entry in reversed(self._ring):
+                if entry.get("job_id") == job_id:
+                    phases = dict(entry.get("phases") or {})
+                    phases[phase] = round(
+                        phases.get(phase, 0.0) + seconds, 6)
+                    entry["phases"] = phases
+                    entry["wall_seconds"] = round(
+                        float(entry.get("wall_seconds", 0.0)) + seconds, 6)
+                    return True
+        return False
 
     def entries(self, since: Optional[float] = None) -> List[dict]:
         with self._lock:

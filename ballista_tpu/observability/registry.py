@@ -115,6 +115,11 @@ PROCESS_METRICS = {
                                                   "executor drain)"),
     "ballista_tasks_dispatched_total": ("counter", "task definitions "
                                                    "handed to executors"),
+    "ballista_tasks_speculated_total": ("counter", "straggler tasks "
+                                                   "duplicated onto another "
+                                                   "executor "
+                                                   "(scheduler.speculate "
+                                                   "events)"),
     "ballista_ready_queue_depth": ("gauge", "tasks in the ready queue"),
     # live progress plane (scheduler)
     "ballista_tasks_running": ("gauge", "tasks currently running across "

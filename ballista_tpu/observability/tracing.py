@@ -46,6 +46,23 @@ what executors mine for the per-task profile windows shipped back with
 the same record dict a file write would but skip the JSON encode and
 the lock, so the measured warm-query overhead stays under the 5% gate.
 
+**One clock with the device trace**: while a ``jax.profiler`` session
+records, every span and event also opens a profiler ``TraceAnnotation``
+of the same name, so the program's spans are host events in the same
+``.xplane.pb`` as the device lines (``perfbench/xplane.py`` names an
+idle gap of the device by the innermost host event over it). Outside a
+session that costs one ``TraceMe.is_enabled()`` test.
+
+**Totals per span name**: :func:`span_totals` gives count and seconds
+per name for every span and event emitted since the process started,
+kept at emit time under a lock of their own: no ring bounds them, and
+they are kept whether or not the ring or a file is on.
+
+**Spans kept out of the ring**: a span whose ``record`` attribute is
+set to False before it ends (the poll waits of an executor with no task
+in flight and no report pending) reaches the profiler annotation and
+the totals only; an idle cluster's polls never turn the ring over.
+
 **Process identity**: :func:`set_process_identity` stamps a role
 (``scheduler`` / ``executor``) and short executor id onto every record
 emitted by this process (``role`` / ``exec`` keys), so a merged
@@ -67,8 +84,13 @@ from collections import deque
 from contextlib import contextmanager
 from typing import Optional
 
+from jax.profiler import TraceAnnotation  # the package imports jax first
+
 _lock = threading.Lock()
 _state: dict = {"configured": False, "fh": None, "ring": None}
+# name -> [count, seconds] since process start (span_totals)
+_totals: dict = {}
+_totals_lock = threading.Lock()
 _span_ids = itertools.count(1)
 _tls = threading.local()
 # (role, short executor id) — set once per process; survives
@@ -308,6 +330,36 @@ def _emit(record: dict) -> None:
             pass
 
 
+def _tally(name: str, seconds: float) -> None:
+    with _totals_lock:
+        t = _totals.get(name)
+        if t is None:
+            _totals[name] = [1, seconds]
+        else:
+            t[0] += 1
+            t[1] += seconds
+
+
+def span_totals() -> dict:
+    """``{name: {"count": n, "seconds": s}}`` for every span and event
+    emitted since the process started (events count with 0 seconds).
+    Kept at emit time, so neither the ring's size nor
+    ``BALLISTA_FLIGHT_RECORDER=0`` bounds or blinds them."""
+    with _totals_lock:
+        return {name: {"count": t[0], "seconds": t[1]}
+                for name, t in _totals.items()}
+
+
+def _annotate(name: str):
+    """An entered profiler annotation of this name while a profiler
+    session records, else None."""
+    if not TraceAnnotation.is_enabled():
+        return None
+    a = TraceAnnotation(name)
+    a.__enter__()
+    return a
+
+
 def _base_record(name: str, attrs: dict) -> dict:
     rec = {"name": name, "ts": time.time(),
            "pid": os.getpid(), "tid": threading.get_ident()}
@@ -323,6 +375,10 @@ def _base_record(name: str, attrs: dict) -> dict:
 def trace_event(name: str, **attrs) -> None:
     """Instant event (no duration). Carries the enclosing span's id as
     ``psid`` so it nests in the reconstructed tree."""
+    _tally(name, 0.0)
+    a = _annotate(name)
+    if a is not None:
+        a.__exit__(None, None, None)
     if not _recording():
         return
     rec = _base_record(name, attrs)
@@ -336,37 +392,47 @@ class trace_span:
     """``with trace_span("executor.task", task=key): ...`` — records one
     line with the span's start time and duration (exceptions are noted
     as ``error=<ExcType>`` and re-raised). Each span gets a process-
-    local ``sid`` and its enclosing span's ``psid``."""
+    local ``sid`` and its enclosing span's ``psid``. After the block
+    ``dur`` holds its seconds; ``record = False``, set before the block
+    ends, keeps it out of the ring and the file (module docstring)."""
 
-    __slots__ = ("name", "attrs", "_t0", "_sid", "_psid")
+    __slots__ = ("name", "attrs", "record", "dur", "_t0", "_sid", "_psid",
+                 "_ann")
 
     def __init__(self, name: str, **attrs):
         self.name = name
         self.attrs = attrs
+        self.record = True
+        self.dur = 0.0
 
     def __enter__(self):
-        if not _recording():
-            self._t0 = None
-            return self
+        self._ann = _annotate(self.name)
+        self._sid = None
+        if _recording():
+            st = _span_stack()
+            self._psid = st[-1] if st else None
+            self._sid = next(_span_ids)
+            st.append(self._sid)
         self._t0 = time.time()
-        st = _span_stack()
-        self._psid = st[-1] if st else None
-        self._sid = next(_span_ids)
-        st.append(self._sid)
         return self
 
     def __exit__(self, exc_type, exc, tb):
-        if self._t0 is not None:
+        self.dur = time.time() - self._t0
+        if self._ann is not None:
+            self._ann.__exit__(None, None, None)
+        _tally(self.name, self.dur)
+        if self._sid is not None:
             st = _span_stack()
             if st and st[-1] == self._sid:
                 st.pop()
-            rec = _base_record(self.name, self.attrs)
-            rec["ts"] = self._t0
-            rec["dur"] = time.time() - self._t0
-            rec["sid"] = self._sid
-            if self._psid is not None:
-                rec["psid"] = self._psid
-            if exc_type is not None:
-                rec["error"] = exc_type.__name__
-            _emit(rec)
+            if self.record:
+                rec = _base_record(self.name, self.attrs)
+                rec["ts"] = self._t0
+                rec["dur"] = self.dur
+                rec["sid"] = self._sid
+                if self._psid is not None:
+                    rec["psid"] = self._psid
+                if exc_type is not None:
+                    rec["error"] = exc_type.__name__
+                _emit(rec)
         return False

@@ -104,6 +104,38 @@ def test_governed_entry_shared_and_counted():
     assert f1.calls >= 1
 
 
+def test_governed_programs_are_named_by_key_family():
+    """Two governed functions of different families lower to XLA modules
+    named for their families, not for the inner function (`run`) their
+    builds return; a shared kernel function is wrapped, not renamed."""
+    import jax.numpy as jnp
+
+    from ballista_tpu.compile.governor import program_name
+
+    def shared_kernel(x):
+        return x * 2
+
+    def build_run():
+        def run(x):
+            return x + 1
+        return run
+
+    probe = governed(("join.probe_dense", ("sig", 1 << 20), "inner"),
+                     build_run)
+    compact = governed(("batch.compact", 4096), lambda: shared_kernel)
+    x = jnp.arange(8)
+    assert "@jit_join_probe_dense" in probe.fn.lower(x).as_text()
+    assert "@jit_batch_compact" in compact.fn.lower(x).as_text()
+    assert "jit_run" not in probe.fn.lower(x).as_text()
+    assert shared_kernel.__name__ == "shared_kernel"
+    assert int(probe(x)[1]) == 2 and int(compact(x)[1]) == 2
+    # no shapes, no literals, short
+    assert program_name(("pipeline.fused.don", ("Filter: x > 3",))) == \
+        "pipeline_fused_don"
+    assert len(program_name(("a" * 80,))) < 40
+    assert program_name(()) == "governed"
+
+
 def test_governed_namespace_eviction():
     gov = governor()
     gov.clear("test.evict")

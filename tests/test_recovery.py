@@ -223,10 +223,17 @@ def test_shuffle_fetch_error_parse_with_class_prefix():
 def test_speculative_execution_of_stragglers(tmp_path):
     """An idle executor gets a DUPLICATE of a long-running task (the
     reference has no speculation at all); first completion wins."""
+    from ballista_tpu.observability.tracing import span_totals
+
+    def speculated():
+        return span_totals().get("scheduler.speculate",
+                                 {"count": 0})["count"]
+
     svc = SchedulerService(SchedulerState(MemoryBackend()),
                            speculation_age_secs=0.05)
     e1 = _make_executor(tmp_path, "e1")
     e2 = _make_executor(tmp_path, "e2")
+    speculated0 = speculated()
     try:
         job_id = _submit_groupby(svc, _source(tmp_path))
         # e1 takes both producer tasks but "hangs" (never reports back):
@@ -244,6 +251,11 @@ def test_speculative_execution_of_stragglers(tmp_path):
         # e1's stuck tasks and actually runs them
         ran = [_pump(svc, e2), _pump(svc, e2)]
         assert all(r is not None for r in ran)
+        # each duplicate is one scheduler.speculate event, counted by
+        # name and exported beside the dispatch counter
+        assert speculated() - speculated0 == 2
+        samples = {name: v for name, _, v in svc._metric_samples()}
+        assert samples["ballista_tasks_speculated_total"] == speculated()
         for _ in range(6):
             _pump(svc, e2)
             if svc.state.get_job_status(job_id).state == "completed":
@@ -251,6 +263,7 @@ def test_speculative_execution_of_stragglers(tmp_path):
         assert svc.state.get_job_status(job_id).state == "completed"
         # each task is duplicated at most once
         assert svc.state.speculative_task(age_secs=0.0) is None
+        assert speculated() - speculated0 == 2
     finally:
         for e in (e1, e2):
             e._data_plane.close()
