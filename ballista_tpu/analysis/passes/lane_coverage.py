@@ -56,6 +56,22 @@ UNMAPPED_ALLOWLIST = {
     "executor.poll",
     "client.poll_wait",
     "client.poll",
+    # the same hand-off where the scheduler holds a call for the event
+    # it waits for (a ready task, a terminal status): a thread waiting
+    # on a condition, named for the device trace and counted; a refused
+    # hold is a dur=0 marker kept out of the ring
+    "scheduler.poll_held",
+    "scheduler.status_held",
+    "scheduler.hold_refused",
+    # marker events (dur=0) counting how often each wait of the
+    # hand-off ended on its event: a poll sent because a task ended, a
+    # poll sent because a slot was still free, a report that sat out a
+    # timer, a held call the event ended
+    "executor.report_now",
+    "executor.refill",
+    "executor.report_waited",
+    "scheduler.poll_woken",
+    "scheduler.status_woken",
     # marker events (dur=0) read as counts from tracing.span_totals():
     # a straggler duplicated (ballista_tasks_speculated_total) and a
     # completion report sent without its profile window

@@ -20,7 +20,12 @@ from .. import serde
 from .dataplane import fetch_partition_bytes
 from .scheduler import SchedulerClient
 
-POLL_SECS = 0.1  # reference: 100ms, context.rs:183-201
+# how long the scheduler may hold one status call of wait_for_job (it
+# answers the moment the job turns terminal), and so the cadence of
+# progress callbacks and of the timeout check; slept away here only when
+# the scheduler would not hold the call (reference: a 100ms sleep between
+# polls, context.rs:183-201)
+POLL_SECS = 0.1
 
 
 def _deadline_secs(settings: Optional[Dict[str, str]]) -> float:
@@ -186,9 +191,12 @@ def wait_for_job(host: str, port: int, job_id: str,
     try:
         deadline = time.time() + timeout
         while True:
+            began = time.monotonic()
             with trace_span("client.poll", job=job_id):
-                result = client.GetJobStatus(
-                    pb.GetJobStatusParams(job_id=job_id))
+                # held no longer than this client still means to wait
+                result = client.GetJobStatus(pb.GetJobStatusParams(
+                    job_id=job_id, wait_secs=max(
+                        min(POLL_SECS, deadline - time.time()), 0.0)))
             which = result.status.WhichOneof("status")
             if which == "completed":
                 # terminal callback: the tracker's frozen final
@@ -247,9 +255,12 @@ def wait_for_job(host: str, port: int, job_id: str,
                     f"job {job_id} timed out after {timeout:.1f}s",
                     job_id=job_id,
                 )
-            # what a terminal job waits for before its client reads it
-            with trace_span("client.poll_wait", job=job_id):
-                time.sleep(POLL_SECS)
+            # the fallback: what is left of the interval when the
+            # scheduler did not hold the call for all of it
+            left = POLL_SECS - (time.monotonic() - began)
+            if left > 0:
+                with trace_span("client.poll_wait", job=job_id):
+                    time.sleep(left)
     finally:
         client.close()
 

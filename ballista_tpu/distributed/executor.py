@@ -38,7 +38,11 @@ from .types import PartitionId
 
 log = logging.getLogger("ballista.executor")
 
-POLL_INTERVAL_SECS = 0.25  # reference: 250ms, execution_loop.rs:41
+# heartbeat and fallback: every wait of the hand-off ends on the event it
+# waits for (a task ends, a slot is free, a task becomes ready); this is
+# how long one lasts when no event ends it (reference: 250ms,
+# execution_loop.rs:41, where it is the only way a wait ends)
+POLL_INTERVAL_SECS = 0.25
 # total task-profile bytes one PollWork may carry (well under the
 # transport's raised 64 MB cap; see scheduler._GRPC_MSG_OPTS)
 _POLL_PROFILE_BUDGET_BYTES = 8 << 20
@@ -137,6 +141,19 @@ class Executor:
         self._pending_status = []
         self._stop = threading.Event()
         self._thread: Optional[threading.Thread] = None
+        # event-driven hand-off: a task that ends sets _task_ended
+        # (after its slot is free) and the report thread polls at once,
+        # so the report never waits for the timer nor behind the poll
+        # thread's call, which the scheduler may be holding; once the
+        # report thread is done and a slot is free it sets _slot_free,
+        # which ends the poll thread's wait so that it offers the slot
+        # in a call the scheduler can hold. _asking counts polls in
+        # flight that offered a slot (under _token_lock): a drain waits
+        # for them, each may still bring a task.
+        self._task_ended = threading.Event()
+        self._slot_free = threading.Event()
+        self._report_thread: Optional[threading.Thread] = None
+        self._asking = 0
         # lifecycle control plane: one cancel token per active task
         # (registered BEFORE the pool accepts the work so drain sees
         # queued-but-unstarted tasks too), the draining flag PollWork
@@ -225,6 +242,11 @@ class Executor:
             target=self._poll_loop, daemon=True, name=f"poll-{self.id[:8]}"
         )
         self._thread.start()
+        self._report_thread = threading.Thread(
+            target=self._report_loop, daemon=True,
+            name=f"report-{self.id[:8]}"
+        )
+        self._report_thread.start()
 
     def stop(self, drain: bool = False,
              drain_timeout: Optional[float] = None):
@@ -240,10 +262,13 @@ class Executor:
         if drain:
             self._drain(drain_timeout)
         self._stop.set()
-        if self._thread:
-            self._thread.join(timeout=5)
+        self._task_ended.set()
+        self._slot_free.set()
+        for t in (self._thread, self._report_thread):
+            if t:
+                t.join(timeout=5)
         if drain:
-            # final flush AFTER the poll thread stopped: whatever
+            # final flush AFTER the poll threads stopped: whatever
             # reports the last in-flight tasks appended still reach the
             # scheduler even though no more polls will run
             try:
@@ -271,7 +296,10 @@ class Executor:
         deadline = time.time() + bound
         log.info("draining executor %s: %d active task(s), bound %.1fs",
                  self.id[:8], len(self._task_tokens), bound)
-        while time.time() < deadline and self._task_tokens:
+        # a poll in flight that offered a slot (the scheduler may hold
+        # it for up to an interval) can still bring a task: in flight too
+        while time.time() < deadline and (self._task_tokens
+                                          or self._asking):
             time.sleep(0.05)
         leftover = self._fire_tokens(reason="drain")
         if leftover:
@@ -318,14 +346,32 @@ class Executor:
             params.task_status.append(st)
         self._client.PollWork(params)
 
-    # -- poll loop (reference: execution_loop.rs:31-76) ----------------------
+    # -- poll loops (reference: execution_loop.rs:31-76) ---------------------
+    #
+    # The hand-off has four waits, and each ends on the event it waits
+    # for; POLL_INTERVAL_SECS is only the heartbeat and the fallback:
+    # - a finished task's report: the task sets _task_ended once its slot
+    #   is free and the report thread polls at once (executor.report_now);
+    # - a free slot after a hand-out: whichever thread was handed a task
+    #   asks again at once (executor.refill);
+    # - an idle slot: the poll thread offers it in a call that lets the
+    #   scheduler hold it until a task becomes ready (wait_secs);
+    # - the client's wait is the scheduler's (GetJobStatusParams.wait_secs).
+    # Reports never travel behind a held call: the report thread's calls
+    # are not held, and both threads carry whatever is pending.
 
     def _poll_loop(self):
+        """The timer's thread: one poll an interval as heartbeat and
+        fallback, sooner when the last one brought a task, or when the
+        report thread says a slot is free that no held call offers yet;
+        the jittered back-off while the scheduler is unreachable."""
         failures = 0
         backoff = 0.0
         while not self._stop.is_set():
+            began = time.monotonic()
+            self._slot_free.clear()
             try:
-                self._poll_once()
+                again = self._poll_once(hold=True)
             except Exception as e:  # noqa: BLE001 - retry like reference
                 # jittered exponential backoff (reset on success): a
                 # scheduler restart must not face a thundering herd of
@@ -342,26 +388,64 @@ class Executor:
                         "poll still failing (%d consecutive; %s: %s); "
                         "next retry in %.2fs", failures,
                         type(e).__name__, e, wait)
-                self._poll_wait(wait)
+                self._poll_wait(wait, self._stop)
                 continue
             if failures:
                 log.info("scheduler reachable again after %d failed "
                          "poll(s)", failures)
             failures = 0
             backoff = 0.0
-            self._poll_wait(POLL_INTERVAL_SECS)
+            if not again:
+                self._poll_wait(
+                    POLL_INTERVAL_SECS - (time.monotonic() - began),
+                    self._slot_free)
 
-    def _poll_wait(self, seconds: float):
-        """The sleep between two polls, as an ``executor.poll_wait``
-        span: what a ready task waits for before it is picked up, and a
-        finished one before it is reported. With no task in flight and
-        no report pending when it ends, the span stays out of the
+    def _report_loop(self):
+        """The events' thread: polls the moment a task of this executor
+        has ended, so its report leaves within a round trip and the
+        reply can bring the next stage's task, then again while a poll
+        brought a task and a slot is still free. These calls are never
+        held. One that fails leaves its reports (re-fronted) to the
+        timer's thread, which owns retry and back-off."""
+        while True:
+            self._task_ended.wait()
+            if self._stop.is_set():
+                return
+            self._task_ended.clear()
+            trace_event("executor.report_now", executor=self.id[:8])
+            try:
+                while self._poll_once():
+                    pass
+            except Exception as e:  # noqa: BLE001 - the timer retries
+                log.warning("report poll failed (%s: %s); the next timed "
+                            "poll re-delivers", type(e).__name__, e)
+            if self._has_free_slot():
+                self._slot_free.set()
+
+    def _has_free_slot(self) -> bool:
+        free = self._slots.acquire(blocking=False)
+        if free:
+            self._slots.release()
+        return free
+
+    def _poll_wait(self, seconds: float, until: threading.Event):
+        """What is left of the interval after a poll (all of it when
+        the scheduler did not hold the call), unless ``until`` is set
+        first, as an ``executor.poll_wait`` span. With no task in flight
+        and no report pending when it ends, the span stays out of the
         flight recorder (profiler annotation and span totals only): an
         idle cluster must not turn the ring over with its waits."""
+        if seconds <= 0:
+            return
         with trace_span("executor.poll_wait",
                         executor=self.id[:8]) as span:
-            self._stop.wait(seconds)
-            span.record = self._inflight > 0 or bool(self._pending_status)
+            ended_early = until.wait(seconds)
+            late = bool(self._pending_status)
+            span.record = self._inflight > 0 or late
+        if late and not ended_early:
+            # a report sat out a wait that no event ended: the report
+            # thread's poll failed (should read 0 a query)
+            trace_event("executor.report_waited", executor=self.id[:8])
 
     def _drop_profile(self, st) -> None:
         """Send a completion report without its profile window, and
@@ -373,14 +457,53 @@ class Executor:
         trace_event("executor.profile_dropped", executor=self.id[:8],
                     task=f"{pid.job_id}/{pid.stage_id}/{pid.partition_id}")
 
-    def _poll_once(self):
-        can_accept = self._slots.acquire(blocking=False)
-        if can_accept:
-            self._slots.release()
-        if self._draining:
-            # graceful drain: finish what's in flight, accept nothing new
-            can_accept = False
-        params = pb.PollWorkParams(can_accept_task=can_accept)
+    def _poll_once(self, hold: bool = False) -> bool:
+        """One ``PollWork`` round trip: heartbeat, every pending report,
+        and a slot offered if one is free. ``hold`` (the timer's thread)
+        lets the scheduler keep the call until a task becomes ready, if
+        it offers a slot and carries no report. Returns True when the
+        caller should ask again at once: a slot is free, and either the
+        reply brought a task (``executor.refill``) or this was the
+        timer's call and could not be held."""
+        # the slot this poll offers stays claimed while the call is in
+        # flight: both threads may be asking, and together they must
+        # never be handed more tasks than there are slots. (A draining
+        # executor finishes what is in flight and offers nothing.)
+        offered = not self._draining and \
+            self._slots.acquire(blocking=False)
+        if offered:
+            with self._token_lock:
+                self._asking += 1
+        started = False
+        try:
+            td, holdable = self._exchange(offered, hold)
+            if self._stop.is_set():
+                td = None  # stopped while the call was held: abandon it
+            if td is not None:
+                if not offered:
+                    # a task nobody asked for: it waits for a slot, as
+                    # it did when every hand-out did
+                    self._slots.acquire()
+                started = True
+                self._run_task(td)
+        finally:
+            if offered:
+                if not started:
+                    self._slots.release()
+                with self._token_lock:
+                    self._asking -= 1
+        if self._draining or not self._has_free_slot():
+            return False
+        if td is not None:
+            trace_event("executor.refill", executor=self.id[:8])
+            return True
+        return hold and not holdable
+
+    def _exchange(self, offered: bool, hold: bool):
+        """The round trip of ``_poll_once``: returns the task the reply
+        brought, if any, and whether the scheduler could hold the
+        call."""
+        params = pb.PollWorkParams(can_accept_task=offered)
         params.metadata.id = self.id
         params.metadata.host = self.config.host
         params.metadata.port = self.port
@@ -408,6 +531,9 @@ class Executor:
         # message limit — a failed PollWork would LOSE the completion
         # reports it carried (pending was already cleared) and hang the
         # job. Reports always go; overflow profiles are dropped.
+        holdable = hold and offered and not pending
+        if holdable:
+            params.wait_secs = POLL_INTERVAL_SECS
         budget = _POLL_PROFILE_BUDGET_BYTES
         sending = time.time()
         for st in pending:
@@ -450,8 +576,7 @@ class Executor:
             log.warning("executor %s: scheduler requested drain; no "
                         "longer accepting tasks", self.id[:8])
             self._draining = True
-        if result.HasField("task"):
-            self._run_task(result.task)
+        return (result.task if result.HasField("task") else None), holdable
 
     def _stamp_report_wait(self, st, sending: float) -> None:
         """``ledger.report_wait`` on a completion's profile: seconds on
@@ -553,7 +678,8 @@ class Executor:
     # -- task execution (in-process; reference: run_received_tasks) ----------
 
     def _run_task(self, td: pb.TaskDefinition):
-        self._slots.acquire()
+        """Start the task on the pool; the caller has claimed its slot,
+        and the task's end (or its rejection here) frees it."""
         pid = PartitionId(td.task_id.job_id, td.task_id.stage_id,
                           td.task_id.partition_id)
         # per-task cancel token: registered BEFORE the pool accepts the
@@ -589,6 +715,7 @@ class Executor:
             self.tasks_failed += 1
             self._report_failed(pid, f"{type(e).__name__}: {e}",
                                 td.stage_version)
+            self._task_ended.set()
             return
 
         def work():
@@ -709,6 +836,9 @@ class Executor:
                     self._running_plans.pop(pid.key(), None)
                 self._inflight -= 1
                 self._slots.release()
+                # AFTER the slot is free: the poll this wakes offers it,
+                # so the reply can bring the next stage's task
+                self._task_ended.set()
 
         self._pool.submit(work)
 

@@ -55,6 +55,11 @@ _GRPC_MSG_OPTS = [
     ("grpc.max_receive_message_length", 64 << 20),
 ]
 
+# the longest one call is held for the event it waits for, whatever its
+# caller asks (PollWorkParams.wait_secs, GetJobStatusParams.wait_secs)
+MAX_HOLD_SECS = 1.0
+_TERMINAL = ("completed", "failed", "cancelled")
+
 
 def _fuse_mesh_stages(stages, n_mesh: int):
     """ICI fast path: collapse a hash-shuffle stage + its final-aggregate
@@ -336,6 +341,15 @@ class SchedulerService:
             lambda job_id, wait: self._ledger_stamp(
                 job_id, "queue_wait", wait))
         self.tasks_dispatched = 0
+        # event-driven hand-off: a call held for the event it waits for
+        # (a ready task, a terminal status) occupies one worker of the
+        # gRPC server's pool until it ends, so at most max_held_calls
+        # are held at once and the rest are answered at once (their
+        # callers fall back to their timers). serve_scheduler sets it
+        # from the pool's own size; a service with no server holds none.
+        self.max_held_calls = 0
+        self._held_calls = 0
+        self._held_lock = threading.Lock()
         if metrics_port is None:
             metrics_port = metrics_port_from_env(-1)
         self.health = maybe_start_health_server(
@@ -1010,6 +1024,45 @@ class SchedulerService:
             job_id, len(stages), 1000 * (time.time() - t0),
         )
 
+    # -- held calls (event-driven hand-off) ---------------------------------
+
+    def _begin_hold(self) -> bool:
+        """Claim one of the workers a held call may occupy; a call that
+        gets none is answered at once and counted
+        (``span_totals()["scheduler.hold_refused"]``, kept out of the
+        ring: past the cap every idle poll is one)."""
+        with self._held_lock:
+            if self._held_calls < self.max_held_calls:
+                self._held_calls += 1
+                return True
+        with trace_span("scheduler.hold_refused") as span:
+            span.record = False
+        return False
+
+    def _end_hold(self) -> None:
+        with self._held_lock:
+            self._held_calls -= 1
+
+    def _hold_for_task(self, meta: ExecutorMeta, wait_secs: float
+                       ) -> Optional[PartitionId]:
+        """Hold an idle executor's poll until a task it can run becomes
+        ready, a job is cancelled, or the bound passes. A hold the bound
+        ended stays out of the flight recorder, like the idle poll it
+        replaces."""
+        if not self._begin_hold():
+            return None
+        try:
+            with trace_span("scheduler.poll_held",
+                            executor=meta.id[:8]) as span:
+                task, woken = self.state.wait_next_task(
+                    meta.num_devices, min(wait_secs, MAX_HOLD_SECS))
+                span.attrs["woken"] = span.record = woken
+        finally:
+            self._end_hold()
+        if woken:
+            trace_event("scheduler.poll_woken", executor=meta.id[:8])
+        return task
+
     # -- RPC: PollWork ------------------------------------------------------
 
     def PollWork(self, request: pb.PollWorkParams, context=None):
@@ -1153,6 +1206,13 @@ class SchedulerService:
                     # ballista_tasks_speculated_total
                     trace_event("scheduler.speculate", task=task.key(),
                                 job=task.job_id, executor=meta.id[:8])
+            if task is None and request.wait_secs > 0 and \
+                    not request.task_status and not jobs_touched:
+                # nothing for an idle executor that lets us hold its
+                # call: wait for the event, not for its next poll
+                task = self._hold_for_task(meta, request.wait_secs)
+                if meta.id in self.drain_requests:
+                    result.drain = True
             if task is not None:
                 try:
                     # a SPAN (not an instant): its duration is the real
@@ -1278,8 +1338,22 @@ class SchedulerService:
         self.state.reap_expired_jobs()
         self.admission.pump()
         st = self.state.get_job_status(request.job_id)
-        if st is not None and st.state in ("completed", "failed",
-                                           "cancelled"):
+        if request.wait_secs > 0 and st is not None and \
+                st.state not in _TERMINAL and self._begin_hold():
+            # a waiting client (wait_for_job): answer when the job turns
+            # terminal, not at the client's next poll
+            try:
+                with trace_span("scheduler.status_held",
+                                job=request.job_id) as span:
+                    st, woken = self.state.wait_job_terminal(
+                        request.job_id,
+                        min(request.wait_secs, MAX_HOLD_SECS))
+                    span.attrs["woken"] = woken
+            finally:
+                self._end_hold()
+            if woken:
+                trace_event("scheduler.status_woken", job=request.job_id)
+        if st is not None and st.state in _TERMINAL:
             self._note_terminal_read(request.job_id)
         result = pb.GetJobStatusResult()
         if st is None:
@@ -1503,8 +1577,11 @@ def serve_scheduler(state: SchedulerState, host: str = "0.0.0.0",
             request_deserializer=req_t.FromString,
             response_serializer=lambda m: m.SerializeToString(),
         )
-    server = grpc.server(futures.ThreadPoolExecutor(max_workers=max_workers),
-                         options=_GRPC_MSG_OPTS)
+    pool = futures.ThreadPoolExecutor(max_workers=max_workers)
+    # held calls may occupy half of the workers this pool really has;
+    # the other half always answers reports, submissions and reads
+    svc.max_held_calls = pool._max_workers // 2
+    server = grpc.server(pool, options=_GRPC_MSG_OPTS)
     server.add_generic_rpc_handlers(
         (grpc.method_handlers_generic_handler(SERVICE, handlers),)
     )

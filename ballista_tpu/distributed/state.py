@@ -191,6 +191,12 @@ class SchedulerState:
         self._lock = threading.RLock()
         # ready-queue of (job_id, stage_id, partition) runnable now
         self._ready: List[PartitionId] = []
+        # the hand-off's one wake-up: notified (under self._lock) when a
+        # task becomes ready, a job is cancelled or a job's status turns
+        # terminal. A held PollWork (wait_next_task) and a held
+        # GetJobStatus (wait_job_terminal) wait on it, each re-checking
+        # its own predicate when woken.
+        self._changed = threading.Condition(self._lock)
         # stage dependency bookkeeping: (job, stage) -> [dep stage ids]
         self._stage_deps: Dict[Tuple[str, int], List[int]] = {}
         self._stage_parts: Dict[Tuple[str, int], int] = {}
@@ -235,6 +241,7 @@ class SchedulerState:
         # scheduler re-queues work but drops pending deadlines), and
         # the deadline-scan throttle stamp — all guarded by self._lock
         self._cancelled_jobs: Dict[str, float] = {}
+        self._cancels = 0  # jobs cancelled so far (ends held polls)
         self._job_deadlines: Dict[str, float] = {}
         self._last_deadline_scan = 0.0
         # hand-off phases of the latency ledger (observability/ledger.py
@@ -432,6 +439,10 @@ class SchedulerState:
                                        query_log=self.query_log)
             with self._lock:  # the hook took it; without one, drop it
                 self._handoff.pop(job_id, None)
+                # AFTER the hook recorded the job's ledger row: a held
+                # status read woken here finds the row to patch
+                # (client_poll_wait)
+                self._changed.notify_all()
 
     def get_job_status(self, job_id: str) -> Optional[JobStatus]:
         v = self.kv.get(self._k("jobs", job_id))
@@ -516,6 +527,9 @@ class SchedulerState:
                                                   "cancelled"):
                 return False
             self._cancelled_jobs[job_id] = time.time()
+            # held polls end here, so their replies carry the id now
+            self._cancels += 1
+            self._changed.notify_all()
             # queued tasks stop here; running ones abort executor-side
             self._ready = [p for p in self._ready if p.job_id != job_id]
             self.save_job_status(job_id, JobStatus(
@@ -808,6 +822,8 @@ class SchedulerState:
                 self._ready.append(PartitionId(job_id, stage_id, p))
         # a task's ready time is now, if none of the job's is out
         self._handoff_tick(job_id)
+        with self._lock:  # every caller holds it already
+            self._changed.notify_all()
 
     # -- hand-off phases (observability/ledger.HANDOFF_PHASES) ---------------
 
@@ -897,6 +913,44 @@ class SchedulerState:
                 self._handoff_tick(pid.job_id, handed=pid)
                 return pid
         return None
+
+    def wait_next_task(self, num_devices: int, timeout: float
+                       ) -> Tuple[Optional[PartitionId], bool]:
+        """A held ``PollWork``: the first ready task the caller can
+        run, waiting up to ``timeout`` seconds for one to become ready
+        (``_enqueue_stage``). Returns ``(task, woken)``; ``woken`` is
+        False when the bound ended the wait. A job cancelled meanwhile
+        ends it with no task, so the reply carries the id at once."""
+        end = time.monotonic() + timeout
+        with self._changed:
+            cancels = self._cancels
+            while True:
+                pid = self.next_task(num_devices)
+                if pid is not None:
+                    return pid, True
+                if self._cancels != cancels:
+                    return None, True
+                left = end - time.monotonic()
+                if left <= 0:
+                    return None, False
+                self._changed.wait(left)
+
+    def wait_job_terminal(self, job_id: str, timeout: float
+                          ) -> Tuple[Optional[JobStatus], bool]:
+        """A held ``GetJobStatus``: the job's status once it is terminal
+        (or unknown), else its status when ``timeout`` seconds have
+        passed. Returns ``(status, woken)`` like ``wait_next_task``."""
+        end = time.monotonic() + timeout
+        with self._changed:
+            while True:
+                st = self.get_job_status(job_id)
+                if st is None or st.state in ("completed", "failed",
+                                              "cancelled"):
+                    return st, True
+                left = end - time.monotonic()
+                if left <= 0:
+                    return st, False
+                self._changed.wait(left)
 
     def is_completed(self, pid: PartitionId) -> bool:
         v = self.kv.get(self._k("tasks", pid.job_id, pid.stage_id,

@@ -213,13 +213,23 @@ class ShuffleReaderExec(PhysicalPlan):
         from ..io import ipc
 
         m = self.metrics()
-        if not self.FORCE_REMOTE and loc.path and os.path.exists(loc.path):
-            m.add_counter("bytes_read", os.path.getsize(loc.path))
-            m.add_counter("local_reads")
-            _, arrays, nulls, dicts, _ = ipc.read_partition_arrays(loc.path)
-        else:
-            arrays, nulls, dicts = self._fetch_with_retry(loc)
-        return arrays, nulls, dicts
+        if not self.FORCE_REMOTE and loc.path:
+            try:
+                size = os.path.getsize(loc.path)
+                _, arrays, nulls, dicts, _ = \
+                    ipc.read_partition_arrays(loc.path)
+            except FileNotFoundError:
+                # not a file of this host, or one that went away under
+                # the read (its executor was lost and its work_dir with
+                # it): ask the data plane, whose failure is the tagged
+                # error the scheduler recovers from by re-queueing the
+                # producer
+                pass
+            else:
+                m.add_counter("bytes_read", size)
+                m.add_counter("local_reads")
+                return arrays, nulls, dicts
+        return self._fetch_with_retry(loc)
 
     def _load_group(self, q: int) -> List[ColumnBatch]:
         """Fetch only THIS output partition's files (a consumer task reads

@@ -337,6 +337,18 @@ def apply_controlplane(fdp: dp.FileDescriptorProto) -> None:
               F.TYPE_BOOL)
 
 
+def apply_handoff(fdp: dp.FileDescriptorProto) -> None:
+    """PR 26: event-driven hand-off (mirrored by hand in
+    ballista.proto) — how long the CALLER lets the scheduler hold the
+    call for the event it waits for: a ready task (PollWork) or the
+    job's terminal status (GetJobStatus). 0, and every caller that
+    predates the field, is answered at once."""
+    add_field(get_message(fdp, "PollWorkParams"), "wait_secs", 5,
+              F.TYPE_DOUBLE)
+    add_field(get_message(fdp, "GetJobStatusParams"), "wait_secs", 2,
+              F.TYPE_DOUBLE)
+
+
 TEMPLATE = '''# -*- coding: utf-8 -*-
 # Generated by dev/gen_proto_patch.py (no protoc in this image). DO NOT EDIT!
 # source: ballista.proto
@@ -373,6 +385,7 @@ def main() -> None:
     apply_spill(fdp)
     apply_admission(fdp)
     apply_controlplane(fdp)
+    apply_handoff(fdp)
     out = TEMPLATE.format(blob=fdp.SerializeToString())
     with open(PB2, "w") as f:
         f.write(out)

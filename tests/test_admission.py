@@ -709,7 +709,8 @@ def test_overload_sweep_bounds_and_byte_identity(tmp_path, faults_env,
 
 
 def test_overload_queue_full_sheds_are_structured(tmp_path,
-                                                  monkeypatch):
+                                                  monkeypatch,
+                                                  faults_env):
     """With a 1-deep queue and retry disabled, the overflow submission
     of a 3-burst single-session storm is shed queue-full; the other two
     complete byte-identical."""
@@ -727,6 +728,9 @@ def test_overload_queue_full_sheds_are_structured(tmp_path,
                                settings={"job.timeout": "60"})
         ctx0.register_tbl("t", path, TSCHEMA)
         expected = ctx0.sql(GROUPBY_SQL).collect()
+        # slow tasks, so the burst overlaps whatever the hand-off takes:
+        # the first job still runs when the third arrives
+        faults_env("executor.task.start=delay:400")
 
         results = {}
 
