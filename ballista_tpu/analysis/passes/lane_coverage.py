@@ -77,6 +77,10 @@ UNMAPPED_ALLOWLIST = {
     # completion report sent without its profile window
     "scheduler.speculate",
     "executor.profile_dropped",
+    # marker event (dur=0) counting compactions (physical/base.py
+    # maybe_compact); the time is the device's, under jit_batch_compact
+    # in a device trace
+    "compact.search",
     # cancellation marker event (dur=0): lifecycle, not latency
     "lifecycle.cancel",
     # adaptive re-planning markers: they fire INSIDE windows that are

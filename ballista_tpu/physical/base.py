@@ -24,6 +24,7 @@ from ..datatypes import Schema
 from ..errors import ExecutionError
 from ..observability.metrics import (MetricsSet, instrument_execute,
                                      metrics_enabled)
+from ..observability.tracing import trace_event
 
 
 @dataclass(frozen=True)
@@ -502,9 +503,15 @@ def maybe_compact(batch: ColumnBatch, shrink_factor: int = 4,
                   known_rows: Optional[int] = None,
                   floor: int = 8) -> ColumnBatch:
     """Shrink a sparse batch: when live rows fill under 1/shrink_factor
-    of the capacity, gather them to the front of a smaller batch. One
-    sort+gather now buys every downstream operator a smaller shape —
-    decisive after selective joins/filters in long pipelines.
+    of the capacity, gather them, in order, to the front of a smaller
+    batch on the bucket ladder, so every downstream operator runs on
+    the smaller shape — decisive after selective joins/filters in long
+    pipelines. One governed program per target rung: compact_perm finds
+    the survivors (one pass over the capacity, then a cost that follows
+    the rung, not the capacity thrown away), take_batch gathers them,
+    one gathered element per survivor and column. Each compaction counts
+    one ``compact.search`` event in ``tracing.span_totals()``, with the
+    capacity it came from.
 
     Pass ``known_rows`` when the live count is already on host (e.g. the
     join expand loop just synced its overflow check) — then this never
@@ -535,6 +542,7 @@ def maybe_compact(batch: ColumnBatch, shrink_factor: int = 4,
 
         return compact
 
+    trace_event("compact.search", rows=n, capacity=cap, to=new_cap)
     return governed(("batch.compact", new_cap), build, aot=True)(batch)
 
 
@@ -556,13 +564,78 @@ def pad_batch(batch: ColumnBatch, capacity: int) -> ColumnBatch:
     return ColumnBatch(batch.schema, cols, selection, batch.num_rows)
 
 
+# compact_perm's shapes (PERF.md, PR 29, has the chip table they were
+# read from). A gather of one row of _BLOCK running counts costs the chip
+# no more than a gather of one element, so the search descends in steps
+# of _BLOCK, not of 2; a level of at most _TOP counts is compared
+# whole; queries go _QUERY_CHUNK at a time so that the gathered rows stay
+# tens of MiB; the running count is taken _COUNT_ROW rows at a time
+# because XLA's TPU compiler spends 15-30 s on a one-pass cumsum over
+# 2**20 rows and under a second on the two-level one, at the same speed.
+_BLOCK = 128
+_TOP = 256
+_QUERY_CHUNK = 1 << 16
+_COUNT_ROW = 4096
+
+
+def _running_count(selection: jax.Array) -> jax.Array:
+    """How many live rows there are up to and including each row (int32:
+    the chip's lanes are 32-bit and a capacity fits)."""
+    n = selection.shape[0]
+    live = selection.astype(jnp.int32)
+    if n <= _COUNT_ROW:
+        return jnp.cumsum(live, dtype=jnp.int32)
+    rows = jnp.pad(live, (0, -n % _COUNT_ROW)).reshape(-1, _COUNT_ROW)
+    within = jnp.cumsum(rows, axis=1, dtype=jnp.int32)
+    ends = jnp.cumsum(within[:, -1], dtype=jnp.int32)
+    return (within + (ends - within[:, -1])[:, None]).reshape(-1)[:n]
+
+
 def compact_perm(selection: jax.Array, size: int) -> jax.Array:
-    """Gather permutation putting live rows first, in order: stable
-    front-compaction via static-size nonzero (cumsum + scatter, O(N)) —
-    a full lax.sort costs more than the compaction saves on large
-    capacities. Traced."""
-    return jnp.nonzero(selection, size=size, fill_value=0)[0] \
-        .astype(jnp.int32)
+    """Indices of the first ``size`` live rows, in order (0 where there
+    are fewer): the gather permutation of a stable front-compaction,
+    element for element ``jnp.nonzero(selection, size=size,
+    fill_value=0)[0]``. Traced.
+
+    The k-th live row is the first whose running count reaches k, so it
+    is SEARCHED for, for k = 1..size: the running count, then above it
+    the count at the end of every _BLOCK rows, and again until a level
+    is short enough to compare whole; a query walks down from there, one
+    gathered row of counts a level. One pass over the capacity plus
+    ``size`` row gathers a level (two levels under the top one at 2**20
+    rows): the cost follows the rows kept. There is no scatter (``jnp.nonzero`` sends
+    one update for EVERY row of the capacity, dead ones too, and the
+    chip scatters an element at a time: 72 ms at 2**20 rows whatever
+    survives) and no lax.sort."""
+    count = _running_count(selection)
+    total = count[-1]
+    levels, top = [], count  # levels: rows of _BLOCK counts, finest first
+    while top.shape[0] > _TOP:
+        # edge padding keeps the level sorted; only a k beyond the
+        # total, which is answered 0 below, can land in it
+        rows = jnp.pad(top, (0, -top.shape[0] % _BLOCK), mode="edge") \
+            .reshape(-1, _BLOCK)
+        levels.append(rows)
+        top = rows[:, -1]
+
+    def first_reaching(k):
+        """For a vector of k: the first row whose count reaches each."""
+        at = jnp.minimum(
+            jnp.sum(top[None, :] < k[:, None], axis=1, dtype=jnp.int32),
+            top.shape[0] - 1)
+        for rows in reversed(levels):
+            # rows[at] with a vector of row numbers: the one form of
+            # gather the chip does a whole row at a time
+            before = jnp.sum(rows[at] < k[:, None], axis=1, dtype=jnp.int32)
+            at = at * _BLOCK + jnp.minimum(before, _BLOCK - 1)
+        return jnp.where(k <= total, at, 0)
+
+    if size <= _QUERY_CHUNK:
+        return first_reaching(jnp.arange(1, size + 1, dtype=jnp.int32))
+    chunks = -(-size // _QUERY_CHUNK)  # the last one's k past size are cut off
+    kth = jnp.arange(1, chunks * _QUERY_CHUNK + 1, dtype=jnp.int32)
+    return jax.lax.map(first_reaching, kth.reshape(chunks, _QUERY_CHUNK)) \
+        .reshape(-1)[:size]
 
 
 def take_batch(batch: ColumnBatch, perm: jax.Array, live: jax.Array) -> ColumnBatch:

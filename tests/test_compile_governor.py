@@ -122,7 +122,10 @@ def test_governed_programs_are_named_by_key_family():
 
     probe = governed(("join.probe_dense", ("sig", 1 << 20), "inner"),
                      build_run)
-    compact = governed(("batch.compact", 4096), lambda: shared_kernel)
+    # not a rung: the governor is process-wide, and a real compaction to
+    # 4,096 rows later in this worker must not be handed this kernel
+    compact = governed(("batch.compact", "shared-kernel"),
+                       lambda: shared_kernel)
     x = jnp.arange(8)
     assert "@jit_join_probe_dense" in probe.fn.lower(x).as_text()
     assert "@jit_batch_compact" in compact.fn.lower(x).as_text()

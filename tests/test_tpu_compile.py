@@ -143,6 +143,23 @@ def test_join_build_sorted_at_first_rung(one_chip, no_disk_cache):
     _compile(join.build_sorted_with_unique, s(jnp.int64), s(jnp.bool_))
 
 
+@pytest.mark.parametrize("size", [16384, 131072, 1 << 20])
+def test_compact_perm_at_1m_rows_has_no_scatter(one_chip, no_disk_cache,
+                                                size):
+    """The join cells' compactions (2**20 rows to 16,384; to 131,072,
+    where the queries go in chunks) and the mesh path's worst case
+    (every row asked for). A scatter over the capacity is what
+    ``jnp.nonzero`` cost on the chip (PERF.md, PR 29); a ``while`` is
+    only the chunk loop's."""
+    from ballista_tpu.physical.base import compact_perm
+
+    compiled = _compile(functools.partial(compact_perm, size=size),
+                        _shape(one_chip, 1 << 20, jnp.bool_))
+    text = compiled.as_text()
+    assert " scatter(" not in text and " sort(" not in text
+    assert (" while(" in text) == (size > 65536)
+
+
 def test_mesh_all_to_all_rows_on_four_chips(topo, no_disk_cache):
     """The ICI shuffle as ONE program across the four described chips:
     the compiler must place an all-to-all, not gather to one device."""
