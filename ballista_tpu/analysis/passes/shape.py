@@ -47,9 +47,6 @@ class JitSitesRule(Rule):
 
     ALLOWLIST = frozenset({
         "ballista_tpu/compile/governor.py",  # THE jit site: the governor
-        # fused-stage AOT export wraps a governed entry's own python
-        # function for jax.export serialization — no uncounted cache
-        "ballista_tpu/compile/aot.py",
     })
     MARKER = "jit-ok:"
 

@@ -57,7 +57,7 @@ from .results import (  # noqa: F401
 
 def cache_counters() -> dict:
     """Flat counter snapshot across all three tiers — the per-JSON-line
-    fields bench.py / bench_serving.py emit and the regression lint
+    fields bench_serving.py emits and the regression lint
     tracks."""
     t = process_table_cache().stats()
     r = process_result_cache().stats()

@@ -502,7 +502,6 @@ class BallistaContext:
                     plan, PlannerOptions.from_settings(self.settings))
             # whole-stage fusion (physical/fusion.py): merge each
             # pipeline stage into one governed XLA program. Before
-            # prewarm (which targets fused-stage signatures) and before
             # the adaptive pass (fused stages survive re-planning via
             # with_new_children).
             from .physical.fusion import maybe_fuse
@@ -533,17 +532,6 @@ class BallistaContext:
             # drains pending device row-count scalars, which would
             # otherwise grow unboundedly when metrics are never read
             reset_plan_metrics(phys)
-        # optional (BALLISTA_PREWARM=1): AOT-compile scan-side pipeline
-        # chains in the background, overlapping XLA compile with the
-        # scan's parse + host-to-device upload. Must start BEFORE the
-        # adaptive pass: standalone adaptive eagerly materializes
-        # repartition inputs (parse + upload + chain compiles) on this
-        # thread, which is exactly the work prewarm wants to overlap.
-        # The chains prewarm targets are scan-rooted and unchanged by
-        # the adaptive rewrites.
-        from .compile import maybe_prewarm
-
-        maybe_prewarm(phys)
         # Parallel ingest (ballista_tpu/ingest): start parse+H2D for
         # every leaf scan NOW, so independent tables overlap each other
         # and the adaptive pass's eager repartition materialization

@@ -21,7 +21,6 @@ shuffle spill), where numpy boolean indexing is cheap.
 from __future__ import annotations
 
 import itertools
-import os
 from dataclasses import dataclass
 from typing import Dict, List, Optional, Sequence, Tuple
 
@@ -74,13 +73,7 @@ def _narrow_wire_enabled() -> bool:
     where jnp.asarray is a plain copy."""
     global _NARROW_WIRE
     if _NARROW_WIRE is None:
-        env = os.environ.get("BALLISTA_NARROW_WIRE", "").lower()
-        if env in ("on", "1", "true"):
-            _NARROW_WIRE = True
-        elif env in ("off", "0", "false"):
-            _NARROW_WIRE = False
-        else:
-            _NARROW_WIRE = jax.default_backend() != "cpu"
+        _NARROW_WIRE = jax.default_backend() != "cpu"
     return _NARROW_WIRE
 
 
@@ -119,9 +112,9 @@ class Dictionary:
     ride in pytree aux-data without defeating jit caching.
     """
 
-    __slots__ = ("values", "_index", "_tracked_bytes", "_aot_fp",
-                 "_str_cache", "_hash_cache", "_str_exact",
-                 "_reg_entry_id", "_reg_version", "_reg_epoch")
+    __slots__ = ("values", "_index", "_tracked_bytes", "_str_cache",
+                 "_hash_cache", "_str_exact", "_reg_entry_id",
+                 "_reg_version", "_reg_epoch")
 
     def __init__(self, values: Sequence[str]):
         self.values: np.ndarray = np.asarray(list(values), dtype=object)
@@ -170,26 +163,6 @@ class Dictionary:
     def encode(strings: Sequence[str]) -> Tuple["Dictionary", np.ndarray]:
         uniq, codes = np.unique(np.asarray(strings, dtype=object), return_inverse=True)
         return Dictionary(uniq), codes.astype(np.int32)
-
-    def content_fingerprint(self) -> str:
-        """Hex digest of the dictionary CONTENT (not identity) — the
-        fused-stage AOT cache keys compiled programs on it, because
-        traced programs bake dictionary values as constants. Cached per
-        instance (values are immutable by convention)."""
-        fp = getattr(self, "_aot_fp", None)
-        if fp is None:
-            import hashlib
-
-            h = hashlib.sha1()
-            for v in self.values:
-                b = str(v).encode("utf-8", "surrogatepass")
-                # length-prefixed: a separator alone is ambiguous when
-                # values can contain it (['a\x00','b'] vs ['a','\x00b'])
-                h.update(str(len(b)).encode())
-                h.update(b":")
-                h.update(b)
-            fp = self._aot_fp = h.hexdigest()
-        return fp
 
     # -- cached views / search primitives ----------------------------------
     #

@@ -28,10 +28,7 @@ This registry makes dictionary identity a managed resource, the way
   the cross-process currency: shuffle writers stamp them into Arrow IPC
   field metadata so readers resolve the SAME in-process instance (or
   adopt one, once, per epoch) instead of rebuilding values from the
-  wire; ``compile/aot.py`` keys artifacts on epochs so the per-value
-  Python fingerprint loop leaves the hot path and equal-content
-  dictionaries (rebuilt per process, per artifact, per dataset copy)
-  stop invalidating exported programs.
+  wire.
 - **Cached remaps/unions**: cross-entry pairs (join keys from different
   tables) and multi-producer unions are built once per
   (fingerprint, fingerprint) pair — C-level searchsorted over the
@@ -119,9 +116,7 @@ def values_fingerprint(sv: np.ndarray,
 def fingerprint(d: Dictionary) -> str:
     """Content fingerprint of any dictionary, cached on the instance.
     Registry members carry it from registration; others compute it
-    once, vectorized — this replaces the per-value Python loop of
-    ``Dictionary.content_fingerprint`` everywhere hot (compile/aot.py
-    keys on it). The object-length plane keeps a trailing-NUL value
+    once, vectorized. The object-length plane keeps a trailing-NUL value
     set (which the registry refuses to intern) from aliasing its
     stripped twin."""
     fp = d._reg_epoch
@@ -308,7 +303,7 @@ class DictionaryRegistry:
         with e.lock:
             return e.versions[-1] if e.versions else None
 
-    # -- cross-process stamps (Arrow IPC metadata, AOT output protos) ------
+    # -- cross-process stamps (Arrow IPC metadata) -------------------------
 
     def stamp_of(self, d: Optional[Dictionary]) -> Optional[str]:
         if d is None or not enabled() or d._reg_epoch is None:
@@ -334,8 +329,8 @@ class DictionaryRegistry:
             return d
 
     def adopt(self, stamp: Optional[str], values) -> Dictionary:
-        """Values received from another process (shuffle read, loaded
-        AOT artifact) -> ONE shared instance per content epoch. The
+        """Values received from another process (shuffle read) -> ONE
+        shared instance per content epoch. The
         stamp's epoch is verified against the actual values before any
         entry identity is trusted. Repeat adoptions of known content
         return the interned instance BEFORE building a Dictionary (the

@@ -1,6 +1,6 @@
 """Compile governor: kernel compilation as a managed, observable resource.
 
-Three parts (see docs/compile_cache.md):
+Two parts (see docs/compile_cache.md):
 
 - :mod:`buckets`  — shape canonicalization: batch capacities quantize
   onto a geometric row-count ladder (``BALLISTA_SHAPE_BUCKETS*`` knobs)
@@ -8,9 +8,7 @@ Three parts (see docs/compile_cache.md):
 - :mod:`governor` — the single process-wide jit cache replacing the
   per-instance/module ad-hoc dicts (adaptive re-plans now reuse every
   trace), with compile counts/seconds/cache hits flowing into operator
-  metrics, EXPLAIN ANALYZE and ``BALLISTA_TRACE`` spans;
-- :mod:`prewarm`  — optional AOT compilation of scan-side pipeline
-  chains concurrent with parse/H2D (``BALLISTA_PREWARM=1``).
+  metrics, EXPLAIN ANALYZE and ``BALLISTA_TRACE`` spans.
 
 ``dev/check_jit_sites.py`` (tier-1-run lint) keeps ``jax.jit`` call
 sites from regrowing outside this package.
@@ -32,4 +30,3 @@ from .governor import (  # noqa: F401
     reset_compile_stats,
 )
 from .keys import fingerprint  # noqa: F401
-from .prewarm import maybe_prewarm, prewarm_enabled  # noqa: F401

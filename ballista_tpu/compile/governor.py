@@ -19,8 +19,7 @@ compile time. The governor replaces them all:
   call that triggered them: per-operator ``compile_count`` /
   ``elapsed_compile`` land on the caller's MetricsSet (so EXPLAIN
   ANALYZE shows them), ``BALLISTA_TRACE`` gets a ``compile.jit`` span,
-  and :func:`compile_stats` exposes the process-wide totals (bench.py
-  emits them every run).
+  and :func:`compile_stats` exposes the process-wide totals.
 - **Bounded namespaces.** Mesh-path entries key on pytree structures
   that pin per-query ``Dictionary`` objects; their namespaces carry an
   LRU cap exactly like the bounded dicts they replaced.
@@ -29,7 +28,6 @@ compile time. The governor replaces them all:
 from __future__ import annotations
 
 import functools
-import os
 import re
 import threading
 import time
@@ -52,7 +50,7 @@ _PERF = time.perf_counter
 # mesh.agg_spmd / mesh.join_spmd / mesh.replicate / mesh.run_spmd):
 # their keys hold meshes and pytree structures whose aux-data pins
 # identity-hashed per-query Dictionary objects, so they stay much
-# tighter than the generic BALLISTA_JIT_CACHE_ENTRIES bound.
+# tighter than the generic JIT_CACHE_ENTRIES bound.
 MESH_NS_CAP = 32
 
 # process-wide totals (plain ints/floats under the GIL — same benign-race
@@ -65,11 +63,7 @@ _STATS: Dict[str, Any] = {
     "governed_calls": 0,        # calls through governed functions
     "entry_hits": 0,            # governed-key lookups that found an entry
     "entries_built": 0,         # governed-key lookups that built one
-    "prewarm_compiles": 0,      # compiles triggered by the prewarm pass
     "entry_trace_evictions": 0,  # within-entry jax trace-cache clears
-    "aot_loads": 0,             # fused-stage programs deserialized from
-                                # BALLISTA_FUSION_AOT_DIR (no re-trace)
-    "aot_exports": 0,           # fused-stage programs serialized to it
 }
 
 _tls = threading.local()
@@ -102,8 +96,6 @@ def _ensure_listener() -> None:
             return
 
         def on_duration(name: str, secs: float, **kw) -> None:
-            if getattr(_tls, "suppress_stats", False):
-                return  # AOT export worker: duplicate compiles
             if name == "/jax/core/compile/backend_compile_duration":
                 _STATS["backend_compiles"] += 1
                 _STATS["compile_seconds"] += secs
@@ -115,8 +107,6 @@ def _ensure_listener() -> None:
                 _STATS["trace_seconds"] += secs
 
         def on_event(name: str, **kw) -> None:
-            if getattr(_tls, "suppress_stats", False):
-                return
             if name == "/jax/compilation_cache/cache_hits":
                 _STATS["persistent_cache_hits"] += 1
                 f = getattr(_tls, "frame", None)
@@ -150,7 +140,7 @@ class GovernedFunction:
     handles shape and dictionary variation within the entry."""
 
     __slots__ = ("key", "fn", "calls", "compiles", "compile_seconds",
-                 "pcache_hits", "aot")
+                 "pcache_hits")
 
     def __init__(self, key: tuple, fn: Callable):
         self.key = key
@@ -159,10 +149,6 @@ class GovernedFunction:
         self.compiles = 0
         self.compile_seconds = 0.0
         self.pcache_hits = 0
-        # fused-stage AOT state (compile/aot.py), or None: set at entry
-        # creation when the caller opted in AND BALLISTA_FUSION_AOT_DIR
-        # is configured
-        self.aot = None
 
     def __call__(self, *args, **kwargs):
         return self.call_with(None, *args, **kwargs)
@@ -176,22 +162,13 @@ class GovernedFunction:
     # (the persistent disk cache still holds the compilations).
     _TRACE_CHECK_EVERY = 64
 
-    @staticmethod
-    def _traces_per_entry() -> int:
-        try:
-            return int(os.environ.get("BALLISTA_JIT_TRACES_PER_ENTRY",
-                                      "128"))
-        except ValueError:
-            return 128
+    _TRACES_PER_ENTRY = 128
 
     def _maybe_trim_traces(self) -> None:
         if self.calls % self._TRACE_CHECK_EVERY:
             return
-        bound = self._traces_per_entry()
-        if bound <= 0:
-            return
         try:
-            if self.fn._cache_size() > bound:
+            if self.fn._cache_size() > self._TRACES_PER_ENTRY:
                 self.fn._clear_cache()
                 _STATS["entry_trace_evictions"] += 1
         except Exception:  # noqa: BLE001 - private jax API drifted
@@ -202,29 +179,6 @@ class GovernedFunction:
         ``metrics`` (an observability MetricsSet, or None)."""
         _STATS["governed_calls"] += 1
         self.calls += 1
-        if self.aot is not None and not kwargs:
-            # serve the whole program from a deserialized artifact when
-            # one matches this call's content fingerprint — no trace or
-            # lower; the exported module's one-time backend compile (or
-            # disk-cache retrieval) still happens inside the call and is
-            # attributed through the same frame machinery, so EXPLAIN
-            # ANALYZE and the profiler's compile lane stay honest. Any
-            # AOT failure falls through to the normal jit path.
-            from .aot import _MISS
-
-            prev = getattr(_tls, "frame", None)
-            frame = _Frame()
-            _tls.frame = frame
-            t0 = _PERF()
-            try:
-                out = self.aot.call(self, args)
-            finally:
-                _tls.frame = prev
-            if out is not _MISS:
-                if frame.compiles or frame.pcache_hits:
-                    self._record(frame, _PERF() - t0, metrics,
-                                 aot=True)
-                return out
         self._maybe_trim_traces()
         prev = getattr(_tls, "frame", None)
         frame = _Frame()
@@ -249,57 +203,27 @@ class GovernedFunction:
             if frame.compiles or frame.pcache_hits:
                 self._record(frame, _PERF() - t0, metrics)
 
-    def _record(self, frame: _Frame, call_secs: float, metrics,
-                aot: bool = False) -> None:
+    def _record(self, frame: _Frame, call_secs: float, metrics) -> None:
         self.compiles += frame.compiles
         self.compile_seconds += frame.compile_secs
         self.pcache_hits += frame.pcache_hits
         if metrics is not None:
             # elapsed_compile is the whole first call (upper bound: it
             # includes the first batch's execution, but compile dominates
-            # by orders of magnitude on a persistent-cache miss). An
-            # AOT-loaded program never traces, so only the measured
-            # backend compile/retrieval counts for it.
+            # by orders of magnitude on a persistent-cache miss).
             if frame.compiles:
                 metrics.add_counter("compile_count", frame.compiles)
-            metrics.add_time("elapsed_compile",
-                             frame.compile_secs if aot else call_secs)
+            metrics.add_time("elapsed_compile", call_secs)
             if frame.pcache_hits:
                 metrics.add_counter("persistent_cache_hits",
                                     frame.pcache_hits)
         from ..observability.tracing import trace_event
 
-        # compile.aot records let the profiler's compile_trace_lower
-        # lane count only the real compile/retrieval seconds for loaded
-        # programs (their first-call execution is execution, not
-        # trace/lower)
-        trace_event("compile.aot" if aot else "compile.jit",
-                    key=_render_key(self.key),
+        trace_event("compile.jit", key=_render_key(self.key),
                     compiles=frame.compiles,
                     compile_seconds=round(frame.compile_secs, 6),
                     persistent_cache_hits=frame.pcache_hits,
                     call_seconds=round(call_secs, 6))
-
-    def warm(self, *abstract_args, **abstract_kwargs) -> bool:
-        """AOT-compile for the given (abstract) arguments — the prewarm
-        pass uses this to populate the in-process and persistent caches
-        without executing anything. Returns True when the lowering
-        compiled cleanly."""
-        prev = getattr(_tls, "frame", None)
-        frame = _Frame()
-        _tls.frame = frame
-        try:
-            self.fn.lower(*abstract_args, **abstract_kwargs).compile()
-        except Exception:  # noqa: BLE001 - prewarm is best-effort
-            return False
-        finally:
-            _tls.frame = prev
-            if frame.compiles or frame.pcache_hits:
-                _STATS["prewarm_compiles"] += frame.compiles
-                self.compiles += frame.compiles
-                self.compile_seconds += frame.compile_secs
-                self.pcache_hits += frame.pcache_hits
-        return True
 
 
 class _BoundGoverned:
@@ -313,9 +237,6 @@ class _BoundGoverned:
 
     def __call__(self, *args, **kwargs):
         return self.gf.call_with(self.metrics, *args, **kwargs)
-
-    def warm(self, *args, **kwargs) -> bool:
-        return self.gf.warm(*args, **kwargs)
 
 
 def program_name(key: tuple) -> str:
@@ -347,18 +268,13 @@ def _render_key(key: tuple) -> str:
         return str(key[0]) if key else "?"
 
 
-def _default_ns_cap() -> int:
-    """Default per-namespace LRU bound. Governed entries outlive
-    operator instances (that's the point), so a long-lived server
-    answering thousands of DISTINCT query shapes would otherwise pin
-    executables — and, through treedef keys, per-query dictionaries —
-    forever. 1024 is far above any single workload's entry count (the
-    whole TPC-H suite builds a few hundred); raise or lower with
-    BALLISTA_JIT_CACHE_ENTRIES."""
-    try:
-        return int(os.environ.get("BALLISTA_JIT_CACHE_ENTRIES", "1024"))
-    except ValueError:
-        return 1024
+# Default per-namespace LRU bound. Governed entries outlive operator
+# instances (that's the point), so a long-lived server answering
+# thousands of DISTINCT query shapes would otherwise pin executables —
+# and, through treedef keys, per-query dictionaries — forever. 1024 is
+# far above any single workload's entry count (the whole TPC-H suite
+# builds a few hundred).
+JIT_CACHE_ENTRIES = 1024
 
 
 class CompileGovernor:
@@ -372,13 +288,11 @@ class CompileGovernor:
 
     def get(self, key: tuple, build: Callable[[], Callable], *,
             metrics=None, cap: Optional[int] = None,
-            jit_kwargs: Optional[dict] = None, aot: bool = False):
+            jit_kwargs: Optional[dict] = None):
         """The governed function for ``key`` (built via ``build()`` and
         jitted on first use). ``cap`` bounds the key's namespace (LRU).
         With ``metrics``, returns a bound wrapper that attributes
-        compiles to that MetricsSet. ``aot=True`` opts the entry into
-        fused-stage program serialization (compile/aot.py) when
-        ``BALLISTA_FUSION_AOT_DIR`` is configured."""
+        compiles to that MetricsSet."""
         _ensure_listener()
         ns = key[0] if key else "default"
         with self._lock:
@@ -391,13 +305,6 @@ class CompileGovernor:
             if gf is not None:
                 space.move_to_end(key)
                 _STATS["entry_hits"] += 1
-        if gf is not None and aot and gf.aot is None and not jit_kwargs:
-            # the entry may predate BALLISTA_FUSION_AOT_DIR being set
-            # (env is read at attach time); attach lazily so it still
-            # exports/loads
-            from .aot import make_entry
-
-            gf.aot = make_entry(key)
         if gf is None:
             # build OUTSIDE the lock: build() may itself request governed
             # entries (e.g. a mesh SPMD program wrapping an aggregate's
@@ -408,10 +315,6 @@ class CompileGovernor:
 
             gf = GovernedFunction(key, jax.jit(_named(build(), key),
                                                **(jit_kwargs or {})))
-            if aot and not jit_kwargs:
-                from .aot import make_entry
-
-                gf.aot = make_entry(key)
             with self._lock:
                 # re-fetch: clear() may have swapped the namespace dict
                 # while we were building — inserting into the captured
@@ -423,7 +326,7 @@ class CompileGovernor:
                     space.move_to_end(key)
                     _STATS["entry_hits"] += 1
                 else:
-                    ns_cap = self._caps.get(ns, _default_ns_cap())
+                    ns_cap = self._caps.get(ns, JIT_CACHE_ENTRIES)
                     if ns_cap > 0:
                         while len(space) >= ns_cap:
                             space.popitem(last=False)
@@ -440,19 +343,12 @@ class CompileGovernor:
     def entry_rows(self) -> list:
         """Per-entry accounting rows for ``system.compile``: signature,
         call/compile counts, elapsed compile seconds, persistent-cache
-        hits, AOT loads. Snapshot under the lock; rendering outside."""
+        hits. Snapshot under the lock; rendering outside."""
         with self._lock:
             snap = [(ns, gf) for ns, space in self._spaces.items()
                     for gf in space.values()]
         out = []
         for ns, gf in snap:
-            aot_loads = 0
-            if gf.aot is not None:
-                # list() first: a concurrent query may be inserting a
-                # freshly-loaded artifact under the entry lock, which
-                # this read does not take
-                aot_loads = sum(1 for v in list(gf.aot.loaded.values())
-                                if v is not None)
             out.append({
                 "namespace": ns,
                 "signature": _render_key(gf.key),
@@ -460,7 +356,6 @@ class CompileGovernor:
                 "compiles": gf.compiles,
                 "compile_seconds": round(gf.compile_seconds, 6),
                 "persistent_cache_hits": gf.pcache_hits,
-                "aot_loads": aot_loads,
             })
         return out
 
@@ -487,10 +382,10 @@ def governor() -> CompileGovernor:
 
 def governed(key: tuple, build: Callable[[], Callable], *, metrics=None,
              cap: Optional[int] = None,
-             jit_kwargs: Optional[dict] = None, aot: bool = False):
+             jit_kwargs: Optional[dict] = None):
     """Module-level shorthand for ``governor().get(...)``."""
     return _GOVERNOR.get(key, build, metrics=metrics, cap=cap,
-                         jit_kwargs=jit_kwargs, aot=aot)
+                         jit_kwargs=jit_kwargs)
 
 
 def compile_stats() -> Dict[str, Any]:

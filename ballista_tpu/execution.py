@@ -160,7 +160,7 @@ def collect_physical_cached(phys: PhysicalPlan,
     """:func:`collect_physical` behind the plan-fingerprint result
     cache (cache/results.py). The library-level surface for callers
     without a BallistaContext (the client collect path hooks the cache
-    itself, earlier, to also skip prewarm/priming on a hit). Plans with
+    itself, earlier, to also skip priming on a hit). Plans with
     unsignable leaves execute normally every time."""
     from .cache import results as _results
 

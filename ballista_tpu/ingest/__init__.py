@@ -30,7 +30,7 @@ timers, which land on the owning scan's ``MetricsSet`` as
 ``elapsed_parse``/``elapsed_h2d`` (EXPLAIN ANALYZE renders them), emit
 ``ingest.parse``/``ingest.h2d`` spans under ``BALLISTA_TRACE=1`` (the
 producer-thread tids make the overlap visible), and accumulate into
-process totals ``phase_totals()`` that bench.py joins with wall time
+process totals ``phase_totals()`` that callers join with wall time
 for the parse/H2D/execute cold-path attribution.
 """
 

@@ -2,7 +2,7 @@
 
 The io layer brackets its work in :func:`phase` blocks. Each block:
 
-- accumulates into PROCESS totals (``phase_totals()``) — bench.py joins
+- accumulates into PROCESS totals (``phase_totals()``) — callers join
   these with wall time for the cold-path parse/H2D/execute attribution;
 - routes to the thread-bound :class:`PhaseRecorder` (if any), which
   forwards onto the owning operator's ``MetricsSet`` as

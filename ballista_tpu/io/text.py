@@ -36,9 +36,7 @@ from ..logical import TableSource
 # table-wide utf8 dictionaries, so the threshold is set where whole-file
 # RAM actually hurts (~1GB of text -> a few GB resident), keeping
 # SF<=1-class files on the single-parse fast path.
-STREAM_CHUNK_BYTES = int(
-    os.environ.get("BALLISTA_SCAN_CHUNK_BYTES", str(1 << 30))
-)
+STREAM_CHUNK_BYTES = 1 << 30
 
 
 def _list_files(path: str, suffixes=(".tbl", ".csv", ".txt", ".dat")) -> List[str]:

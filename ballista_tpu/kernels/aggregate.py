@@ -316,104 +316,9 @@ def _min_ident(dt):
 # ---------------------------------------------------------------------------
 
 
-# On CPU the kernel runs in (slow, python-looped) interpret mode, so the
-# automatic gate only admits batches small enough for CI validation.
-_PALLAS_INTERPRET_MAX_ROWS = 4096
-
-
-def _pallas_mode() -> str:
-    """'' (off) | 'on' (compiled kernel) | 'interpret' | 'auto'.
-
-    Default (no env): OFF everywhere in production — the round-3
-    on-chip A/B (recorded in bench.py's JSON every run) measured the XLA
-    dense path at ~4x the Pallas one-hot matmul for q1's tiny group
-    counts (G<=8 leaves the MXU idle and the limb split adds ~7x HBM
-    traffic), and interpret mode is a python loop nobody should pay
-    outside tests. Under pytest, small CPU batches auto-route through
-    interpret mode so the kernel stays exactness-tested in every run.
-    Explicit ``BALLISTA_PALLAS`` (off/on/interpret) always wins; an
-    unrecognized value warns once and means off.
-    """
-    import os
-
-    env = os.environ.get("BALLISTA_PALLAS", "").lower()
-    if not env:
-        return "auto"
-    if env in ("off", "0", "no", "false"):
-        return ""
-    if env in ("on", "1", "yes", "true"):
-        return "on"
-    if env == "interpret":
-        return "interpret"
-    if env not in _warned_env:
-        import logging
-
-        logging.getLogger("ballista.kernels").warning(
-            "unrecognized BALLISTA_PALLAS=%r: treating as off "
-            "(expected off/on/interpret)", env)
-        _warned_env.append(env)
-    return ""
-
-
-_warned_env: list = []
-
-
-def _pallas_additive(a: AggInput) -> bool:
-    """True for aggregates the Pallas kernel computes (integer sums and
-    counts, validity-masked or not); min/max and float sums stay on the
-    XLA dense path (split per aggregate, same program)."""
-    if a.op == "count":
-        return True
-    return (a.op == "sum" and a.values is not None
-            and jnp.issubdtype(a.values.dtype, jnp.integer))
-
-
 def dense_grouped_aggregate(
     gids: jax.Array,  # int32 [N] in [0, num_groups)
     live: jax.Array,  # bool [N]
-    aggs: Sequence[AggInput],
-    num_groups: int,
-) -> GroupedResult:
-    mode = _pallas_mode()
-    if mode == "auto":
-        import os
-
-        if "PYTEST_CURRENT_TEST" in os.environ and \
-                jax.default_backend() == "cpu" and \
-                gids.shape[0] <= _PALLAS_INTERPRET_MAX_ROWS:
-            mode = "interpret"  # CI: keep the kernel exactness-tested
-        else:
-            mode = ""  # production default is XLA: measured faster
-    if mode in ("on", "interpret"):
-        additive = [a for a in aggs if _pallas_additive(a)]
-        rest = [a for a in aggs if not _pallas_additive(a)]
-        if any(a.op == "sum" for a in additive):
-            res_p = _dense_grouped_pallas(
-                gids, live, additive, num_groups,
-                interpret=(mode == "interpret"),
-            )
-            if not rest:
-                return res_p
-            res_x = _dense_grouped_xla(gids, live, rest, num_groups)
-            results, valids = [], []
-            ip = ix = 0
-            for a in aggs:
-                if _pallas_additive(a):
-                    results.append(res_p.aggregates[ip])
-                    valids.append(res_p.agg_valid[ip])
-                    ip += 1
-                else:
-                    results.append(res_x.aggregates[ix])
-                    valids.append(res_x.agg_valid[ix])
-                    ix += 1
-            return GroupedResult(res_p.rep_indices, res_p.group_valid,
-                                 res_p.num_groups, results, valids)
-    return _dense_grouped_xla(gids, live, aggs, num_groups)
-
-
-def _dense_grouped_xla(
-    gids: jax.Array,
-    live: jax.Array,
     aggs: Sequence[AggInput],
     num_groups: int,
 ) -> GroupedResult:
@@ -464,7 +369,7 @@ def dense_grouped_scatter(
     num_groups: int,
 ) -> GroupedResult:
     """O(N) scatter-based dense grouping for group counts where
-    ``_dense_grouped_xla``'s [N, G] membership product is prohibitive
+    ``dense_grouped_aggregate``'s [N, G] membership product is prohibitive
     (ranged-integer keys: thousands to millions of groups). Same
     semantics: non-compact groups, ``group_valid`` marks occupancy,
     per-aggregate validity is "any non-NULL input seen"."""
@@ -516,72 +421,6 @@ def dense_grouped_scatter(
         results.append(jnp.where(va, r, jnp.zeros((), r.dtype)))
         valid_results.append(va)
 
-    return GroupedResult(rep_indices, group_valid, num_present, results,
-                         valid_results)
-
-
-def _dense_grouped_pallas(gids, live, aggs, num_groups,
-                          interpret: bool) -> GroupedResult:
-    """Integer sums/counts via the fused Pallas kernel
-    (kernels/pallas_agg.py); representatives via cheap XLA ops.
-
-    Validity handling happens BEFORE the kernel: masked-out sum inputs
-    are zeroed (sum semantics), and each validity-masked aggregate gets
-    one extra 0/1 value column whose per-group sum is its valid-input
-    count — so the kernel only ever sums, and per-aggregate NULL
-    semantics (all-NULL group -> NULL) survive exactly."""
-    from .pallas_agg import dense_grouped_sums
-
-    values: List[jax.Array] = []
-    # per agg: ("count", None) | ("countv", vcol) | ("sum", col, vcol|None)
-    plan = []
-    vmask_col: dict = {}  # id(validity) -> value-column index of its mask
-
-    def mask_col(validity) -> int:
-        key = id(validity)
-        if key not in vmask_col:
-            vmask_col[key] = len(values)
-            values.append(validity.astype(jnp.int64))
-        return vmask_col[key]
-
-    for a in aggs:
-        if a.op == "count":
-            if a.validity is None:
-                plan.append(("count", None, None))
-            else:
-                plan.append(("countv", mask_col(a.validity), None))
-        else:  # integer sum
-            v = a.values.astype(jnp.int64)
-            vcol = None
-            if a.validity is not None:
-                v = jnp.where(a.validity, v, jnp.int64(0))
-                vcol = mask_col(a.validity)  # may append; BEFORE len()
-            plan.append(("sum", len(values), vcol))
-            values.append(v)
-
-    sums, counts = dense_grouped_sums(gids, live, values, num_groups,
-                                      interpret=interpret)
-    n = gids.shape[0]
-    pos = jnp.arange(n, dtype=jnp.int32)
-    first = jax.ops.segment_min(jnp.where(live, pos, n), gids,
-                                num_segments=num_groups)
-    rep_indices = jnp.minimum(first, n - 1).astype(jnp.int32)
-    group_valid = counts > 0
-    num_present = jnp.sum(group_valid.astype(jnp.int32))
-    results: List[jax.Array] = []
-    valid_results: List[jax.Array] = []
-    for a, (kind, col, vcol) in zip(aggs, plan):
-        if kind == "count":
-            results.append(counts)
-            valid_results.append(group_valid)
-        elif kind == "countv":
-            results.append(sums[col])
-            valid_results.append(group_valid)
-        else:
-            va = group_valid if vcol is None else (sums[vcol] > 0)
-            out = sums[col].astype(a.values.dtype)
-            results.append(jnp.where(va, out, jnp.zeros((), out.dtype)))
-            valid_results.append(va)
     return GroupedResult(rep_indices, group_valid, num_present, results,
                          valid_results)
 

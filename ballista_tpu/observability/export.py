@@ -71,7 +71,6 @@ LANE_SPANS = {
     "ingest.parse": "parse",
     "ingest.h2d": "h2d",
     "compile.jit": "compile_trace_lower",
-    "compile.aot": "compile_trace_lower",
     "device.block": "device_blocked",
     "host.dictionary": "host_dictionary",
     "shuffle.spill": "shuffle_spill",
@@ -105,12 +104,6 @@ def compute_lanes(session: dict) -> dict:
     }
     compile_lane = sum(float(r.get("call_seconds", 0.0)) for r in records
                        if r.get("name") == "compile.jit")
-    # AOT-loaded programs (compile/aot.py) never trace or lower; only
-    # their measured backend compile/disk-retrieval seconds belong in
-    # this lane — first-call execution is execution
-    compile_lane += sum(float(r.get("compile_seconds", 0.0))
-                        for r in records
-                        if r.get("name") == "compile.aot")
     if compile_lane == 0.0:
         # no compile.jit records (tracing came up late): fall back to
         # the governor's process-stat delta

@@ -162,7 +162,7 @@ class Profiler:
             except OSError:
                 self._trace_offset = 0
         # NOTE: the process-wide memory peaks are NOT reset here — the
-        # health plane, heartbeats and bench.py report them as lifetime
+        # health plane and heartbeats report them as lifetime
         # trajectories, and an ambient profiler window clobbering them
         # would make those under-report. The artifact's memory section
         # is a snapshot taken at stop() (peaks = process lifetime).

@@ -15,7 +15,7 @@ own telemetry becomes relational tables served by the engine itself:
 - ``system.operators`` — per-operator MetricsSet rows of the last N
   queries, long format (one row per operator x metric).
 - ``system.compile``   — compile-governor entries: signature, calls,
-  compiles, elapsed compile seconds, persistent-cache hits, AOT loads.
+  compiles, elapsed compile seconds, persistent-cache hits.
 - ``system.cache``     — warm-path serving caches (docs/caching.md):
   one row per device-resident table entry / host result-cache entry.
 - ``system.executors`` — executor heartbeat resources (cluster) or one
@@ -69,18 +69,9 @@ KNOBS: Dict[str, tuple] = {
     "BALLISTA_SHAPE_BUCKETS_GROWTH": ("2", "geometric ladder step"),
     "BALLISTA_FUSION": ("on", "whole-stage fusion: one governed XLA "
                               "program per pipeline stage"),
-    "BALLISTA_FUSION_AOT_DIR": ("off", "serialize fused-stage programs "
-                                       "(jax.export) into this directory"),
-    "BALLISTA_PREWARM": ("off", "AOT-compile fused stages concurrently "
-                                "with parse/H2D"),
     "BALLISTA_XLA_CACHE_MIN_COMPILE_SECS": ("0", "only disk-cache kernels "
                                                  "compiling at least this "
                                                  "long"),
-    "BALLISTA_JIT_CACHE_ENTRIES": ("1024", "per-namespace LRU bound on "
-                                           "governed jit entries"),
-    "BALLISTA_JIT_TRACES_PER_ENTRY": ("128", "clear an entry's in-memory "
-                                             "trace cache past this many "
-                                             "specializations"),
     # ingest (docs/ingest.md)
     "BALLISTA_INGEST_THREADS": ("min(cpu_count, 8)", "shared ingest pool "
                                                      "width"),
@@ -88,25 +79,12 @@ KNOBS: Dict[str, tuple] = {
                                        "(0 = serial pull loop)"),
     "BALLISTA_SCAN_THREADS": ("cpu count", "native C++ scanner threads "
                                            "within one file"),
-    "BALLISTA_SCAN_CHUNK_BYTES": ("1073741824", "text scan chunk size"),
     # kernels / execution
     "BALLISTA_DICT_REGISTRY": ("on", "process-wide dictionary registry: "
                                      "interned string dictionaries, "
-                                     "cached integer remaps, epoch-keyed "
-                                     "AOT artifacts (off = legacy "
+                                     "cached integer remaps (off = legacy "
                                      "object-array unify/remap; "
                                      "docs/strings.md)"),
-    "BALLISTA_PALLAS": ("off", "force the Pallas dense-aggregation kernel "
-                               "(off/on/interpret)"),
-    "BALLISTA_JOIN_SWAP": ("on", "planner may swap join build/probe sides "
-                                 "by estimated size"),
-    "BALLISTA_JOIN_SYNC_WINDOW": ("8", "deferred-sync join build window "
-                                       "(batches)"),
-    "BALLISTA_JOIN_SYNC_WINDOW_BYTES": ("1073741824", "deferred-sync join "
-                                                      "build window cap "
-                                                      "(bytes)"),
-    "BALLISTA_NARROW_WIRE": ("auto", "narrow integer wire encoding for "
-                                     "shuffle IPC"),
     "BALLISTA_ALLOW_MIMALLOC": ("off", "skip the jemalloc pool guard for "
                                        "pyarrow"),
     # distributed / streaming shuffle (docs/shuffle.md)
@@ -322,7 +300,7 @@ SYSTEM_SCHEMAS: Dict[str, Schema] = {
     "system.compile": make_schema(
         ("namespace", Utf8), ("signature", Utf8), ("calls", Int64),
         ("compiles", Int64), ("compile_seconds", Float64),
-        ("persistent_cache_hits", Int64), ("aot_loads", Int64),
+        ("persistent_cache_hits", Int64),
     ),
     "system.executors": make_schema(
         ("executor_id", Utf8), ("host", Utf8), ("port", Int64),

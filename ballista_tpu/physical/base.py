@@ -115,14 +115,9 @@ class PhysicalPlan:
         ``subkey`` (namespace first); compiles it triggers are
         attributed to this operator's metrics. Replaces the per-instance
         ``self._jit_*`` dicts, which adaptive re-planning (new operator
-        instances) used to throw away. Operator entries are AOT-eligible
-        (compile/aot.py): with ``BALLISTA_FUSION_AOT_DIR`` set, whole
-        programs serialize after first use and fresh processes
-        deserialize instead of re-tracing; entries whose call shapes the
-        AOT layer cannot fingerprint disable themselves safely."""
+        instances) used to throw away."""
         key = (subkey[0], self.compile_signature()) + tuple(subkey[1:])
         metrics = self.metrics() if metrics_enabled() else None
-        kw.setdefault("aot", True)
         return governed(key, build, metrics=metrics, **kw)
 
     def governed_call(self, subkey: tuple, build, batch: ColumnBatch,
@@ -131,11 +126,10 @@ class PhysicalPlan:
         donating the batch's device buffers when it is transient
         (single-consumer intermediate, cache/donation.py) and donation
         is enabled. The donating variant is a SEPARATE governed entry
-        (``<namespace>.don``) because ``donate_argnums`` is
-        incompatible with AOT attachment, and because its call
-        convention splits the batch: the treedef rides as a static
-        argument, column/validity/selection leaves are the donated
-        payload, and ``num_rows`` stays an ordinary argument —
+        (``<namespace>.don``) because its call convention splits the
+        batch: the treedef rides as a static argument,
+        column/validity/selection leaves are the donated payload, and
+        ``num_rows`` stays an ordinary argument —
         MetricsSet.record_output_batch holds that scalar in
         ``_pending_rows`` long after the batch body is consumed, so
         donating it would hand ``_resolve_rows`` deleted buffers."""
@@ -146,7 +140,7 @@ class PhysicalPlan:
             fn = self.governed_jit(
                 (subkey[0] + ".don",) + tuple(subkey[1:]),
                 _donating_build(build),
-                jit_kwargs=dict(DONATING_JIT_KWARGS), aot=False)
+                jit_kwargs=dict(DONATING_JIT_KWARGS))
             leaves, treedef = jax.tree_util.tree_flatten(batch)
             payload, num_rows = tuple(leaves[:-1]), leaves[-1]
             record_donation(sum(int(getattr(x, "nbytes", 0))
@@ -313,16 +307,14 @@ class PipelineOp(PhysicalPlan):
             key = ("pipeline.fused",
                    tuple(op.compile_signature() for op in chain))
             metrics = self.metrics() if metrics_enabled() else None
-            fused = self._fused_fn = governed(key, build, metrics=metrics,
-                                              aot=True)
+            fused = self._fused_fn = governed(key, build, metrics=metrics)
         return fused
 
     def _fused_governed_donating(self):
         """Donating twin of :meth:`_fused_governed` (split-call
         convention, see ``governed_call``): used per-batch when the
         incoming batch is transient. Shares the chain-signature key
-        shape under the ``pipeline.fused.don`` namespace; not
-        AOT-eligible (donate_argnums)."""
+        shape under the ``pipeline.fused.don`` namespace."""
         fused = getattr(self, "_fused_don_fn", None)
         if fused is None:
             chain, _ = self._pipeline_chain()
@@ -543,7 +535,7 @@ def maybe_compact(batch: ColumnBatch, shrink_factor: int = 4,
         return compact
 
     trace_event("compact.search", rows=n, capacity=cap, to=new_cap)
-    return governed(("batch.compact", new_cap), build, aot=True)(batch)
+    return governed(("batch.compact", new_cap), build)(batch)
 
 
 def pad_batch(batch: ColumnBatch, capacity: int) -> ColumnBatch:

@@ -35,6 +35,15 @@ from .base import PhysicalPlan, Partitioning, concat_batches
 
 JOIN_TYPES = ("inner", "left", "semi", "anti", "full")
 
+# Deferred-sync window of ``_probe_expand_stream``: match totals of this
+# many probe batches are fetched in one ``device_get``. The window also
+# bounds BYTES pinned on device (probe + expanded output buffers stay
+# live until their totals are fetched), so a wide join with huge batch
+# capacities flushes early instead of multiplying its peak memory by
+# the batch-count window.
+_SYNC_WINDOW = 8
+_SYNC_WINDOW_BYTES = 1 << 30
+
 
 class JoinExec(PhysicalPlan):
     """build = left child (merged to 1 partition), probe = right child."""
@@ -393,8 +402,7 @@ class JoinExec(PhysicalPlan):
         if table is None:
             sorted_fn = governed(
                 ("join.sorted",), lambda: join_k.build_sorted_with_unique,
-                metrics=self.metrics() if metrics_enabled() else None,
-                aot=True)
+                metrics=self.metrics() if metrics_enabled() else None)
             table, uniq = sorted_fn(keys, live)
             unique = bool(uniq)
         self._build_data[key] = (table, bb, unique, has_null_key, mode,
@@ -715,17 +723,8 @@ class JoinExec(PhysicalPlan):
             raise NotImplementedError_(
                 f"{self.how} join with duplicate build keys"
             )
-        import os as _os
-
         from .base import maybe_compact
 
-        window = max(int(_os.environ.get("BALLISTA_JOIN_SYNC_WINDOW", 8)), 1)
-        # the window also bounds BYTES pinned on device (probe + expanded
-        # output buffers stay live until their totals are fetched), so a
-        # wide join with huge batch capacities flushes early instead of
-        # multiplying its peak memory by the batch-count window
-        window_bytes = int(_os.environ.get(
-            "BALLISTA_JOIN_SYNC_WINDOW_BYTES", str(1 << 30)))
         # fixed-size-list columns hold ``length`` elements per row, so
         # itemsize alone would under-count them by length x
         row_bytes = sum(
@@ -775,7 +774,8 @@ class JoinExec(PhysicalPlan):
                                           key_tables, remaps, out_cap)
             pend.append((pb, remaps, out, out_cap, total))
             pend_bytes += (pb.capacity + out_cap) * row_bytes
-            if len(pend) >= window or pend_bytes >= window_bytes:
+            if (len(pend) >= _SYNC_WINDOW
+                    or pend_bytes >= _SYNC_WINDOW_BYTES):
                 yield from flush()
         yield from flush()
 

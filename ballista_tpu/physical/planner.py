@@ -8,7 +8,6 @@ aggregate split and probe/build side selection for joins.
 
 from __future__ import annotations
 
-import os
 from dataclasses import dataclass
 from typing import Dict, Optional
 
@@ -60,7 +59,7 @@ class PlannerOptions:
     join_partition_threshold: Optional[int] = 1_000_000
     join_partitions: int = 8
     # cost-based inner-join orientation (see the swap block below);
-    # settings key "join.swap", env BALLISTA_JOIN_SWAP as default source
+    # settings key "join.swap"
     join_swap: bool = True
     # hash-shuffled aggregation: partial -> Repartition(hash on group
     # keys) -> final, instead of merging all partial tables to one task.
@@ -88,8 +87,7 @@ class PlannerOptions:
             )
         if "join.partitions" in s:
             opts.join_partitions = int(s["join.partitions"])
-        swap = s.get("join.swap",
-                     os.environ.get("BALLISTA_JOIN_SWAP", "on")).lower()
+        swap = s.get("join.swap", "on").lower()
         if swap in ("off", "0", "false"):
             opts.join_swap = False
         elif swap not in ("on", "1", "true", ""):

@@ -4,8 +4,8 @@
 # native build, the full suite INCLUDING the SF0.2 scale tier (all 22
 # TPC-H queries through standalone AND the cluster — the scale-dependent
 # paths: overflow, compaction, partitioned joins, recovery), then the
-# benchmark smoke. Budget: ~6min on a 1-core box (~2min fast tier +
-# ~160s SF0.2 + bench). Skip the scale tier for quick iteration with
+# chip smoke rehearsed on the CPU. Budget: ~6min on a 1-core box (~2min
+# fast tier + ~160s SF0.2 + smoke). Skip the scale tier for quick iteration with
 #   FAST_ONLY=1 dev/integration_test.sh
 set -euo pipefail
 cd "$(dirname "$0")/.."
@@ -26,4 +26,4 @@ if [[ "${FAST_ONLY:-0}" == "1" ]]; then
 else
   python -m pytest tests/ -q
 fi
-python bench.py --cpu --scale 0.2 --runs 2
+python chip_smoke.py --rehearse --scale 0.01

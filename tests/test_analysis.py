@@ -440,6 +440,23 @@ def test_whole_package_analysis_clean_within_budget():
     assert elapsed < 10.0, f"analysis took {elapsed:.1f}s (budget 10s)"
 
 
+def test_production_code_does_not_ask_whether_pytest_is_running():
+    """A branch on ``PYTEST_CURRENT_TEST`` makes the tests exercise a
+    path users never get (the dense aggregate had one until PR 30).
+    ``ballista_tpu/testing/`` is the test-support package and exempt."""
+    pkg = os.path.join(REPO, "ballista_tpu")
+    hits = []
+    for root, dirs, files in os.walk(pkg):
+        if os.path.relpath(root, pkg).split(os.sep)[0] == "testing":
+            continue
+        for f in files:
+            if f.endswith(".py"):
+                path = os.path.join(root, f)
+                if "PYTEST_CURRENT_TEST" in open(path).read():
+                    hits.append(os.path.relpath(path, REPO))
+    assert not hits, hits
+
+
 def test_analyze_json_and_changed_only_modes():
     r = subprocess.run(
         [sys.executable, ANALYZE, "--json"],

@@ -1,5 +1,5 @@
 """Compile governor (PR 3): shape-bucket ladder, unified jit cache,
-compile observability, prewarm.
+compile observability.
 
 Layers, bottom-up: ladder math + knobs; governor entry
 sharing/attribution/eviction units; the partition-size-jitter pin (same
@@ -8,7 +8,7 @@ not once per count); the adaptive-re-plan regression (a re-built plan
 performs ZERO new compiles for unchanged signatures — the per-instance
 ``self._jit_*`` dicts this PR deleted used to throw every trace away);
 a masked-correctness sweep (bucket-padded results row-identical to
-unpadded across agg/sort/join/limit); prewarm smoke; and the
+unpadded across agg/sort/join/limit); the one-way-to-compile pins; and the
 ``dev/check_jit_sites.py`` lint so the scattered-cache problem can't
 regrow. Also hosts the BALLISTA_XLA_CACHE_MIN_COMPILE_SECS default pin.
 """
@@ -490,42 +490,69 @@ def test_compile_cache_placed_from_outside(tmp_path, from_env):
 
 
 # ---------------------------------------------------------------------------
-# prewarm
+# one way to obtain a program: jax.jit under the governor
 # ---------------------------------------------------------------------------
 
 
-def test_prewarm_compiles_scan_chain(tmp_path, monkeypatch):
-    from ballista_tpu.compile import maybe_prewarm
-    from ballista_tpu.compile.governor import _STATS
-    from ballista_tpu.execution import collect_physical, plan_logical
+def test_governed_takes_no_aot_argument_and_counts_no_aot_or_prewarm():
+    import inspect
 
-    n = 1100
-    lines = "".join(f"{i}|{i * 3}|\n" for i in range(n))
-    (tmp_path / "t.tbl").write_text(lines)
+    from ballista_tpu.execution import plan_logical
+
+    assert "aot" not in inspect.signature(governed).parameters
+    assert "aot" not in inspect.signature(governor().get).parameters
+    with pytest.raises(TypeError):
+        governed(("test.unit", "no-aot"), lambda: (lambda x: x), aot=True)
+    with pytest.raises(TypeError):
+        plan_logical(_replan_ctx().sql(_REPLAN_SQL).plan).governed_jit(
+            ("test.unit",), lambda: (lambda x: x), aot=True)
+    assert not [k for k in compile_stats()
+                if k.startswith(("aot_", "prewarm_"))]
+    assert all("aot_loads" not in row for row in governor().entry_rows())
+
+
+def test_equal_calls_compile_once_and_programs_keep_their_names(tmp_path):
+    """A governed call made twice with equal arguments compiles once,
+    and the programs the benchmark's cells show keep the names they had
+    (a renamed program is a new cache key: it compiles again)."""
+    import jax.numpy as jnp
+
+    from ballista_tpu.compile.governor import program_name
+
+    fn = governed(("test.unit.once", 1024), lambda: (lambda x: x * 3 + 1))
+    x = jnp.arange(1024, dtype=jnp.int64)
+    out = fn(x)
+    # the entry's own count (a disk-cache retrieval is a trip through
+    # the backend's compile call too, and counts in both)
+    assert fn.compiles == 1 and fn.pcache_hits <= 1
+    seen = (fn.compiles, fn.pcache_hits)
+    again = fn(jnp.arange(1024, dtype=jnp.int64))
+    assert (fn.compiles, fn.pcache_hits) == seen and fn.calls == 2
+    assert int(out[2]) == int(again[2]) == 7
+
+    # the keys the operators really use, taken from real queries: a
+    # filter's compaction, a donating dense aggregate, a unique-key join
+    n = 3000
+    (tmp_path / "f.tbl").write_text(
+        "".join(f"{i}|{i % 7}|{'abc'[i % 3]}|{i * 2}|\n" for i in range(n)))
+    (tmp_path / "d.tbl").write_text(
+        "".join(f"{i}|{i * 10}|\n" for i in range(7)))
     ctx = BallistaContext.standalone()
-    ctx.register_tbl("pw_t", str(tmp_path / "t.tbl"),
-                     schema(("pk", Int64), ("pv", Int64)))
-    df = ctx.sql("SELECT pk, pv FROM pw_t WHERE pv > 100")
-    phys = plan_logical(df.plan)
-    monkeypatch.setenv("BALLISTA_PREWARM", "1")
-    before = _STATS["prewarm_compiles"]
-    t = maybe_prewarm(phys)
-    assert t is not None
-    t.join(timeout=120)
-    assert not t.is_alive()
-    assert _STATS["prewarm_compiles"] > before
-    # second call on the same plan is a no-op
-    assert maybe_prewarm(phys) is None
-    out = collect_physical(phys)
-    assert sorted(out["pk"]) == [i for i in range(n) if i * 3 > 100]
-
-
-def test_prewarm_disabled_by_default(monkeypatch):
-    from ballista_tpu.compile import maybe_prewarm, prewarm_enabled
-
-    monkeypatch.delenv("BALLISTA_PREWARM", raising=False)
-    assert not prewarm_enabled()
-    assert maybe_prewarm(object()) is None
+    ctx.register_tbl("pn_f", str(tmp_path / "f.tbl"), schema(
+        ("fk", Int64), ("fd", Int64), ("ff", Utf8), ("fv", Int64)))
+    ctx.register_tbl("pn_d", str(tmp_path / "d.tbl"),
+                     schema(("dk", Int64), ("dw", Int64)),
+                     primary_key=["dk"])
+    flags = ctx.sql("SELECT ff, SUM(fv) AS s FROM pn_f WHERE fk < 10 "
+                    "GROUP BY ff ORDER BY ff").collect()
+    assert list(flags["s"]) == [36, 24, 30]
+    joined = ctx.sql("SELECT dw, fv FROM pn_f JOIN pn_d ON fd = dk "
+                     "WHERE fk < 10 ORDER BY fv").collect()
+    assert list(joined["dw"]) == [(i % 7) * 10 for i in range(10)]
+    names = {program_name((ns,))
+             for ns, size in governor().namespace_sizes().items() if size}
+    assert {"batch_compact", "agg_grouped_don", "join_unique"} <= names, \
+        sorted(names)
 
 
 # ---------------------------------------------------------------------------

@@ -6,9 +6,6 @@ Pins the tentpole contracts:
 - version chains remap through pure integer composition; cross-entry
   pairs build once (cached) and match the legacy searchsorted result;
 - Arrow IPC stamps resolve to the SAME in-process instance on read;
-- compile/aot.py keys on registry epochs: a dictionary APPEND does not
-  invalidate artifacts keyed on older versions, and the per-value
-  Python fingerprint loop never runs on the keying path;
 - q1/q5/q16 results are byte-identical registry ON vs OFF;
 - warm q1 pays < 5% for the plane (drift-cancelling scheme, PR-1);
 - the vectorized stable_hashes matches the reference FNV-1a loop;
@@ -245,42 +242,6 @@ def test_ipc_roundtrip_resolves_to_interned_instance(registry_env,
     batches = ipc.batches_from_parts(
         b.schema, [(arrays, nulls, dicts)])
     assert batches[0].column("k").dictionary is d
-
-
-# ---------------------------------------------------------------------------
-# tentpole: AOT keys ride registry epochs
-# ---------------------------------------------------------------------------
-
-
-def test_aot_key_stable_under_dict_append(registry_env, monkeypatch):
-    from ballista_tpu.compile import aot
-
-    key = _fresh_key("aotkey")
-    v0 = reg.intern(key, ["m", "n"])
-    b = _batch(v0, [0, 1])
-
-    def no_loop(self):  # the per-value Python loop must be OFF this path
-        raise AssertionError("content_fingerprint loop ran on the "
-                             "AOT keying path")
-
-    monkeypatch.setattr(Dictionary, "content_fingerprint", no_loop)
-    fp_before = aot._args_fingerprint((b,))
-    # an APPEND mints a new version; programs keyed on v0 batches keep
-    # their artifacts (same fingerprint), the new version keys fresh
-    v1 = reg.intern(key, ["m", "n", "o"])
-    assert aot._args_fingerprint((b,)) == fp_before
-    assert aot._args_fingerprint((_batch(v1, [0, 1]),)) != fp_before
-
-
-def test_aot_output_proto_resolves_shared_dictionary(registry_env):
-    from ballista_tpu.compile import aot
-
-    d = reg.intern(_fresh_key("aotout"), ["u", "v"])
-    b = _batch(d, [1, 0])
-    proto = aot._encode_out(b)
-    mat = aot._materialize_dicts(proto)
-    # the loaded artifact's output dictionary IS the interned instance
-    assert mat[2][0][2] is d
 
 
 # ---------------------------------------------------------------------------

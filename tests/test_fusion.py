@@ -1,6 +1,6 @@
 """Whole-stage fusion (physical/fusion.py): determinism, plan shape,
-re-plan cache reuse, the distinct-count kernel, AOT export/load, and the
-program-count regression gate.
+re-plan cache reuse, the distinct-count kernel, and the program-count
+regression gate.
 
 The fusion pass reorders NOTHING — TPC-H results must be byte-identical
 with ``BALLISTA_FUSION`` ON vs OFF, across the adaptive pass (default
@@ -10,7 +10,6 @@ on) and with the shape-bucket ladder on or off.
 import os
 import subprocess
 import sys
-import time
 
 import numpy as np
 import pytest
@@ -279,75 +278,6 @@ def test_distinct_single_partition_drops_dedup():
            .reset_index(drop=True))
     assert list(out["k"]) == list(exp["k"])
     assert list(out["dv"]) == list(exp["dv"])
-
-
-# ---------------------------------------------------------------------------
-# AOT export/load (BALLISTA_FUSION_AOT_DIR)
-# ---------------------------------------------------------------------------
-
-
-def test_aot_export_then_load(tpch_dir, tmp_path, monkeypatch):
-    """First run exports fused-stage programs; after clearing the
-    in-process governor (standing in for a fresh process) the next run
-    LOADS them — no re-trace — and stays byte-identical."""
-    from ballista_tpu.client import BallistaContext
-    from ballista_tpu.compile import compile_stats, governor
-    from benchmarks.tpch.schema_def import register_tpch
-
-    aot = str(tmp_path / "aot")
-    monkeypatch.setenv("BALLISTA_FUSION_AOT_DIR", aot)
-    monkeypatch.setenv("BALLISTA_FUSION", "on")
-    sql = open(os.path.join(QDIR, "q1.sql")).read()
-    ctx = BallistaContext.standalone()
-    register_tpch(ctx, tpch_dir, "tbl")
-    first = ctx.sql(sql).collect()
-    deadline = time.time() + 30
-    while time.time() < deadline:  # background export
-        if os.path.isdir(aot) and os.listdir(aot):
-            break
-        time.sleep(0.2)
-    assert os.path.isdir(aot) and os.listdir(aot), "no AOT artifact"
-    governor().clear()  # fresh-process stand-in: all entries gone
-    base_loads = int(compile_stats()["aot_loads"])
-    ctx2 = BallistaContext.standalone()
-    register_tpch(ctx2, tpch_dir, "tbl")
-    second = ctx2.sql(sql).collect()
-    assert int(compile_stats()["aot_loads"]) > base_loads, \
-        "fused stage was re-traced instead of AOT-loaded"
-    _assert_byte_identical(first, second, "q1[aot]")
-
-
-def test_aot_off_by_default(monkeypatch):
-    monkeypatch.delenv("BALLISTA_FUSION_AOT_DIR", raising=False)
-    from ballista_tpu.compile.aot import aot_dir, make_entry
-
-    assert aot_dir() is None
-    assert make_entry(("agg.grouped", "x")) is None
-
-
-# ---------------------------------------------------------------------------
-# prewarm targets fused-stage signatures
-# ---------------------------------------------------------------------------
-
-
-def test_prewarm_targets_fused_stage(tpch_dir, monkeypatch):
-    from ballista_tpu.client import BallistaContext
-    from ballista_tpu.compile.prewarm import collect_targets
-    from ballista_tpu.execution import plan_logical
-    from ballista_tpu.physical.fusion import maybe_fuse
-    from ballista_tpu.physical.planner import PlannerOptions
-    from benchmarks.tpch.schema_def import register_tpch
-
-    monkeypatch.setenv("BALLISTA_FUSION", "on")
-    ctx = BallistaContext.standalone()
-    register_tpch(ctx, tpch_dir, "tbl")
-    sql = open(os.path.join(QDIR, "q1.sql")).read()
-    phys = maybe_fuse(plan_logical(
-        ctx.sql(sql)._plan, PlannerOptions.from_settings(ctx.settings)))
-    targets = collect_targets(phys)
-    assert targets, "fused q1 stage must be a prewarm target"
-    fn, batch = targets[0]
-    assert fn.warm(batch) in (True, False)  # lowering must not raise
 
 
 # ---------------------------------------------------------------------------

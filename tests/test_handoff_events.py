@@ -98,36 +98,36 @@ def _warm_ledgers(ctx, runs=3):
 
 def test_reports_leave_on_completion_and_idle_executors_are_woken(
         table_dir):
+    """Proved by the events themselves, not by the wall clock (a loaded
+    test machine stretches every phase): which call carried a report,
+    which wait an event ended."""
     before = {n: _count(n) for n in (
         "executor.report_now", "executor.report_waited", "executor.refill",
-        "scheduler.poll_woken", "scheduler.status_woken")}
+        "scheduler.poll_woken", "scheduler.status_woken",
+        "scheduler.hold_refused")}
     cluster = LocalCluster(num_executors=2)
     try:
         ledgers = _warm_ledgers(_context(cluster, table_dir))
     finally:
         cluster.shutdown()
-    # a warm three-stage query: no report waited for a timer. The best
-    # of three keeps a loaded test machine's scheduling out of it.
-    assert min(led["phases"]["report_wait"] for led in ledgers) \
-        < POLL_INTERVAL_SECS / 10, ledgers
-    # submitted to an idle cluster, picked up from a held poll
-    assert min(led["phases"]["dispatch_wait"] for led in ledgers) \
-        < POLL_INTERVAL_SECS / 2, ledgers
-    assert min(led["phases"]["client_poll_wait"] for led in ledgers) \
-        < 0.05, ledgers
-    after = {n: _count(n) for n in before}
-    # 4 queries of 6 tasks: every task's end sent a poll of its own
-    assert after["executor.report_now"] - before["executor.report_now"] \
-        >= 12
-    assert after["executor.report_waited"] == \
-        before["executor.report_waited"]
-    assert after["executor.refill"] > before["executor.refill"]
-    # one held poll at least ended with a task for each idle start, and
-    # every job's terminal status woke its waiting client
-    assert after["scheduler.poll_woken"] - before["scheduler.poll_woken"] \
-        >= 3
-    assert after["scheduler.status_woken"] - \
-        before["scheduler.status_woken"] >= 3
+    rose = {n: _count(n) - before[n] for n in before}
+    # 4 queries of 3 stages: each stage's end sent a poll of its own
+    # (tasks of one executor that end together share one), and no
+    # report sat out a timer's wait
+    assert rose["executor.report_now"] >= 12, rose
+    assert rose["executor.report_waited"] == 0, rose
+    assert rose["executor.refill"] > 0, rose
+    # submitted to an idle cluster: one held poll at least ended with a
+    # task for each idle start, no hold was refused, and every warm
+    # job's terminal status woke its waiting client
+    assert rose["scheduler.poll_woken"] >= 3, rose
+    assert rose["scheduler.hold_refused"] == 0, rose
+    assert rose["scheduler.status_woken"] >= 3, rose
+    # the ledger names the three waits; how long they took is the
+    # benchmark's to say (sched_wait_s), on a machine of its own
+    for led in ledgers:
+        assert {"report_wait", "dispatch_wait",
+                "client_poll_wait"} <= set(led["phases"]), led
 
 
 def test_every_free_slot_is_filled_before_the_first_report(table_dir,

@@ -154,6 +154,80 @@ def test_system_settings(ctx, clean_env, monkeypatch):
         assert names.count(knob) == 1
 
 
+# The ten knobs PR 30 removed: three switched on mechanisms that are
+# gone (AOT export, prewarm, the Pallas aggregate), seven had one value
+# in use and became constants beside their reader. name -> (value a
+# user might still export, what the reader returns whatever it is).
+_REMOVED_KNOBS = {
+    "BALLISTA_FUSION_AOT_DIR": ("{tmp}/aot", False),   # dir is created
+    "BALLISTA_PREWARM": ("1", False),       # a prewarm counter exists
+    "BALLISTA_PALLAS": ("interpret", False),     # pallas gets imported
+    "BALLISTA_JOIN_SYNC_WINDOW": ("2", 8),
+    "BALLISTA_JOIN_SYNC_WINDOW_BYTES": ("4096", 1 << 30),
+    "BALLISTA_JOIN_SWAP": ("off", True),
+    "BALLISTA_NARROW_WIRE": ("on", False),  # auto: off on the CPU
+    "BALLISTA_JIT_CACHE_ENTRIES": ("3", 1024),
+    "BALLISTA_JIT_TRACES_PER_ENTRY": ("1", 128),
+    "BALLISTA_SCAN_CHUNK_BYTES": ("64", 1 << 30),
+}
+
+_REMOVED_KNOBS_PROBE = """
+import json, os, sys
+import jax.numpy as jnp
+from ballista_tpu import columnar
+from ballista_tpu.compile import compile_stats, governed, governor as gov
+from ballista_tpu.compile.governor import GovernedFunction, JIT_CACHE_ENTRIES
+from ballista_tpu.io import text
+from ballista_tpu.kernels.aggregate import AggInput, dense_grouped_aggregate
+from ballista_tpu.observability import systables
+from ballista_tpu.physical import join
+from ballista_tpu.physical.planner import PlannerOptions
+
+fn = governed(("agg.grouped", "probe"), lambda: (
+    lambda g, live, v: dense_grouped_aggregate(
+        g, live, [AggInput("sum", v, None)], 4).aggregates[0]))
+out = fn(jnp.arange(8, dtype=jnp.int32) % 4, jnp.ones(8, bool),
+         jnp.arange(8, dtype=jnp.int64))
+assert [int(x) for x in out] == [4, 6, 8, 10]
+print(json.dumps({
+    "settings": [r["name"] for r in systables.settings_rows()],
+    "BALLISTA_FUSION_AOT_DIR":
+        os.path.exists(os.environ["BALLISTA_FUSION_AOT_DIR"]),
+    "BALLISTA_PREWARM": any("prewarm" in k for k in compile_stats()),
+    "BALLISTA_PALLAS": any("pallas" in m for m in sys.modules),
+    "BALLISTA_JOIN_SYNC_WINDOW": join._SYNC_WINDOW,
+    "BALLISTA_JOIN_SYNC_WINDOW_BYTES": join._SYNC_WINDOW_BYTES,
+    "BALLISTA_JOIN_SWAP": PlannerOptions.from_settings({}).join_swap,
+    "BALLISTA_NARROW_WIRE": columnar._narrow_wire_enabled(),
+    "BALLISTA_JIT_CACHE_ENTRIES": JIT_CACHE_ENTRIES,
+    "BALLISTA_JIT_TRACES_PER_ENTRY": GovernedFunction._TRACES_PER_ENTRY,
+    "BALLISTA_SCAN_CHUNK_BYTES": text.STREAM_CHUNK_BYTES,
+}))
+"""
+
+
+@pytest.fixture(scope="module")
+def removed_knobs_probe(tmp_path_factory):
+    """One fresh process with all ten variables exported (import-time
+    readers included), reporting what each reader returns."""
+    tmp = str(tmp_path_factory.mktemp("removed_knobs"))
+    env = {**os.environ, "JAX_PLATFORMS": "cpu"}
+    for name, (value, _) in _REMOVED_KNOBS.items():
+        env[name] = value.format(tmp=tmp)
+    out = subprocess.run([sys.executable, "-c", _REMOVED_KNOBS_PROBE],
+                         capture_output=True, text=True, timeout=300,
+                         cwd=REPO, env=env)
+    assert out.returncode == 0, out.stderr[-3000:]
+    return json.loads(out.stdout.splitlines()[-1])
+
+
+@pytest.mark.parametrize("name", sorted(_REMOVED_KNOBS))
+def test_removed_knob_has_no_row_and_no_effect(removed_knobs_probe, name):
+    assert name not in systables.KNOBS
+    assert name not in removed_knobs_probe["settings"]
+    assert removed_knobs_probe[name] == _REMOVED_KNOBS[name][1]
+
+
 def test_system_compile_and_executors(ctx, clean_env):
     ctx.sql("SELECT k, sum(a) AS s FROM t GROUP BY k").collect()
     c = _fresh_select(

@@ -28,7 +28,7 @@ import pytest
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from jax.sharding import SingleDeviceSharding
 
-from ballista_tpu.kernels import aggregate, join, mesh_shuffle, pallas_agg
+from ballista_tpu.kernels import aggregate, join, mesh_shuffle
 from ballista_tpu.kernels.aggregate import AggInput
 
 
@@ -82,25 +82,13 @@ def test_dense_xla_aggregate_q1_at_8m_rows(one_chip, no_disk_cache):
         aggs = [AggInput("sum", v, None)
                 for v in (qty, price, disc_price, charge)]
         aggs.append(AggInput("count", None, None))
-        return aggregate._dense_grouped_xla(gids, live, aggs, 8)
+        return aggregate.dense_grouped_aggregate(gids, live, aggs, 8)
 
     s = functools.partial(_shape, one_chip, n)
     compiled = _compile(q1_partial, s(jnp.int32), s(jnp.bool_),
                         *[s(jnp.int64)] * 4)
     mem = compiled.memory_analysis()
     assert mem.temp_size_in_bytes + mem.argument_size_in_bytes < 16e9
-
-
-def test_pallas_aggregate_has_custom_call(one_chip, no_disk_cache):
-    n = 1 << 20
-
-    def sums(gids, live, a, b, c, d):
-        return pallas_agg.dense_grouped_sums(gids, live, [a, b, c, d], 8)
-
-    s = functools.partial(_shape, one_chip, n)
-    compiled = _compile(sums, s(jnp.int32), s(jnp.bool_),
-                        *[s(jnp.int64)] * 4)
-    assert "tpu_custom_call" in compiled.as_text()
 
 
 @pytest.mark.parametrize("dense", [True, False], ids=["dense", "sorted"])
