@@ -81,6 +81,11 @@ UNMAPPED_ALLOWLIST = {
     # maybe_compact); the time is the device's, under jit_batch_compact
     # in a device trace
     "compact.search",
+    # marker events (dur=0), one a probe batch of a join with a fused
+    # probe chain (physical/join.py _probe_inputs): compacted before
+    # its probe, or handed to the single fused program
+    "join.probe_compacted",
+    "join.probe_fused",
     # cancellation marker event (dur=0): lifecycle, not latency
     "lifecycle.cancel",
     # adaptive re-planning markers: they fire INSIDE windows that are

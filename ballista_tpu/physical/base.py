@@ -148,6 +148,47 @@ class PhysicalPlan:
             return fn(treedef, payload, num_rows, *extra)
         return self.governed_jit(subkey, build)(batch, *extra)
 
+    # -- adaptive compaction ------------------------------------------------
+    #
+    # The ONE rule for operators whose input transform can kill rows
+    # (a pipeline chain with a FilterExec; a JoinExec whose fused probe
+    # chain holds one): read the live count of what the transform left,
+    # compact when under a quarter survives (maybe_compact), and learn.
+    # A filter's selectivity is stationary within a query, so after 2
+    # consecutive batches that decline, stop paying the per-batch
+    # live-count sync for the operator's lifetime (it would otherwise
+    # serialize host scan parsing against device compute batch-by-batch
+    # for zero benefit on unselective filters). The learned capacity
+    # floor keeps later batches from compacting to ever-different
+    # power-of-two rungs, bounding downstream per-capacity jit compiles
+    # to ~one extra.
+    #
+    # BENIGN RACE: _compact_misses/_compact_floor (and JoinExec's
+    # _expand_cap_floor) are unsynchronized instance state; executor
+    # worker threads running partitions of one operator concurrently can
+    # interleave updates. Outcomes stay correct — these only steer
+    # heuristics — but learned values can thrash; the same policy covers
+    # the MetricsSet counters.
+
+    def still_compacting(self) -> bool:
+        """False once two batches in a row declined to compact: from
+        then on the operator reads no live count."""
+        return getattr(self, "_compact_misses", 0) < 2
+
+    def compact_learning(self, batch: ColumnBatch) -> ColumnBatch:
+        """``maybe_compact`` at the learned floor (one blocking count
+        read), recording whether the batch shrank. Returns ``batch``
+        itself when it declined."""
+        floor = getattr(self, "_compact_floor", 8)
+        res = maybe_compact(batch, floor=floor)
+        if res is batch:
+            self._compact_misses = getattr(self, "_compact_misses", 0) + 1
+        else:
+            self._compact_misses = 0
+            self._compact_floor = max(floor, res.capacity)
+            self.metrics().add_counter("compact_count")
+        return res
+
     def trace_twin(self) -> "PhysicalPlan":
         """Config-only shallow clone for governed closures to capture.
 
@@ -343,21 +384,8 @@ class PipelineOp(PhysicalPlan):
 
         chain, source = self._pipeline_chain()
         fused = self._fused_governed()
-        # Adaptive: a filter's selectivity is stationary within a query,
-        # so after 2 consecutive batches that decline to compact, stop
-        # paying the per-batch live-count sync for the operator's
-        # lifetime (it would otherwise serialize host scan parsing
-        # against device compute batch-by-batch for zero benefit on
-        # unselective filters). The learned capacity floor keeps later
-        # batches from compacting to ever-different power-of-two rungs,
-        # bounding downstream per-capacity jit compiles to ~one extra.
-        #
-        # BENIGN RACE: _compact_misses/_compact_floor (and JoinExec's
-        # _expand_cap_floor) are unsynchronized instance state mutated
-        # here; executor worker threads running partitions of one
-        # operator concurrently can interleave updates. Outcomes stay
-        # correct — these only steer heuristics — but learned values can
-        # thrash; the same policy covers the MetricsSet counters below.
+        # a chain that can kill rows compacts its output under the one
+        # adaptive rule (PhysicalPlan.compact_learning)
         compact = any(op.compactable for op in chain)
         for batch in source.execute(partition):
             # the governor records the compile-vs-execute split: a call
@@ -375,18 +403,8 @@ class PipelineOp(PhysicalPlan):
                     treedef, payload, num_rows)
             else:
                 out = fused(batch)
-            if compact and getattr(self, "_compact_misses", 0) < 2:
-                res = maybe_compact(
-                    out, floor=getattr(self, "_compact_floor", 8))
-                if res is out:
-                    self._compact_misses = \
-                        getattr(self, "_compact_misses", 0) + 1
-                else:
-                    self._compact_misses = 0
-                    self._compact_floor = max(
-                        getattr(self, "_compact_floor", 8), res.capacity)
-                    self.metrics().add_counter("compact_count")
-                out = res
+            if compact and self.still_compacting():
+                out = self.compact_learning(out)
             # fresh XLA output (or fresh compaction), exactly one
             # downstream consumer: donation-eligible
             mark_transient(out)
@@ -504,6 +522,11 @@ def maybe_compact(batch: ColumnBatch, shrink_factor: int = 4,
     one gathered element per survivor and column. Each compaction counts
     one ``compact.search`` event in ``tracing.span_totals()``, with the
     capacity it came from.
+
+    Callers: the adaptive rule (``PhysicalPlan.compact_learning``: a
+    pipeline chain's output, and a join's probe batch BEFORE its probe
+    when the fused probe chain holds a filter), and ``JoinExec`` after a
+    probe of a batch that was not compacted before it.
 
     Pass ``known_rows`` when the live count is already on host (e.g. the
     join expand loop just synced its overflow check) — then this never

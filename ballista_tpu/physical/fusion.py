@@ -515,7 +515,17 @@ def fuse_plan(phys: PhysicalPlan, *, fuse_joins: bool = True,
     re-plan fuses new subtrees and (value-keyed signatures) reuses every
     compiled entry. ``fuse_joins=False`` skips probe-chain fusion — the
     post-adaptive re-pass uses it so a demoted join keeps the probe
-    chain (and compiled programs) it already has."""
+    chain (and compiled programs) it already has.
+
+    The join branch moves a Filter/Projection chain off a join's probe
+    child into ``JoinExec.probe_chain``: the chain then runs inside the
+    probe programs and is no longer a ``PipelineOp`` whose output is
+    compacted. The join takes that duty over: while its chain holds a
+    ``compactable`` operator and shrinks its batches,
+    ``JoinExec._probe_inputs`` runs the chain alone, compacts, and
+    probes the small batch (the shared rule,
+    ``PhysicalPlan.compact_learning``); an unselective chain declines
+    twice and stays fused."""
     counter = _counter or itertools.count(1)
     stats = {"stages": 0, "joins": 0, "distinct": 0}
 

@@ -8,7 +8,8 @@ to a single partition).
 
 The build (left) side is materialized once and sorted (kernels.join);
 probe-side batches stream through a jitted probe that appends gathered
-build columns. FK->PK joins (unique build keys) take the no-expansion fast
+build columns (a batch whose fused probe-side filter kept under a quarter
+of it is compacted first, ``_probe_inputs``). FK->PK joins (unique build keys) take the no-expansion fast
 path; duplicate build keys fall back to the expanding probe with adaptive
 output capacity.
 
@@ -31,6 +32,7 @@ from ..datatypes import Schema
 from ..errors import ExecutionError, NotImplementedError_
 from ..kernels import join as join_k
 from ..observability.metrics import metrics_enabled
+from ..observability.tracing import trace_event
 from .base import PhysicalPlan, Partitioning, concat_batches
 
 JOIN_TYPES = ("inner", "left", "semi", "anti", "full")
@@ -43,6 +45,13 @@ JOIN_TYPES = ("inner", "left", "semi", "anti", "full")
 # the batch-count window.
 _SYNC_WINDOW = 8
 _SYNC_WINDOW_BYTES = 1 << 30
+
+
+def _chained_key(chained: bool) -> tuple:
+    """Key suffix of a probe program traced WITHOUT the fused probe
+    chain (its batch arrived chained and compacted). Empty otherwise, so
+    the fused programs keep the keys they always had."""
+    return ("chained",) if chained else ()
 
 
 class JoinExec(PhysicalPlan):
@@ -80,8 +89,9 @@ class JoinExec(PhysicalPlan):
         self.adaptive_note = adaptive_note
         # whole-stage fusion (physical/fusion.py): the Filter/Projection
         # chain that used to feed the probe side, applied INSIDE every
-        # traced probe program instead of as a separate per-batch jit.
-        # When set, ``probe`` is the chain's SOURCE; ``probe_key_raw``
+        # traced probe program instead of as a separate per-batch jit
+        # (unless it kills most rows: ``_probe_inputs`` then runs it
+        # alone and compacts before the probe). When set, ``probe`` is the chain's SOURCE; ``probe_key_raw``
         # maps each post-chain probe key column name to its raw source
         # column (for the host-side dictionary remap).
         self.probe_chain = tuple(probe_chain or ())
@@ -435,27 +445,65 @@ class JoinExec(PhysicalPlan):
             return
         from .base import maybe_compact
 
-        if unique:
-            for pb in self.probe.execute(partition):
-                remaps = self._remaps_for(build_batch, pb)
-                # selective joins strand few live rows in huge batches;
-                # compacting here shrinks every downstream operator
-                yield maybe_compact(self._probe_unique_batch(
-                    table, build_batch, pb, mode, key_tables, remaps))
-        elif self.how in ("semi", "anti"):
-            # membership only: unique probe works regardless of build
-            # dups. Selective membership tests (q16's NOT IN keeps ~15%
-            # of partsupp) strand few live rows in probe-capacity
-            # batches; compacting shrinks every downstream shape, same
-            # policy as the unique path above
-            for pb in self.probe.execute(partition):
-                remaps = self._remaps_for(build_batch, pb)
-                yield maybe_compact(self._probe_unique_batch(
-                    table, build_batch, pb, mode, key_tables, remaps))
+        inputs = self._probe_inputs(build_batch,
+                                    self.probe.execute(partition))
+        if unique or self.how in ("semi", "anti"):
+            # membership only needs the unique probe whatever the build's
+            # duplicates. Selective joins (q16's NOT IN keeps ~15% of
+            # partsupp) strand few live rows in huge batches; compacting
+            # shrinks every downstream operator. A batch compacted BEFORE
+            # its probe is not asked again: the probe can only remove
+            # rows from a batch already sized to its survivors
+            for pb, remaps, chained in inputs:
+                out = self._probe_unique_batch(
+                    table, build_batch, pb, mode, key_tables, remaps,
+                    chained)
+                yield out if chained else maybe_compact(out)
         else:
             yield from self._probe_expand_stream(
-                table, build_batch, self.probe.execute(partition), mode,
-                key_tables)
+                table, build_batch, inputs, mode, key_tables)
+
+    def _probe_inputs(self, build_batch: ColumnBatch, probe_iter):
+        """``(probe batch, remaps, chained)`` for every batch of the
+        probe side: where the probe batch is taken, for the unique,
+        semi/anti and expanding forms alike.
+
+        A fused probe chain that kills rows (a FilterExec) would make
+        the probe gather build rows for every dead row of the capacity,
+        so while the operator is still learning
+        (``PhysicalPlan.still_compacting``, the rule ``PipelineOp.execute``
+        follows) the chain runs FIRST as its own small program
+        (``join.prologue``), its survivors are counted, and a batch under
+        a quarter full is compacted before the probe: ``chained`` is then
+        True and the probe programs skip the chain (q14: 1<<20 rows ->
+        16,384, one ``join.probe_compacted`` event). A batch that declines,
+        and every batch once two in a row have, goes to the probe RAW with
+        ``chained`` False: the single fused program, no count read
+        (``join.probe_fused``)."""
+        asks = any(op.compactable for op in self.probe_chain)
+        for pb in probe_iter:
+            # raw batch: key columns under their pre-chain names
+            remaps = self._remaps_for(build_batch, pb)
+            if asks and self.still_compacting():
+                kept = self._run_probe_chain(pb)
+                small = self.compact_learning(kept)
+                if small is not kept:
+                    trace_event("join.probe_compacted",
+                                rows=int(kept.num_rows),  # already read
+                                capacity=pb.capacity, to=small.capacity)
+                    yield small, remaps, True
+                    continue
+            if self.probe_chain:
+                trace_event("join.probe_fused")
+            yield pb, remaps, False
+
+    def _run_probe_chain(self, pb: ColumnBatch) -> ColumnBatch:
+        """The fused probe chain alone, as its own governed program."""
+        def build():
+            tw = self.trace_twin()
+            return tw._probe_prologue
+
+        return self.governed_jit(("join.prologue",), build)(pb)
 
     # full outer ------------------------------------------------------------
 
@@ -475,8 +523,9 @@ class JoinExec(PhysicalPlan):
                     yield self._probe_unique_batch(table, build_batch, pb,
                                                    mode, key_tables, remaps)
                 else:
-                    yield from self._probe_expand_batch(
-                        table, build_batch, pb, mode, key_tables)
+                    yield from self._probe_expand_stream(
+                        table, build_batch, iter([(pb, remaps, False)]),
+                        mode, key_tables)
                 hit |= np.asarray(self._mark_hits(build_batch, pb, mode,
                                                   key_tables, remaps,
                                                   bkeys, blive))
@@ -636,13 +685,25 @@ class JoinExec(PhysicalPlan):
         return tuple(out)
 
     def _probe_unique_batch(self, table, build_batch, pb: ColumnBatch,
-                            mode: str, key_tables, remaps) -> ColumnBatch:
+                            mode: str, key_tables, remaps,
+                            chained: bool = False) -> ColumnBatch:
+        """Probe-aligned join of one batch as ONE program: the fused
+        probe chain (unless ``chained``: ``_probe_inputs`` already ran
+        it and compacted the batch, so the program is traced at the
+        survivors' capacity), key extraction, the unique probe and
+        assembly (a gather of every build column per probe ROW, dead or
+        live — why a selective chain is compacted first)."""
+        fn = self._unique_program(mode, chained)
+        return fn(table, build_batch, pb, key_tables, remaps)
+
+    def _unique_program(self, mode: str, chained: bool):
         def build():
             tw = self.trace_twin()
 
             def run(table, bb: ColumnBatch, pb: ColumnBatch,
                     key_tables, remaps) -> ColumnBatch:
-                pb = tw._probe_prologue(pb)
+                if not chained:
+                    pb = tw._probe_prologue(pb)
                 pkeys, plive = tw._probe_keys(pb, mode, key_tables, remaps)
                 build_rows, matched = join_k.probe_unique(table, pkeys, plive)
                 return tw._assemble(bb, pb, build_rows, matched,
@@ -650,20 +711,21 @@ class JoinExec(PhysicalPlan):
 
             return run
 
-        fn = self.governed_jit(("join.unique", mode), build)
-        return fn(table, build_batch, pb, key_tables, remaps)
+        return self.governed_jit(
+            ("join.unique", mode) + _chained_key(chained), build)
 
     # general path: expanding probe -----------------------------------------
 
     def _expand_run(self, table, build_batch, pb, mode, key_tables, remaps,
-                    out_cap: int):
+                    out_cap: int, chained: bool):
         """One async expanding-probe launch at a fixed output capacity.
         Returns (out_batch, total_matches_device) WITHOUT syncing."""
         def build():
             tw = self.trace_twin()
 
             def run(table, bb, pb, key_tables, remaps, _cap=out_cap):
-                pb = tw._probe_prologue(pb)
+                if not chained:
+                    pb = tw._probe_prologue(pb)
                 pkeys, plive = tw._probe_keys(pb, mode, key_tables,
                                               remaps)
                 prows, brows, olive, total = join_k.probe_expand(
@@ -674,18 +736,20 @@ class JoinExec(PhysicalPlan):
 
             return run
 
-        fn = self.governed_jit(("join.expand", mode, out_cap), build)
+        fn = self.governed_jit(
+            ("join.expand", mode, out_cap) + _chained_key(chained), build)
         return fn(table, build_batch, pb, key_tables, remaps)
 
     def _unmatched_batch(self, table, build_batch, pb, mode, key_tables,
-                         remaps) -> ColumnBatch:
+                         remaps, chained: bool) -> ColumnBatch:
         """left/full: preserved probe rows with no match, null build
         columns. Pure device work — no sync."""
         def build():
             tw = self.trace_twin()
 
             def run_unmatched(table, bb, pb, key_tables, remaps):
-                pb = tw._probe_prologue(pb)
+                if not chained:
+                    pb = tw._probe_prologue(pb)
                 pkeys, plive = tw._probe_keys(pb, mode, key_tables,
                                               remaps)
                 counts = join_k.probe_counts(table, pkeys)
@@ -700,19 +764,15 @@ class JoinExec(PhysicalPlan):
 
             return run_unmatched
 
-        fn = self.governed_jit(("join.unmatched", mode), build)
+        fn = self.governed_jit(
+            ("join.unmatched", mode) + _chained_key(chained), build)
         return fn(table, build_batch, pb, key_tables, remaps)
 
-    def _probe_expand_batch(self, table, build_batch, pb, mode,
-                            key_tables) -> Iterator[ColumnBatch]:
-        """Single-batch expanding probe (full-outer accumulation needs
-        per-batch lockstep with its hit-marking pass)."""
-        yield from self._probe_expand_stream(table, build_batch, iter([pb]),
-                                             mode, key_tables)
-
-    def _probe_expand_stream(self, table, build_batch, probe_iter,
+    def _probe_expand_stream(self, table, build_batch, inputs,
                              mode: str, key_tables) -> Iterator[ColumnBatch]:
-        """Expanding probe over a batch stream with DEFERRED overflow
+        """Expanding probe over a stream of ``_probe_inputs`` triples
+        (a batch its chain compacted arrives at its survivors' capacity,
+        so its output capacity starts there too) with DEFERRED overflow
         syncs: launches are asynchronous and match totals for a whole
         window are fetched in ONE ``device_get`` (every blocking sync
         drains the device queue — q5's per-batch check was the dominant
@@ -747,14 +807,15 @@ class JoinExec(PhysicalPlan):
             with trace_span("device.block", site="join.expand_totals",
                             n=len(pend)):
                 totals = jax.device_get([p[-1] for p in pend])  # ONE sync
-            for (pb, remaps, out, out_cap, _), total in zip(pend, totals):
+            for (pb, remaps, chained, out, out_cap, _), total in zip(
+                    pend, totals):
                 t = int(total)
                 while t > out_cap:  # rare: re-run at a ladder capacity
                     self.metrics().add_counter("expand_reruns")
                     out_cap = bucket_capacity(t)
                     out, tot = self._expand_run(
                         table, build_batch, pb, mode, key_tables, remaps,
-                        out_cap)
+                        out_cap, chained)
                     t = int(tot)
                     self._expand_cap_floor = max(
                         getattr(self, "_expand_cap_floor", 0), out_cap)
@@ -763,16 +824,17 @@ class JoinExec(PhysicalPlan):
                 yield maybe_compact(out, known_rows=min(t, out_cap))
                 if self.how in ("left", "full"):
                     yield self._unmatched_batch(table, build_batch, pb,
-                                                mode, key_tables, remaps)
+                                                mode, key_tables, remaps,
+                                                chained)
             pend.clear()
 
-        for pb in probe_iter:
-            remaps = self._remaps_for(build_batch, pb)
+        for pb, remaps, chained in inputs:
             out_cap = max(pb.capacity,
                           getattr(self, "_expand_cap_floor", 0))
             out, total = self._expand_run(table, build_batch, pb, mode,
-                                          key_tables, remaps, out_cap)
-            pend.append((pb, remaps, out, out_cap, total))
+                                          key_tables, remaps, out_cap,
+                                          chained)
+            pend.append((pb, remaps, chained, out, out_cap, total))
             pend_bytes += (pb.capacity + out_cap) * row_bytes
             if (len(pend) >= _SYNC_WINDOW
                     or pend_bytes >= _SYNC_WINDOW_BYTES):

@@ -49,10 +49,14 @@ def scan() -> List[Tuple[str, int, str]]:
 # fused operators are actually in the plans and (b) the number of
 # governed entries minted. Budget pinned from a measured 22 entries
 # (pre-fusion: 27 at the same scale) with small headroom for planner
-# drift — a de-fused q1 alone would add 3+ entries and trip it.
+# drift — a de-fused q1 alone would add 3+ entries and trip it. PR 32
+# spent the headroom (24 measured) and added one entry on purpose: q5's
+# join with a Filter fused into its probe side runs that chain alone
+# (``join.prologue``) for its first two batches, to learn whether to
+# compact before the probe. 25 measured, so no headroom is left.
 # ---------------------------------------------------------------------------
 
-DEFAULT_ENTRY_BUDGET = 24
+DEFAULT_ENTRY_BUDGET = 25
 
 
 def check_budget(budget: int = DEFAULT_ENTRY_BUDGET) -> int:

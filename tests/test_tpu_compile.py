@@ -169,3 +169,52 @@ def test_mesh_all_to_all_rows_on_four_chips(topo, no_disk_cache):
     compiled = _compile(exchange, s(jnp.int64), s(jnp.int64), s(jnp.bool_),
                         s(jnp.int32))
     assert "all-to-all" in compiled.as_text()
+
+
+def test_join_probe_after_compaction_at_16384_rows(one_chip, no_disk_cache,
+                                                   monkeypatch):
+    """q14's shape since PR 32: the month filter fused into the probe side
+    keeps 1.2% of a 2**20-row lineitem batch, so ``JoinExec`` compacts to
+    16,384 rows BEFORE the probe and the probe program (dense-table
+    gather, one gather a build column) is traced at that capacity,
+    without the chain. The batches are made and the choice is taken on
+    the CPU; the program it chose is then compiled for the chip."""
+    from ballista_tpu import Int32, Int64, col, lit, schema
+    from ballista_tpu.io import MemTableSource
+    from ballista_tpu.physical import base
+    from ballista_tpu.physical.fusion import fuse_plan
+    from ballista_tpu.physical.join import JoinExec
+    from ballista_tpu.physical.operators import FilterExec, ScanExec
+
+    monkeypatch.setattr(base, "_SYNC_COST", [0.0])
+    rng = np.random.default_rng(14)
+    parts, rows = 600_000, 1 << 20
+    part = MemTableSource.from_pydict(
+        schema(("p_partkey", Int64), ("p_type", Int32)),
+        {"p_partkey": np.arange(1, parts + 1),
+         "p_type": rng.integers(0, 150, parts)})
+    lineitem = MemTableSource.from_pydict(
+        schema(("l_partkey", Int64), ("l_extendedprice", Int64),
+               ("l_discount", Int64), ("l_shipdate", Int32)),
+        {"l_partkey": rng.integers(1, parts + 1, rows),
+         "l_extendedprice": rng.integers(90_000, 10_000_000, rows),
+         "l_discount": rng.integers(0, 11, rows),
+         "l_shipdate": rng.integers(8036, 8036 + 2526, rows)})
+    j = fuse_plan(JoinExec(
+        ScanExec("part", part),
+        FilterExec((col("l_shipdate") >= lit(9374))
+                   & (col("l_shipdate") < lit(9404)),
+                   ScanExec("lineitem", lineitem)),
+        [("p_partkey", "l_partkey")], "inner"))
+    table, bb, unique, _, mode, key_tables, *_ = j._materialize_build(0)
+    (pb, remaps, chained), = j._probe_inputs(bb, j.probe.execute(0))
+    assert chained and unique and table.dense_rows is not None
+    assert pb.capacity == 16384 and 10_000 < int(pb.num_rows) < 16384
+
+    fn = j._unique_program(mode, chained)
+    jitted = getattr(fn, "gf", fn).fn
+    shapes = jax.tree_util.tree_map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip),
+        (table, bb, pb, key_tables, remaps))
+    assert jax.eval_shape(jitted, *shapes).capacity == 16384
+    jitted.lower(*shapes).compile()
