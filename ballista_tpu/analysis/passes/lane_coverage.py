@@ -86,6 +86,10 @@ UNMAPPED_ALLOWLIST = {
     # its probe, or handed to the single fused program
     "join.probe_compacted",
     "join.probe_fused",
+    # marker event (dur=0), one a probe launch that searches the sorted
+    # build keys in steps of 128 (physical/join.py _note_search); the
+    # time is the device's, under jit_join_expand / jit_join_unique
+    "join.search",
     # cancellation marker event (dur=0): lifecycle, not latency
     "lifecycle.cancel",
     # adaptive re-planning markers: they fire INSIDE windows that are

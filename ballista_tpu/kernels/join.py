@@ -3,14 +3,19 @@
 TPU-native replacement for the reference's ``HashJoinExec`` (reference:
 rust/core/proto/ballista.proto:399-407, HashJoinExecNode with on-keys and
 join type). A CPU-style linked hash table doesn't map to the MXU/VPU, so the
-build side is *sorted* and the probe side does a vectorized binary search
-(XLA lowers searchsorted to a fused gather loop):
+build side is *sorted* and the probe side searches it, every probe row at
+once, in steps of 128 (``kernels/search.py``: one gathered ROW of keys a
+level, two levels under the top one at 2**21 build rows, where
+a binary search walked 21 dependent single-element gathers; the int64
+keys are compared as two int32 planes, split inside the probe program):
 
 - ``build_lookup`` sorts the build keys once;
 - ``probe_unique`` handles the FK->PK joins that dominate TPC-H (build keys
-  unique): one searchsorted + one gather, no row expansion;
+  unique): one gather from the dense table where the build has one, else
+  one stepped search + one gather; no row expansion;
 - ``probe_expand`` (general many-to-many) computes per-probe match counts
-  and materializes matches up to a static output capacity.
+  (two searches of the build keys) and materializes matches up to a static
+  output capacity (one search of the running match count a slot).
 
 Keys are single int64 columns (dict codes / ints / dates cast to int64).
 """
@@ -23,16 +28,20 @@ from typing import Optional, Tuple
 import jax
 import jax.numpy as jnp
 
+from .search import build_levels, count_below
+
 INT64_SENTINEL = jnp.iinfo(jnp.int64).max
 
 
 @dataclass
 class BuildTable:
-    """Build side of a join: always carries the sorted representation;
-    near-dense integer keys additionally carry a direct-index table
-    (``dense_rows``/``dense_base``) so probes are ONE gather instead of
-    a ~log2(Nb)-step binary search — the decisive difference on TPU,
-    where each searchsorted step is a dependent gather."""
+    """Build side of a join: always carries the sorted representation,
+    which the probes search in steps of 128 (``kernels/search.py``; its
+    levels are built inside the probe program, one pass over
+    ``sorted_keys``, so the table holds nothing for them); near-dense
+    integer keys additionally carry a direct-index table
+    (``dense_rows``/``dense_base``) so probes are ONE gather with no
+    search at all."""
 
     sorted_keys: jax.Array  # int64 [Nb] (dead rows = sentinel, at end)
     order: jax.Array  # int32 [Nb] original row index per sorted slot
@@ -103,8 +112,8 @@ def probe_unique(
             jnp.logical_and(in_range, row >= 0), probe_live)
         return jnp.where(matched, row, 0), matched
     nb = table.sorted_keys.shape[0]
-    idx = jnp.searchsorted(table.sorted_keys, probe_keys, side="left")
-    idx = jnp.minimum(idx, nb - 1).astype(jnp.int32)
+    idx = count_below(build_levels(table.sorted_keys), probe_keys)
+    idx = jnp.minimum(idx, nb - 1)
     hit = jnp.equal(table.sorted_keys[idx], probe_keys)
     hit = jnp.logical_and(hit, probe_keys != INT64_SENTINEL)
     matched = jnp.logical_and(hit, probe_live)
@@ -122,9 +131,9 @@ def probe_semi(
 
 def probe_counts(table: BuildTable, probe_keys: jax.Array) -> jax.Array:
     """Number of build matches per probe key (for many-to-many planning)."""
-    lo = jnp.searchsorted(table.sorted_keys, probe_keys, side="left")
-    hi = jnp.searchsorted(table.sorted_keys, probe_keys, side="right")
-    return (hi - lo).astype(jnp.int32)
+    levels = build_levels(table.sorted_keys)
+    return (count_below(levels, probe_keys, "right")
+            - count_below(levels, probe_keys, "left"))
 
 
 def probe_expand(
@@ -141,20 +150,20 @@ def probe_expand(
     bigger capacity (host-side fallback policy).
     """
     keyed = jnp.where(probe_live, probe_keys, INT64_SENTINEL - 1)
-    lo = jnp.searchsorted(table.sorted_keys, keyed, side="left")
-    hi = jnp.searchsorted(table.sorted_keys, keyed, side="right")
-    counts = (hi - lo).astype(jnp.int32)
+    levels = build_levels(table.sorted_keys)
+    lo = count_below(levels, keyed, "left")
+    counts = count_below(levels, keyed, "right") - lo
     counts = jnp.where(probe_live, counts, 0)
-    offsets = jnp.cumsum(counts) - counts  # exclusive prefix sum
+    ends = jnp.cumsum(counts)
+    offsets = ends - counts  # exclusive prefix sum
     total = jnp.sum(counts)
 
     C = out_capacity
     out_slot = jnp.arange(C, dtype=jnp.int32)
     # For each output slot, find its probe row: the row whose [offset,
-    # offset+count) window contains the slot.
-    probe_of_slot = (
-        jnp.searchsorted(offsets + counts, out_slot, side="right")
-    ).astype(jnp.int32)
+    # offset+count) window contains the slot, so the first whose running
+    # count of matches passes the slot.
+    probe_of_slot = count_below(build_levels(ends), out_slot, "right")
     np_rows = probe_keys.shape[0]
     probe_of_slot = jnp.minimum(probe_of_slot, np_rows - 1)
     within = out_slot - offsets[probe_of_slot]

@@ -22,6 +22,9 @@ from ..columnar import Column, ColumnBatch
 from ..compile import bucket_capacity, governed
 from ..datatypes import Schema
 from ..errors import ExecutionError
+from ..kernels.search import (  # noqa: F401 - compact_perm's shapes
+    BLOCK as _BLOCK, QUERY_CHUNK as _QUERY_CHUNK, TOP as _TOP,
+    build_levels, count_below)
 from ..observability.metrics import (MetricsSet, instrument_execute,
                                      metrics_enabled)
 from ..observability.tracing import trace_event
@@ -579,17 +582,11 @@ def pad_batch(batch: ColumnBatch, capacity: int) -> ColumnBatch:
     return ColumnBatch(batch.schema, cols, selection, batch.num_rows)
 
 
-# compact_perm's shapes (PERF.md, PR 29, has the chip table they were
-# read from). A gather of one row of _BLOCK running counts costs the chip
-# no more than a gather of one element, so the search descends in steps
-# of _BLOCK, not of 2; a level of at most _TOP counts is compared
-# whole; queries go _QUERY_CHUNK at a time so that the gathered rows stay
-# tens of MiB; the running count is taken _COUNT_ROW rows at a time
-# because XLA's TPU compiler spends 15-30 s on a one-pass cumsum over
-# 2**20 rows and under a second on the two-level one, at the same speed.
-_BLOCK = 128
-_TOP = 256
-_QUERY_CHUNK = 1 << 16
+# compact_perm searches in the steps of kernels/search.py (whose
+# _BLOCK, _TOP and _QUERY_CHUNK these are); the running count is taken
+# _COUNT_ROW rows at a time because XLA's TPU compiler spends 15-30 s on
+# a one-pass cumsum over 2**20 rows and under a second on the two-level
+# one, at the same speed (PERF.md, PR 29).
 _COUNT_ROW = 4096
 
 
@@ -613,44 +610,17 @@ def compact_perm(selection: jax.Array, size: int) -> jax.Array:
     fill_value=0)[0]``. Traced.
 
     The k-th live row is the first whose running count reaches k, so it
-    is SEARCHED for, for k = 1..size: the running count, then above it
-    the count at the end of every _BLOCK rows, and again until a level
-    is short enough to compare whole; a query walks down from there, one
-    gathered row of counts a level. One pass over the capacity plus
-    ``size`` row gathers a level (two levels under the top one at 2**20
-    rows): the cost follows the rows kept. There is no scatter (``jnp.nonzero`` sends
-    one update for EVERY row of the capacity, dead ones too, and the
-    chip scatters an element at a time: 72 ms at 2**20 rows whatever
+    is SEARCHED for, for k = 1..size, in the steps of
+    ``kernels/search.py``: one pass over the capacity plus ``size`` row
+    gathers a level (two levels under the top one at 2**20 rows), so
+    the cost follows the rows kept. There is no scatter (``jnp.nonzero``
+    sends one update for EVERY row of the capacity, dead ones too, and
+    the chip scatters an element at a time: 72 ms at 2**20 rows whatever
     survives) and no lax.sort."""
     count = _running_count(selection)
-    total = count[-1]
-    levels, top = [], count  # levels: rows of _BLOCK counts, finest first
-    while top.shape[0] > _TOP:
-        # edge padding keeps the level sorted; only a k beyond the
-        # total, which is answered 0 below, can land in it
-        rows = jnp.pad(top, (0, -top.shape[0] % _BLOCK), mode="edge") \
-            .reshape(-1, _BLOCK)
-        levels.append(rows)
-        top = rows[:, -1]
-
-    def first_reaching(k):
-        """For a vector of k: the first row whose count reaches each."""
-        at = jnp.minimum(
-            jnp.sum(top[None, :] < k[:, None], axis=1, dtype=jnp.int32),
-            top.shape[0] - 1)
-        for rows in reversed(levels):
-            # rows[at] with a vector of row numbers: the one form of
-            # gather the chip does a whole row at a time
-            before = jnp.sum(rows[at] < k[:, None], axis=1, dtype=jnp.int32)
-            at = at * _BLOCK + jnp.minimum(before, _BLOCK - 1)
-        return jnp.where(k <= total, at, 0)
-
-    if size <= _QUERY_CHUNK:
-        return first_reaching(jnp.arange(1, size + 1, dtype=jnp.int32))
-    chunks = -(-size // _QUERY_CHUNK)  # the last one's k past size are cut off
-    kth = jnp.arange(1, chunks * _QUERY_CHUNK + 1, dtype=jnp.int32)
-    return jax.lax.map(first_reaching, kth.reshape(chunks, _QUERY_CHUNK)) \
-        .reshape(-1)[:size]
+    kth = jnp.arange(1, size + 1, dtype=jnp.int32)
+    return jnp.where(kth <= count[-1],
+                     count_below(build_levels(count), kth), 0)
 
 
 def take_batch(batch: ColumnBatch, perm: jax.Array, live: jax.Array) -> ColumnBatch:

@@ -31,6 +31,7 @@ from ..compile import bucket_capacity, governed
 from ..datatypes import Schema
 from ..errors import ExecutionError, NotImplementedError_
 from ..kernels import join as join_k
+from ..kernels.search import depth as search_depth
 from ..observability.metrics import metrics_enabled
 from ..observability.tracing import trace_event
 from .base import PhysicalPlan, Partitioning, concat_batches
@@ -45,6 +46,14 @@ JOIN_TYPES = ("inner", "left", "semi", "anti", "full")
 # the batch-count window.
 _SYNC_WINDOW = 8
 _SYNC_WINDOW_BYTES = 1 << 30
+
+
+def _note_search(table, pb: ColumnBatch) -> None:
+    """One probe batch goes through the stepped search of the sorted
+    build keys (``kernels/search.py``); the dense table's does not."""
+    build = table.sorted_keys.shape[0]
+    trace_event("join.search", probes=pb.capacity, build=build,
+                levels=search_depth(build))
 
 
 def _chained_key(chained: bool) -> tuple:
@@ -694,6 +703,8 @@ class JoinExec(PhysicalPlan):
         assembly (a gather of every build column per probe ROW, dead or
         live — why a selective chain is compacted first)."""
         fn = self._unique_program(mode, chained)
+        if table.dense_rows is None:
+            _note_search(table, pb)
         return fn(table, build_batch, pb, key_tables, remaps)
 
     def _unique_program(self, mode: str, chained: bool):
@@ -738,6 +749,7 @@ class JoinExec(PhysicalPlan):
 
         fn = self.governed_jit(
             ("join.expand", mode, out_cap) + _chained_key(chained), build)
+        _note_search(table, pb)
         return fn(table, build_batch, pb, key_tables, remaps)
 
     def _unmatched_batch(self, table, build_batch, pb, mode, key_tables,

@@ -109,6 +109,39 @@ def test_join_probe_unique_at_1m_rows(one_chip, no_disk_cache, dense):
              s(jnp.int32), scalar(jnp.int64), s(jnp.int64), s(jnp.bool_))
 
 
+def test_join_probe_expand_at_q3_shapes(one_chip, no_disk_cache):
+    """q3's expanding probe at SF3 standalone (131,072 probe rows, output
+    capacity 131,072, a 2**22-row partition of int64 build keys: the
+    ``join.search`` events of a chip run, PERF.md, PR 36; served it is
+    32,768 over 2**21, the same two levels): every search goes
+    down kernels/search.py's levels, so inside a ``while`` (the chunk
+    loop's; ``jnp.searchsorted``'s was a loop of log2(n) dependent
+    element gathers, 100 of the program's 102 ms on the chip: PERF.md,
+    PR 36) every gather takes a whole row of 128; the element gathers
+    that are left assemble the output, once a slot. Well under a minute:
+    the program holds no sort."""
+    import re
+    import time
+
+    nb, rows = 1 << 22, 131072
+    scalar = functools.partial(jax.ShapeDtypeStruct, (), sharding=one_chip)
+
+    def probe(sorted_keys, order, num_live, probe_keys, probe_live):
+        table = join.BuildTable(sorted_keys, order, num_live)
+        return join.probe_expand(table, probe_keys, probe_live, rows)
+
+    started = time.monotonic()
+    compiled = _compile(
+        probe, _shape(one_chip, nb, jnp.int64), _shape(one_chip, nb, jnp.int32),
+        scalar(jnp.int32), _shape(one_chip, rows, jnp.int64),
+        _shape(one_chip, rows, jnp.bool_))
+    assert time.monotonic() - started < 60
+    text = compiled.as_text()
+    assert " sort(" not in text and " scatter(" not in text
+    looped = [g for g in re.findall(r" gather\(.*", text) if "/while/" in g]
+    assert looped and all("slice_sizes={1,128}" in g for g in looped)
+
+
 def test_sort_based_aggregate_at_first_rung(one_chip, no_disk_cache):
     """Two-key ``grouped_aggregate`` (the multi-operand lax.sort form) at
     1,024 rows only — see the module docstring for why not larger."""
