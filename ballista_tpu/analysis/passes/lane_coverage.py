@@ -90,6 +90,17 @@ UNMAPPED_ALLOWLIST = {
     # build keys in steps of 128 (physical/join.py _note_search); the
     # time is the device's, under jit_join_expand / jit_join_unique
     "join.search",
+    # the mesh exchange (physical/mesh_input.py, mesh_agg.py,
+    # distributed/scheduler.py _fuse_mesh_stages): marker events (dur=0)
+    # for a side exchanged over the mesh and for a join or aggregate the
+    # fusion pass fused or left alone, and the span around a fused
+    # stage's stacked input, which runs inside the executor's task
+    # window; the device's time is under jit_mesh_join_spmd and
+    # jit_mesh_agg_spmd in a device trace
+    "mesh.exchange",
+    "mesh.assemble",
+    "mesh.fused",
+    "mesh.unfused",
     # cancellation marker event (dur=0): lifecycle, not latency
     "lifecycle.cancel",
     # adaptive re-planning markers: they fire INSIDE windows that are

@@ -105,30 +105,36 @@ def _fuse_mesh_stages(stages, n_mesh: int):
             wrappers.append(plan)
             plan = plan.children()[0]
         def _shuffle_producer(node):
-            """The single hash-shuffle producer stage behind an
-            UnresolvedShuffleExec (referenced nowhere else, so dropping
-            it is safe), or None."""
+            """(producer, None) for the single hash-shuffle producer
+            stage behind an UnresolvedShuffleExec (referenced nowhere
+            else, so dropping it is safe), else (None, why not)."""
             if not (isinstance(node, UnresolvedShuffleExec)
                     and len(node.query_stage_ids) == 1):
-                return None
+                return None, "not partitioned"
             sid = node.query_stage_ids[0]
             prod = by_id.get(sid)
-            if prod is None or sid in dropped or refcount[sid] != 1 \
-                    or not prod.shuffle_output_partitions \
+            if prod is None or sid in dropped or refcount[sid] != 1:
+                return None, "refcount"
+            if not prod.shuffle_output_partitions \
                     or not prod.shuffle_hash_exprs:
-                return None
-            return prod
+                return None, "not partitioned"
+            return prod, None
 
         new_plan = None
         if isinstance(plan, HashAggregateExec) and plan.mode == "final":
-            producer = _shuffle_producer(plan.child)
-            if producer is not None:
+            producer, why_not = _shuffle_producer(plan.child)
+            if producer is None:
+                trace_event("mesh.unfused", stage=stage.stage_id,
+                            op="aggregate", reason=why_not)
+            else:
                 dropped.add(producer.stage_id)
                 new_plan = MeshAggExec(
                     producer.child, plan.group_exprs, plan.agg_exprs,
                     list(producer.shuffle_hash_exprs), n_mesh,
                     plan.group_capacity,
                 )
+                trace_event("mesh.fused", stage=stage.stage_id,
+                            op="aggregate", n_dev=n_mesh)
                 log.info("fused stages %d+%d into a %d-device mesh "
                          "shuffle-agg", producer.stage_id, stage.stage_id,
                          n_mesh)
@@ -138,11 +144,25 @@ def _fuse_mesh_stages(stages, n_mesh: int):
             # the subtree; everything above it runs on host over the
             # fused single-partition output
             def replace_join(node):
-                if isinstance(node, JoinExec) and node.partitioned:
-                    bprod = _shuffle_producer(node.build)
-                    pprod = _shuffle_producer(node.probe)
-                    if bprod is not None and pprod is not None:
+                if isinstance(node, JoinExec):
+                    on = ",".join(f"{l}={r}" for l, r in node.on)
+                    why_not = None if node.partitioned else "broadcast"
+                    if why_not is None:
+                        bprod, why_not = _shuffle_producer(node.build)
+                    if why_not is None:
+                        pprod, why_not = _shuffle_producer(node.probe)
+                    if why_not is not None:
+                        # said, not silent: this join's rows do not cross
+                        # the mesh (a merged-build join moves its build
+                        # side through the data plane)
+                        trace_event("mesh.unfused", stage=stage.stage_id,
+                                    op="join", on=on, reason=why_not)
+                        log.info("stage %d: join on %s stays off the mesh "
+                                 "(%s)", stage.stage_id, on, why_not)
+                    else:
                         dropped.update({bprod.stage_id, pprod.stage_id})
+                        trace_event("mesh.fused", stage=stage.stage_id,
+                                    op="join", on=on, n_dev=n_mesh)
                         log.info(
                             "fused stages %d+%d+%d into a %d-device mesh "
                             "shuffle-join (how=%s)", bprod.stage_id,

@@ -18,9 +18,14 @@ order): exact for every int64, negative, sentinel or packed. One path
 for every key and every size: a vector of at most ``TOP`` entries is
 compared whole with no level.
 
-Callers: ``physical/base.py`` ``compact_perm`` (the running count of
-live rows) and the probes of ``kernels/join.py`` (the sorted build keys
-and the running count of matches).
+``first_live`` is the other half of a compaction: the k-th live row of
+a mask is the first whose running count reaches k, so it is searched
+for, with no scatter and no sort.
+
+Callers: ``physical/base.py`` ``compact_perm`` (``first_live`` as it
+stands), the probes of ``kernels/join.py`` (the sorted build keys and
+the running count of matches) and the pack of
+``kernels/mesh_shuffle.py`` (``first_live`` once a destination).
 """
 
 from __future__ import annotations
@@ -114,3 +119,41 @@ def count_below(sorted_levels: SortedLevels, queries: jax.Array,
         return descend(queries)
     chunks = jnp.pad(queries, (0, -n % QUERY_CHUNK)).reshape(-1, QUERY_CHUNK)
     return jax.lax.map(descend, chunks).reshape(-1)[:n]
+
+
+# The running count is taken COUNT_ROW rows at a time because XLA's TPU
+# compiler spends 15-30 s on a one-pass cumsum over 2**20 rows and under
+# a second on the two-level one, at the same speed (PERF.md, PR 29).
+COUNT_ROW = 4096
+
+
+def running_count(selection: jax.Array) -> jax.Array:
+    """How many live rows there are up to and including each row (int32:
+    the chip's lanes are 32-bit and a capacity fits)."""
+    n = selection.shape[0]
+    live = selection.astype(jnp.int32)
+    if n <= COUNT_ROW:
+        return jnp.cumsum(live, dtype=jnp.int32)
+    rows = jnp.pad(live, (0, -n % COUNT_ROW)).reshape(-1, COUNT_ROW)
+    within = jnp.cumsum(rows, axis=1, dtype=jnp.int32)
+    ends = jnp.cumsum(within[:, -1], dtype=jnp.int32)
+    return (within + (ends - within[:, -1])[:, None]).reshape(-1)[:n]
+
+
+def first_live(selection: jax.Array, size: int) -> jax.Array:
+    """Indices of the first ``size`` live rows, in order (0 where there
+    are fewer): the gather permutation of a stable front-compaction,
+    element for element ``jnp.nonzero(selection, size=size,
+    fill_value=0)[0]``. Traced.
+
+    The k-th live row is the first whose running count reaches k, so it
+    is SEARCHED for, for k = 1..size: one pass over the capacity plus
+    ``size`` row gathers a level (two levels under the top one at 2**20
+    rows), so the cost follows the rows kept. There is no scatter
+    (``jnp.nonzero`` sends one update for EVERY row of the capacity,
+    dead ones too, and the chip scatters an element at a time: 72 ms at
+    2**20 rows whatever survives) and no lax.sort."""
+    count = running_count(selection)
+    kth = jnp.arange(1, size + 1, dtype=jnp.int32)
+    return jnp.where(kth <= count[-1],
+                     count_below(build_levels(count), kth), 0)

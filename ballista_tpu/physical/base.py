@@ -23,8 +23,8 @@ from ..compile import bucket_capacity, governed
 from ..datatypes import Schema
 from ..errors import ExecutionError
 from ..kernels.search import (  # noqa: F401 - compact_perm's shapes
-    BLOCK as _BLOCK, QUERY_CHUNK as _QUERY_CHUNK, TOP as _TOP,
-    build_levels, count_below)
+    BLOCK as _BLOCK, COUNT_ROW as _COUNT_ROW, QUERY_CHUNK as _QUERY_CHUNK,
+    TOP as _TOP, first_live)
 from ..observability.metrics import (MetricsSet, instrument_execute,
                                      metrics_enabled)
 from ..observability.tracing import trace_event
@@ -582,45 +582,10 @@ def pad_batch(batch: ColumnBatch, capacity: int) -> ColumnBatch:
     return ColumnBatch(batch.schema, cols, selection, batch.num_rows)
 
 
-# compact_perm searches in the steps of kernels/search.py (whose
-# _BLOCK, _TOP and _QUERY_CHUNK these are); the running count is taken
-# _COUNT_ROW rows at a time because XLA's TPU compiler spends 15-30 s on
-# a one-pass cumsum over 2**20 rows and under a second on the two-level
-# one, at the same speed (PERF.md, PR 29).
-_COUNT_ROW = 4096
-
-
-def _running_count(selection: jax.Array) -> jax.Array:
-    """How many live rows there are up to and including each row (int32:
-    the chip's lanes are 32-bit and a capacity fits)."""
-    n = selection.shape[0]
-    live = selection.astype(jnp.int32)
-    if n <= _COUNT_ROW:
-        return jnp.cumsum(live, dtype=jnp.int32)
-    rows = jnp.pad(live, (0, -n % _COUNT_ROW)).reshape(-1, _COUNT_ROW)
-    within = jnp.cumsum(rows, axis=1, dtype=jnp.int32)
-    ends = jnp.cumsum(within[:, -1], dtype=jnp.int32)
-    return (within + (ends - within[:, -1])[:, None]).reshape(-1)[:n]
-
-
-def compact_perm(selection: jax.Array, size: int) -> jax.Array:
-    """Indices of the first ``size`` live rows, in order (0 where there
-    are fewer): the gather permutation of a stable front-compaction,
-    element for element ``jnp.nonzero(selection, size=size,
-    fill_value=0)[0]``. Traced.
-
-    The k-th live row is the first whose running count reaches k, so it
-    is SEARCHED for, for k = 1..size, in the steps of
-    ``kernels/search.py``: one pass over the capacity plus ``size`` row
-    gathers a level (two levels under the top one at 2**20 rows), so
-    the cost follows the rows kept. There is no scatter (``jnp.nonzero``
-    sends one update for EVERY row of the capacity, dead ones too, and
-    the chip scatters an element at a time: 72 ms at 2**20 rows whatever
-    survives) and no lax.sort."""
-    count = _running_count(selection)
-    kth = jnp.arange(1, size + 1, dtype=jnp.int32)
-    return jnp.where(kth <= count[-1],
-                     count_below(build_levels(count), kth), 0)
+# compact_perm is kernels/search.py's first_live (whose _BLOCK, _TOP,
+# _QUERY_CHUNK and _COUNT_ROW these are): the k-th live row by searching
+# the running count, shared with the mesh exchange's pack.
+compact_perm = first_live
 
 
 def take_batch(batch: ColumnBatch, perm: jax.Array, live: jax.Array) -> ColumnBatch:

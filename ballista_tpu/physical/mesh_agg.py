@@ -45,14 +45,14 @@ from .base import PhysicalPlan, Partitioning
 
 
 def _shuffle_side(b: ColumnBatch, hash_exprs, ev: Evaluator, n_dev: int,
-                  in_cap: int, axis: str = "data") -> ColumnBatch:
+                  slots: int, axis: str = "data") -> ColumnBatch:
     """Traced: hash rows by ``hash_exprs`` and exchange them over the
-    mesh axis; returns the post-shuffle per-device batch (capacity
-    n_dev * in_cap)."""
+    mesh axis, ``slots`` a destination; returns the post-shuffle
+    per-device batch (capacity n_dev * slots)."""
     dest = _partition_ids(b, hash_exprs, n_dev, ev)
     arrays = [c.values for c in b.columns] + [c.validity for c in b.columns]
     out_arrays, out_live, _counts = mesh_shuffle.all_to_all_rows(
-        arrays, b.selection, dest, axis, n_dev, dest_capacity=in_cap,
+        arrays, b.selection, dest, axis, n_dev, dest_capacity=slots,
     )
     nf = len(b.schema.fields)
     cols = [
@@ -62,6 +62,78 @@ def _shuffle_side(b: ColumnBatch, hash_exprs, ev: Evaluator, n_dev: int,
     ]
     return ColumnBatch(b.schema, cols, out_live,
                        jnp.sum(out_live).astype(jnp.int32))
+
+
+def exchange_slots(plan: PhysicalPlan, sides, mesh) -> List[dict]:
+    """Slots a destination for each side of one exchange, from the counts
+    the exchange itself will send: ``sides`` is ``[(name, stacked batch,
+    hash exprs, evaluator)]``. One small SPMD program hashes every side
+    and takes, over the whole mesh, the largest count any device has for
+    any destination and the live rows; ONE host read (n_dev-replicated
+    int32s, not data) then sizes the send buffers at the bucket of that
+    count, so a device receives about the rows bound for it where a
+    whole input capacity a source was ``n_dev`` times that. The same
+    data gives the same bucket, so a warm plan meets the programs it
+    compiled. Returns a side's ``slots`` with what ``note_exchange``
+    says of it at every launch: the live ``rows``, and the ``slots``
+    and ``bytes`` over the whole mesh."""
+    from functools import partial
+
+    from jax import shard_map
+
+    from ..compile import MESH_NS_CAP, governed
+
+    n_dev = mesh.devices.size
+    axis = mesh.axis_names[0]
+    stacked = tuple(st for _, st, _, _ in sides)
+
+    def build():
+        hashed = [(hx, ev) for _, _, hx, ev in sides]
+
+        @partial(shard_map, mesh=mesh, in_specs=(P(axis),),
+                 out_specs=P(axis), check_vma=False)
+        def run(stacked_sides):
+            out = []
+            for st, (hx, ev) in zip(stacked_sides, hashed):
+                b = jax.tree.map(lambda x: x[0], st)
+                counts = mesh_shuffle.destination_counts(
+                    b.selection, _partition_ids(b, hx, n_dev, ev), n_dev)
+                out += [jax.lax.pmax(jnp.max(counts), axis),
+                        jax.lax.psum(jnp.sum(counts), axis)]
+            return jnp.stack(out)[None]
+
+        return run
+
+    key = ("mesh.exchange_counts", plan.compile_signature(),
+           tuple(name for name, _, _, _ in sides), mesh,
+           tuple(int(st.selection.shape[1]) for st in stacked),
+           jax.tree.structure(stacked))
+    counted = governed(key, build, cap=MESH_NS_CAP,
+                       metrics=plan.metrics())(stacked)
+    from ..observability.tracing import trace_span
+
+    with trace_span("device.block", site="mesh.exchange_counts",
+                    n=len(sides)):
+        # replicated by the pmax/psum: any local shard holds them all
+        got = np.asarray(counted.addressable_shards[0].data).reshape(-1)
+    planned = []
+    for i, (name, st, _, _) in enumerate(sides):
+        largest, rows = int(got[2 * i]), int(got[2 * i + 1])
+        cap = bucket_capacity(max(largest, 1))
+        # the values of every column, and one int32 word of validity bits
+        width = 4 + sum(c.values.dtype.itemsize for c in st.columns)
+        planned.append({"slots": cap, "event": dict(
+            side=name, rows=rows, slots=n_dev * n_dev * cap,
+            bytes=rows * width, n_dev=n_dev)})
+    return planned
+
+
+def note_exchange(planned: List[dict]) -> None:
+    """One ``mesh.exchange`` event a side, at the launch that sends it."""
+    from .mesh_input import note
+
+    for side in planned:
+        note("exchanges", "mesh.exchange", **side["event"])
 
 
 def _host_visible(stacked, mesh):
@@ -153,7 +225,7 @@ class MeshAggExec(PhysicalPlan):
 
     # -- execution -----------------------------------------------------------
 
-    def _spmd(self, stacked, mesh, cap: int, in_cap: int):
+    def _spmd(self, stacked, mesh, cap: int, slots: int):
         """(stacked batch pytree) -> (stacked out batch, num_groups[n])."""
         from functools import partial
 
@@ -166,14 +238,14 @@ class MeshAggExec(PhysicalPlan):
 
         def build():
             tw = self.trace_twin()
-            final_fn = self._final._get_grouped_fn(cap, n_dev * in_cap)
+            final_fn = self._final._get_grouped_fn(cap, n_dev * slots)
 
             @partial(shard_map, mesh=mesh, in_specs=(P("data"),),
                      out_specs=(P("data"), P("data")), check_vma=False)
             def run(stacked_b):
                 b = jax.tree.map(lambda x: x[0], stacked_b)
                 b2 = _shuffle_side(b, tw.hash_exprs, tw._ev, n_dev,
-                                   in_cap)
+                                   slots)
                 out_batch, num_groups = final_fn(b2)
                 return (
                     jax.tree.map(lambda x: x[None], out_batch),
@@ -183,7 +255,7 @@ class MeshAggExec(PhysicalPlan):
             return run
 
         key = ("mesh.agg_spmd", self.compile_signature(), mesh, cap,
-               in_cap, jax.tree.structure(stacked))
+               slots, jax.tree.structure(stacked))
         return governed(key, build, cap=_MESH_NS_CAP,
                         metrics=self.metrics())(stacked)
 
@@ -194,11 +266,15 @@ class MeshAggExec(PhysicalPlan):
         from ..parallel.multihost import host_max
         from .mesh_input import stacked_input
 
-        stacked, in_cap = stacked_input(self.producer, self._partial_schema,
-                                        mesh)
+        stacked, _ = stacked_input(self.producer, self._partial_schema,
+                                   mesh)
+        planned = exchange_slots(
+            self, [("agg", stacked, self.hash_exprs, self._ev)], mesh)
+        slots = planned[0]["slots"]
         cap = self.group_capacity
         while True:
-            out_stacked, num_groups = self._spmd(stacked, mesh, cap, in_cap)
+            note_exchange(planned)
+            out_stacked, num_groups = self._spmd(stacked, mesh, cap, slots)
             ng = host_max(num_groups)  # multihost-safe replicated max
             if ng <= cap:
                 return out_stacked
@@ -298,8 +374,13 @@ class MeshJoinExec(PhysicalPlan):
 
     # -- execution -----------------------------------------------------------
 
+    def _hash_exprs(self):
+        """(build, probe) hash expressions: the join keys of each side."""
+        return ([ex.ColumnRef(b) for b, _ in self.on],
+                [ex.ColumnRef(p) for _, p in self.on])
+
     def _spmd(self, stacked_b, stacked_p, mesh, remaps, out_cap: int,
-              b_cap: int, p_cap: int):
+              b_slots: int, p_slots: int):
         from functools import partial as fpartial
 
         from ..kernels import join as join_k
@@ -311,8 +392,7 @@ class MeshJoinExec(PhysicalPlan):
             n_dev = self.n_devices
             bcols = [b for b, _ in self.on]
             pcols = [p for _, p in self.on]
-            bhash = [ex.ColumnRef(c) for c in bcols]
-            phash = [ex.ColumnRef(c) for c in pcols]
+            bhash, phash = self._hash_exprs()
             out_schema = self.output_schema()
             probe_schema = self.probe_producer.output_schema()
             tw = self.trace_twin()
@@ -323,8 +403,8 @@ class MeshJoinExec(PhysicalPlan):
             def run(sb, sp, remaps):
               b = jax.tree.map(lambda x: x[0], sb)
               p = jax.tree.map(lambda x: x[0], sp)
-              b2 = _shuffle_side(b, bhash, tw._build_ev, n_dev, b_cap)
-              p2 = _shuffle_side(p, phash, tw._probe_ev, n_dev, p_cap)
+              b2 = _shuffle_side(b, bhash, tw._build_ev, n_dev, b_slots)
+              p2 = _shuffle_side(p, phash, tw._probe_ev, n_dev, p_slots)
               # keys: raw for a single column, exact rank codec otherwise
               if len(tw.on) == 1:
                   bk = b2.column(bcols[0]).values.astype(jnp.int64)
@@ -446,7 +526,7 @@ class MeshJoinExec(PhysicalPlan):
         from ..compile import MESH_NS_CAP, governed
 
         key = ("mesh.join_spmd", self.compile_signature(), mesh, out_cap,
-               b_cap, p_cap,
+               b_slots, p_slots,
                jax.tree.structure((stacked_b, stacked_p, remaps)))
         return governed(key, build, cap=MESH_NS_CAP,
                         metrics=self.metrics())(stacked_b, stacked_p,
@@ -458,19 +538,25 @@ class MeshJoinExec(PhysicalPlan):
         SPMD program; stacked [n_dev, out_cap] output stays sharded."""
         from .mesh_input import stacked_input
 
-        sb, b_cap = stacked_input(
+        sb, _ = stacked_input(
             self.build_producer, self.build_producer.output_schema(), mesh)
-        sp, p_cap = stacked_input(
+        sp, _ = stacked_input(
             self.probe_producer, self.probe_producer.output_schema(), mesh)
         remaps = self._join._remaps_for(sb, sp)
         from ..parallel.multihost import host_max
 
-        out_cap = self.n_devices * p_cap  # post-shuffle probe rows/device
+        bhash, phash = self._hash_exprs()
+        planned = exchange_slots(
+            self, [("build", sb, bhash, self._build_ev),
+                   ("probe", sp, phash, self._probe_ev)], mesh)
+        b_slots, p_slots = (side["slots"] for side in planned)
+        out_cap = self.n_devices * p_slots  # post-shuffle probe rows/device
         if self.how == "full":  # + room for unmatched build rows
-            out_cap = bucket_capacity(out_cap + self.n_devices * b_cap)
+            out_cap = bucket_capacity(out_cap + self.n_devices * b_slots)
         while True:
+            note_exchange(planned)
             out_stacked, totals = self._spmd(sb, sp, mesh, remaps, out_cap,
-                                             b_cap, p_cap)
+                                             b_slots, p_slots)
             t = host_max(totals)  # multihost-safe replicated max
             if t <= out_cap:
                 return out_stacked

@@ -39,16 +39,28 @@ from ..compile import bucket_capacity, governed
 from ..datatypes import Schema
 from ..parallel.mesh import shard_map
 
-# Instrumentation (tests assert the device path actually ran):
-#   slot_assemblies — producer outputs laid out over the mesh on device;
+# Instrumentation (tests assert the device path actually ran; the
+# benchmark's mesh readers take the same events from the trace ring):
+#   slot_assemblies — producer outputs laid out over the mesh on device
+#                     (a ``mesh.assemble`` span, ``how="assembled"``);
 #   chained_stages  — stage inputs taken straight from a fused producer's
-#                     stacked HBM output (no re-assembly at all).
-STATS = {"slot_assemblies": 0, "chained_stages": 0}
+#                     stacked HBM output (``how="chained"``);
+#   exchanges       — sides exchanged over the mesh (``mesh.exchange``).
+STATS = {"slot_assemblies": 0, "chained_stages": 0, "exchanges": 0}
 
 
 def reset_stats() -> None:
-    STATS["slot_assemblies"] = 0
-    STATS["chained_stages"] = 0
+    for k in STATS:
+        STATS[k] = 0
+
+
+def note(stat: str, event: str, **attrs) -> None:
+    """One mesh event: counted in ``STATS[stat]`` and emitted as a trace
+    event (``tracing.span_totals()``, the ring, the profiler's trace)."""
+    from ..observability.tracing import trace_event
+
+    STATS[stat] += 1
+    trace_event(event, **attrs)
 
 
 # ---------------------------------------------------------------------------
@@ -225,7 +237,6 @@ def assemble_over_mesh(producer, schema: Schema, mesh
         slot_bigs[idx] = (remapped[0] if len(remapped) == 1
                           else concat_batches(schema, remapped))
 
-    STATS["slot_assemblies"] += 1
     if row_split:
         # every process reads the whole (small) producer and slices its
         # local windows — duplicated work, but globally consistent
@@ -408,10 +419,19 @@ def stacked_input(producer, schema: Schema, mesh) -> Tuple[ColumnBatch, int]:
     """The mesh-fused operator input contract: ``producer``'s rows as a
     stacked [n_dev, cap] ColumnBatch sharded over the mesh, + cap.
     Chains HBM-resident when the producer is itself mesh-fused; never
-    round-trips row data through host either way."""
-    chained = _try_chain(producer, mesh)
-    if chained is not None:
-        STATS["chained_stages"] += 1
-        chained = _maybe_compact_stacked(chained, mesh)
-        return chained, int(chained.selection.shape[1])
-    return assemble_over_mesh(producer, schema, mesh)
+    round-trips row data through host either way. One ``mesh.assemble``
+    span an input, with its per-device ``capacity``."""
+    from ..observability.tracing import trace_span
+
+    with trace_span("mesh.assemble", n_dev=int(mesh.devices.size)) as span:
+        chained = _try_chain(producer, mesh)
+        if chained is not None:
+            STATS["chained_stages"] += 1
+            chained = _maybe_compact_stacked(chained, mesh)
+            stacked, cap = chained, int(chained.selection.shape[1])
+        else:
+            STATS["slot_assemblies"] += 1
+            stacked, cap = assemble_over_mesh(producer, schema, mesh)
+        span.attrs.update(how="assembled" if chained is None else "chained",
+                          capacity=cap)
+    return stacked, cap

@@ -181,27 +181,40 @@ def test_compact_perm_at_1m_rows_has_no_scatter(one_chip, no_disk_cache,
     assert (" while(" in text) == (size > 65536)
 
 
-def test_mesh_all_to_all_rows_on_four_chips(topo, no_disk_cache):
+@pytest.mark.parametrize("rows,slots", [(4096, 1024), (1 << 20, 1 << 18)],
+                         ids=["4k_rows", "1m_rows"])
+def test_mesh_all_to_all_rows_on_four_chips(topo, no_disk_cache, rows,
+                                            slots):
     """The ICI shuffle as ONE program across the four described chips:
-    the compiler must place an all-to-all, not gather to one device."""
+    the compiler must place an all-to-all, not gather to one device; and
+    the pack that fills the send buffers holds no ``sort`` and no
+    ``scatter`` (it searches the running count a destination,
+    ``kernels/search.py`` ``first_live``, and gathers): at 2**20 rows a
+    device and slots of a quarter, an int64 key, an int64 and an int32
+    payload and two validity planes, which travel as one word."""
     from jax import shard_map
 
-    n_dev, cap = 4, 1024
+    n_dev = 4
     mesh = Mesh(np.asarray(topo.devices[:n_dev]), ("data",))
     sharded = NamedSharding(mesh, P("data"))
 
-    @functools.partial(shard_map, mesh=mesh, in_specs=(P("data"),) * 4,
-                       out_specs=(P("data"), P("data"), P("data")),
-                       check_vma=False)
-    def exchange(keys, vals, live, dest):
+    @functools.partial(shard_map, mesh=mesh, in_specs=(P("data"),) * 6,
+                       out_specs=(P("data"),) * 5, check_vma=False)
+    def exchange(keys, vals, dates, valid_a, valid_b, live):
+        dest = mesh_shuffle.destination_ids(keys, live, n_dev)
         cols, out_live, counts = mesh_shuffle.all_to_all_rows(
-            [keys, vals], live, dest, "data", n_dev, dest_capacity=cap)
-        return cols[0], out_live, counts
+            [keys, vals, dates, valid_a, valid_b], live, dest, "data",
+            n_dev, dest_capacity=slots)
+        return cols[0], cols[2], cols[4], out_live, counts
 
-    s = functools.partial(_shape, sharded, n_dev * cap)
-    compiled = _compile(exchange, s(jnp.int64), s(jnp.int64), s(jnp.bool_),
-                        s(jnp.int32))
-    assert "all-to-all" in compiled.as_text()
+    s = functools.partial(_shape, sharded, n_dev * rows)
+    compiled = _compile(exchange, s(jnp.int64), s(jnp.int64), s(jnp.int32),
+                        s(jnp.bool_), s(jnp.bool_), s(jnp.bool_))
+    text = compiled.as_text()
+    assert "all-to-all" in text
+    assert " sort(" not in text and " scatter(" not in text
+    # key, value, date, one word of validity bits, and the counts
+    assert text.count(" all-to-all(") <= 6
 
 
 def test_join_probe_after_compaction_at_16384_rows(one_chip, no_disk_cache,
