@@ -88,8 +88,12 @@ UNMAPPED_ALLOWLIST = {
     "join.probe_fused",
     # marker event (dur=0), one a probe launch that searches the sorted
     # build keys in steps of 128 (physical/join.py _note_search); the
-    # time is the device's, under jit_join_expand / jit_join_unique
+    # time is the device's, under jit_join_ranges / jit_join_unique
     "join.search",
+    # marker event (dur=0), one a second-half launch of an expanding
+    # probe, after its count was read (physical/join.py _expand_run);
+    # the time is the device's, under jit_join_expand
+    "join.expand",
     # the mesh exchange (physical/mesh_input.py, mesh_agg.py,
     # distributed/scheduler.py _fuse_mesh_stages): marker events (dur=0)
     # for a side exchanged over the mesh and for a join or aggregate the

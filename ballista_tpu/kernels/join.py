@@ -13,9 +13,12 @@ keys are compared as two int32 planes, split inside the probe program):
 - ``probe_unique`` handles the FK->PK joins that dominate TPC-H (build keys
   unique): one gather from the dense table where the build has one, else
   one stepped search + one gather; no row expansion;
-- ``probe_expand`` (general many-to-many) computes per-probe match counts
-  (two searches of the build keys) and materializes matches up to a static
-  output capacity (one search of the running match count a slot).
+- ``probe_ranges`` + ``expand_slots`` (general many-to-many): per probe
+  row the match counts (two searches of the build keys), then per output
+  slot the match itself (one search of the running match count). Two
+  functions so that ``JoinExec`` can read the count between them and
+  size the second by it; ``probe_expand`` is the two in one program at a
+  static output capacity.
 
 Keys are single int64 columns (dict codes / ints / dates cast to int64).
 """
@@ -136,41 +139,78 @@ def probe_counts(table: BuildTable, probe_keys: jax.Array) -> jax.Array:
             - count_below(levels, probe_keys, "left"))
 
 
-def probe_expand(
-    table: BuildTable,
-    probe_keys: jax.Array,
-    probe_live: jax.Array,
-    out_capacity: int,
-) -> Tuple[jax.Array, jax.Array, jax.Array, jax.Array]:
-    """General inner join with row expansion to a static output capacity.
+def probe_ranges(
+    table: BuildTable, probe_keys: jax.Array, probe_live: jax.Array
+) -> Tuple[jax.Array, jax.Array, jax.Array]:
+    """First half of the expanding probe, everything that costs a PROBE
+    ROW: where each probe key's matches start among the sorted build
+    keys, and the running count of matches.
 
-    Returns (probe_row_idx [C], build_row_idx [C], out_live [C],
-    total_matches scalar). If total_matches > out_capacity the result is
-    truncated; callers detect via the returned total and re-run with a
-    bigger capacity (host-side fallback policy).
+    Returns (lo int32 [Np], ends int32 [Np], total_matches scalar): probe
+    row p matches sorted build slots ``lo[p] .. lo[p] + count[p]``, and
+    ``ends`` is the inclusive running sum of the counts (dead probe rows
+    count 0), so the matches of row p fill output slots
+    ``ends[p-1] .. ends[p]``. Nothing here has the output's size: a
+    caller that can read ``total`` on the host sizes ``expand_slots`` by
+    it.
     """
     keyed = jnp.where(probe_live, probe_keys, INT64_SENTINEL - 1)
     levels = build_levels(table.sorted_keys)
     lo = count_below(levels, keyed, "left")
     counts = count_below(levels, keyed, "right") - lo
-    counts = jnp.where(probe_live, counts, 0)
-    ends = jnp.cumsum(counts)
-    offsets = ends - counts  # exclusive prefix sum
-    total = jnp.sum(counts)
+    ends = jnp.cumsum(jnp.where(probe_live, counts, 0))
+    return lo, ends, ends[-1]
 
+
+def expand_slots(
+    table: BuildTable,
+    lo: jax.Array,
+    ends: jax.Array,
+    total: jax.Array,
+    out_capacity: int,
+) -> Tuple[jax.Array, jax.Array, jax.Array]:
+    """Second half, everything that costs an OUTPUT SLOT: the matches of
+    ``probe_ranges`` written as a prefix of a static output capacity.
+
+    Returns (probe_row_idx [C], build_row_idx [C], out_live [C]); the
+    pairs go probe row by probe row, a row's matches in sorted build
+    order. Matches past the capacity are cut off.
+    """
     C = out_capacity
     out_slot = jnp.arange(C, dtype=jnp.int32)
     # For each output slot, find its probe row: the row whose [offset,
     # offset+count) window contains the slot, so the first whose running
     # count of matches passes the slot.
     probe_of_slot = count_below(build_levels(ends), out_slot, "right")
-    np_rows = probe_keys.shape[0]
-    probe_of_slot = jnp.minimum(probe_of_slot, np_rows - 1)
-    within = out_slot - offsets[probe_of_slot]
-    build_slot = lo[probe_of_slot] + within
+    probe_of_slot = jnp.minimum(probe_of_slot, ends.shape[0] - 1)
+    # a row's window starts where the row before it ended
+    offset = jnp.where(probe_of_slot > 0,
+                       ends[jnp.maximum(probe_of_slot - 1, 0)], 0)
+    build_slot = lo[probe_of_slot] + (out_slot - offset)
     nb = table.sorted_keys.shape[0]
     build_slot = jnp.minimum(build_slot, nb - 1)
     out_live = out_slot < jnp.minimum(total, C)
     build_rows = jnp.where(out_live, table.order[build_slot], 0)
     probe_rows = jnp.where(out_live, probe_of_slot, 0)
+    return probe_rows, build_rows, out_live
+
+
+def probe_expand(
+    table: BuildTable,
+    probe_keys: jax.Array,
+    probe_live: jax.Array,
+    out_capacity: int,
+) -> Tuple[jax.Array, jax.Array, jax.Array, jax.Array]:
+    """General inner join with row expansion to a static output
+    capacity: ``probe_ranges`` and ``expand_slots`` in ONE program, for
+    a caller that cannot read the count in between (the mesh join's SPMD
+    program, ``physical/mesh_agg.py``).
+
+    Returns (probe_row_idx [C], build_row_idx [C], out_live [C],
+    total_matches scalar). If total_matches > out_capacity the result is
+    truncated, and the returned total says so.
+    """
+    lo, ends, total = probe_ranges(table, probe_keys, probe_live)
+    probe_rows, build_rows, out_live = expand_slots(
+        table, lo, ends, total, out_capacity)
     return probe_rows, build_rows, out_live, total

@@ -56,7 +56,6 @@ OPERATOR_METRICS = {
                                       "prefetch queue"),
     # operator-specific
     "compact_count": ("counter", "adaptive post-filter compactions taken"),
-    "expand_reruns": ("counter", "expanding-probe capacity re-runs"),
     "bytes_read": ("counter", "shuffle reader input bytes"),
     "local_reads": ("counter", "shuffle partitions read from local disk"),
     "remote_fetches": ("counter", "shuffle partitions fetched over the "

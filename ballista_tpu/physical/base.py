@@ -166,12 +166,11 @@ class PhysicalPlan:
     # power-of-two rungs, bounding downstream per-capacity jit compiles
     # to ~one extra.
     #
-    # BENIGN RACE: _compact_misses/_compact_floor (and JoinExec's
-    # _expand_cap_floor) are unsynchronized instance state; executor
-    # worker threads running partitions of one operator concurrently can
-    # interleave updates. Outcomes stay correct — these only steer
-    # heuristics — but learned values can thrash; the same policy covers
-    # the MetricsSet counters.
+    # BENIGN RACE: _compact_misses/_compact_floor are unsynchronized
+    # instance state; executor worker threads running partitions of one
+    # operator concurrently can interleave updates. Outcomes stay
+    # correct — these only steer heuristics — but learned values can
+    # thrash; the same policy covers the MetricsSet counters.
 
     def still_compacting(self) -> bool:
         """False once two batches in a row declined to compact: from
@@ -529,13 +528,15 @@ def maybe_compact(batch: ColumnBatch, shrink_factor: int = 4,
     Callers: the adaptive rule (``PhysicalPlan.compact_learning``: a
     pipeline chain's output, and a join's probe batch BEFORE its probe
     when the fused probe chain holds a filter), and ``JoinExec`` after a
-    probe of a batch that was not compacted before it.
+    probe-aligned (unique, semi, anti) probe of a batch that was not
+    compacted before it. The expanding probe needs none: it sizes its
+    output by the count it reads (``JoinExec._expand_run``).
 
-    Pass ``known_rows`` when the live count is already on host (e.g. the
-    join expand loop just synced its overflow check) — then this never
-    blocks. Without it, the live-count sync is only paid while measured
-    sync cost is low; on a remote accelerator the first call measures
-    the round-trip and all later speculative syncs are skipped."""
+    Pass ``known_rows`` when the live count is already on host — then
+    this never blocks. Without it, the live-count sync is only paid
+    while measured sync cost is low; on a remote accelerator the first
+    call measures the round-trip and all later speculative syncs are
+    skipped."""
     if known_rows is not None:
         n = known_rows
     else:

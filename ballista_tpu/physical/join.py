@@ -10,8 +10,8 @@ The build (left) side is materialized once and sorted (kernels.join);
 probe-side batches stream through a jitted probe that appends gathered
 build columns (a batch whose fused probe-side filter kept under a quarter
 of it is compacted first, ``_probe_inputs``). FK->PK joins (unique build keys) take the no-expansion fast
-path; duplicate build keys fall back to the expanding probe with adaptive
-output capacity.
+path; duplicate build keys fall back to the expanding probe, which counts
+its matches first and expands them at the capacity the count names.
 
 Join types: inner, left (preserves PROBE side — the planner picks which
 logical side becomes the probe accordingly), semi, anti, and full (a
@@ -40,10 +40,10 @@ JOIN_TYPES = ("inner", "left", "semi", "anti", "full")
 
 # Deferred-sync window of ``_probe_expand_stream``: match totals of this
 # many probe batches are fetched in one ``device_get``. The window also
-# bounds BYTES pinned on device (probe + expanded output buffers stay
-# live until their totals are fetched), so a wide join with huge batch
-# capacities flushes early instead of multiplying its peak memory by
-# the batch-count window.
+# bounds BYTES pinned on device (a probe batch and its match ranges stay
+# live until its total is fetched), so a join over huge batch capacities
+# flushes early instead of multiplying its peak memory by the
+# batch-count window.
 _SYNC_WINDOW = 8
 _SYNC_WINDOW_BYTES = 1 << 30
 
@@ -726,31 +726,62 @@ class JoinExec(PhysicalPlan):
             ("join.unique", mode) + _chained_key(chained), build)
 
     # general path: expanding probe -----------------------------------------
+    #
+    # Two programs around ONE host read of the match count. Before the
+    # read, what costs a probe ROW (``join.ranges``: the chain, the keys,
+    # two searches of the build keys, the running count); after it, what
+    # costs an output SLOT (``join.expand``: each slot's probe and build
+    # row, one gather an output column), traced at the rung of the count,
+    # so a probe that keeps an eighth of its rows gathers an eighth.
 
-    def _expand_run(self, table, build_batch, pb, mode, key_tables, remaps,
-                    out_cap: int, chained: bool):
-        """One async expanding-probe launch at a fixed output capacity.
-        Returns (out_batch, total_matches_device) WITHOUT syncing."""
+    def _ranges_run(self, table, pb, mode, key_tables, remaps,
+                    chained: bool):
+        """One async ``join.ranges`` launch. Returns (the batch the fused
+        chain left, or ``pb`` itself where none ran inside; lo; ends;
+        total on device) WITHOUT syncing."""
+        runs_chain = bool(self.probe_chain) and not chained
+
         def build():
             tw = self.trace_twin()
 
-            def run(table, bb, pb, key_tables, remaps, _cap=out_cap):
-                if not chained:
+            def run(table, pb, key_tables, remaps):
+                if runs_chain:
                     pb = tw._probe_prologue(pb)
                 pkeys, plive = tw._probe_keys(pb, mode, key_tables,
                                               remaps)
-                prows, brows, olive, total = join_k.probe_expand(
-                    table, pkeys, plive, _cap
-                )
-                out = tw._assemble_expanded(bb, pb, prows, brows, olive)
-                return out, total
+                ranges = join_k.probe_ranges(table, pkeys, plive)
+                # a batch the program did not change is not copied out
+                return (pb if runs_chain else None,) + ranges
 
             return run
 
         fn = self.governed_jit(
-            ("join.expand", mode, out_cap) + _chained_key(chained), build)
+            ("join.ranges", mode) + _chained_key(chained), build)
         _note_search(table, pb)
-        return fn(table, build_batch, pb, key_tables, remaps)
+        kept, lo, ends, total = fn(table, pb, key_tables, remaps)
+        return (pb if kept is None else kept), lo, ends, total
+
+    def _expand_run(self, table, build_batch, kept: ColumnBatch, lo, ends,
+                    total, t: int) -> ColumnBatch:
+        """One ``join.expand`` launch for a probe batch whose ``t``
+        matches are already counted: the packed prefix of ``t`` rows at
+        the bucket ladder's rung for ``t``, never truncated and never
+        re-run."""
+        cap = bucket_capacity(t)
+
+        def build():
+            tw = self.trace_twin()
+
+            def run(table, bb, pb, lo, ends, total):
+                prows, brows, olive = join_k.expand_slots(
+                    table, lo, ends, total, cap)
+                return tw._assemble_expanded(bb, pb, prows, brows, olive)
+
+            return run
+
+        fn = self.governed_jit(("join.expand", cap), build)
+        trace_event("join.expand", rows=t, probes=kept.capacity, to=cap)
+        return fn(table, build_batch, kept, lo, ends, total)
 
     def _unmatched_batch(self, table, build_batch, pb, mode, key_tables,
                          remaps, chained: bool) -> ColumnBatch:
@@ -783,29 +814,24 @@ class JoinExec(PhysicalPlan):
     def _probe_expand_stream(self, table, build_batch, inputs,
                              mode: str, key_tables) -> Iterator[ColumnBatch]:
         """Expanding probe over a stream of ``_probe_inputs`` triples
-        (a batch its chain compacted arrives at its survivors' capacity,
-        so its output capacity starts there too) with DEFERRED overflow
-        syncs: launches are asynchronous and match totals for a whole
-        window are fetched in ONE ``device_get`` (every blocking sync
-        drains the device queue — q5's per-batch check was the dominant
-        on-chip cost in the one chip record). Only overflowed
-        batches re-run; a learned capacity floor makes later windows
-        overflow-free."""
+        with DEFERRED count reads: ``join.ranges`` launches are
+        asynchronous and the match totals of a whole window are fetched
+        in ONE ``device_get`` (every blocking sync drains the device
+        queue — q5's per-batch check was the dominant on-chip cost in
+        the one chip record). Each batch is then expanded at the rung of
+        its own count and yielded as it is: already the packed prefix, so
+        nothing compacts it afterwards."""
         if self.how not in ("inner", "left", "full"):
             raise NotImplementedError_(
                 f"{self.how} join with duplicate build keys"
             )
-        from .base import maybe_compact
-
-        # fixed-size-list columns hold ``length`` elements per row, so
-        # itemsize alone would under-count them by length x
-        row_bytes = sum(
+        # what a pending batch pins: the batch its chain left and two
+        # int32 a probe row. Fixed-size-list columns hold ``length``
+        # elements per row, so itemsize alone would under-count them
+        row_bytes = 8 + sum(
             f.dtype.device_dtype().itemsize * (getattr(f.dtype, "length", 0)
                                                or 1)
-            for f in self.output_schema().fields
-        ) + sum(f.dtype.device_dtype().itemsize
-                * (getattr(f.dtype, "length", 0) or 1)
-                for f in self._probe_out_schema().fields)
+            for f in self._probe_out_schema().fields)
         pend: list = []
         pend_bytes = 0
 
@@ -818,22 +844,11 @@ class JoinExec(PhysicalPlan):
 
             with trace_span("device.block", site="join.expand_totals",
                             n=len(pend)):
-                totals = jax.device_get([p[-1] for p in pend])  # ONE sync
-            for (pb, remaps, chained, out, out_cap, _), total in zip(
-                    pend, totals):
-                t = int(total)
-                while t > out_cap:  # rare: re-run at a ladder capacity
-                    self.metrics().add_counter("expand_reruns")
-                    out_cap = bucket_capacity(t)
-                    out, tot = self._expand_run(
-                        table, build_batch, pb, mode, key_tables, remaps,
-                        out_cap, chained)
-                    t = int(tot)
-                    self._expand_cap_floor = max(
-                        getattr(self, "_expand_cap_floor", 0), out_cap)
-                # the overflow check above already synced the match
-                # count, so compaction never costs an extra round-trip
-                yield maybe_compact(out, known_rows=min(t, out_cap))
+                counts = jax.device_get([p[-1] for p in pend])  # ONE sync
+            for (pb, remaps, chained, kept, lo, ends, total), t in zip(
+                    pend, counts):
+                yield self._expand_run(table, build_batch, kept, lo, ends,
+                                       total, int(t))
                 if self.how in ("left", "full"):
                     yield self._unmatched_batch(table, build_batch, pb,
                                                 mode, key_tables, remaps,
@@ -841,13 +856,9 @@ class JoinExec(PhysicalPlan):
             pend.clear()
 
         for pb, remaps, chained in inputs:
-            out_cap = max(pb.capacity,
-                          getattr(self, "_expand_cap_floor", 0))
-            out, total = self._expand_run(table, build_batch, pb, mode,
-                                          key_tables, remaps, out_cap,
-                                          chained)
-            pend.append((pb, remaps, chained, out, out_cap, total))
-            pend_bytes += (pb.capacity + out_cap) * row_bytes
+            pend.append((pb, remaps, chained) + self._ranges_run(
+                table, pb, mode, key_tables, remaps, chained))
+            pend_bytes += pb.capacity * row_bytes
             if (len(pend) >= _SYNC_WINDOW
                     or pend_bytes >= _SYNC_WINDOW_BYTES):
                 yield from flush()

@@ -142,6 +142,73 @@ def test_join_probe_expand_at_q3_shapes(one_chip, no_disk_cache):
     assert looped and all("slice_sizes={1,128}" in g for g in looped)
 
 
+def test_join_probe_ranges_at_q3_shapes(one_chip, no_disk_cache):
+    """The first half of q3's expanding probe (``join.ranges``): 131,072
+    probe rows over a 2**22-row partition of build keys. Everything in
+    it costs a probe row; its searches walk the levels a row of 128 at a
+    time, and it holds no sort and no scatter."""
+    import re
+    import time
+
+    nb, rows = 1 << 22, 131072
+    scalar = functools.partial(jax.ShapeDtypeStruct, (), sharding=one_chip)
+
+    def ranges(sorted_keys, order, num_live, probe_keys, probe_live):
+        table = join.BuildTable(sorted_keys, order, num_live)
+        return join.probe_ranges(table, probe_keys, probe_live)
+
+    started = time.monotonic()
+    compiled = _compile(
+        ranges, _shape(one_chip, nb, jnp.int64),
+        _shape(one_chip, nb, jnp.int32), scalar(jnp.int32),
+        _shape(one_chip, rows, jnp.int64), _shape(one_chip, rows, jnp.bool_))
+    assert time.monotonic() - started < 60
+    text = compiled.as_text()
+    assert " sort(" not in text and " scatter(" not in text
+    gathers = re.findall(r" gather\(.*", text)
+    assert gathers and all("slice_sizes={1,128}" in g for g in gathers)
+
+
+def test_join_expand_slots_at_q3_shapes(one_chip, no_disk_cache):
+    """The second half (``join.expand``) at the rung of q3's count:
+    16,384 slots out of 131,072 probe rows and a 2**22-row build, with
+    the assembly's gathers as ``JoinExec._assemble_expanded`` makes them
+    (q3's output: three int64 and one int32 column of the probe side,
+    three and three of the build side). Everything in it costs an output
+    slot: no gather produces 131,072 elements, there is no sort and no
+    scatter."""
+    import re
+    import time
+
+    nb, rows, slots = 1 << 22, 131072, 16384
+    scalar = functools.partial(jax.ShapeDtypeStruct, (), sharding=one_chip)
+    probe_cols = [jnp.int64] * 3 + [jnp.int32]
+    build_cols = [jnp.int64] * 3 + [jnp.int32] * 3
+
+    def expand(sorted_keys, order, num_live, lo, ends, total, pcols, bcols):
+        table = join.BuildTable(sorted_keys, order, num_live)
+        prows, brows, olive = join.expand_slots(table, lo, ends, total,
+                                                slots)
+        return ([jnp.take(c, prows) for c in pcols]
+                + [jnp.take(c, brows) for c in bcols], olive)
+
+    started = time.monotonic()
+    compiled = _compile(
+        expand, _shape(one_chip, nb, jnp.int64),
+        _shape(one_chip, nb, jnp.int32), scalar(jnp.int32),
+        _shape(one_chip, rows, jnp.int32), _shape(one_chip, rows, jnp.int32),
+        scalar(jnp.int32),
+        [_shape(one_chip, rows, d) for d in probe_cols],
+        [_shape(one_chip, nb, d) for d in build_cols])
+    assert time.monotonic() - started < 60
+    text = compiled.as_text()
+    assert " sort(" not in text and " scatter(" not in text
+    # "<name> = <type>[<output shape>]{..} gather(<operands>), ..."
+    made = re.findall(r"= (\S+) gather\(", text)
+    assert made and not any(str(rows) in shape for shape in made)
+    assert any(str(slots) in shape for shape in made)
+
+
 def test_sort_based_aggregate_at_first_rung(one_chip, no_disk_cache):
     """Two-key ``grouped_aggregate`` (the multi-operand lax.sort form) at
     1,024 rows only — see the module docstring for why not larger."""
