@@ -105,6 +105,14 @@ UNMAPPED_ALLOWLIST = {
     "mesh.assemble",
     "mesh.fused",
     "mesh.unfused",
+    # every governed launch (compile/governor.py call_with): the host's
+    # time from the call to the return of the jitted function, counted
+    # in tracing.span_totals() under launch:jit_<family> and annotated
+    # in a device trace, never a ring record, so no lane or ledger
+    # window ever holds one; a call that compiled is renamed launch.cold
+    # before it ends (compile.jit is the mapped record of that time)
+    "launch",
+    "launch.cold",
     # cancellation marker event (dur=0): lifecycle, not latency
     "lifecycle.cancel",
     # adaptive re-planning markers: they fire INSIDE windows that are

@@ -26,6 +26,12 @@ the span need not be in the same function, a wrapper's span covers the
 wrapped body). Everything else is a finding: wrap it with a span
 carrying a ``site=`` attribute, or suppress with
 ``# ballista: ignore[sync-span]`` and a reason.
+
+The ``site=`` of every ``device.block`` span must be a string LITERAL:
+it is the span's sub-key in ``tracing.span_totals()``
+(``device.block:join.stats``), and a key made from a value of the data,
+a job or a task would grow the totals without bound. A span without
+one, or with a computed one, is a finding too.
 """
 
 from __future__ import annotations
@@ -52,6 +58,18 @@ SKIP_FILES = frozenset({
 })
 
 
+def _is_block_span(call: ast.AST) -> bool:
+    """``trace_span("device.block", ...)``."""
+    if not isinstance(call, ast.Call) or not call.args:
+        return False
+    fname = (call.func.id if isinstance(call.func, ast.Name)
+             else call.func.attr
+             if isinstance(call.func, ast.Attribute) else "")
+    first = call.args[0]
+    return fname == "trace_span" and isinstance(first, ast.Constant) \
+        and first.value == "device.block"
+
+
 def _span_ranges(sf: SourceFile) -> List[Tuple[int, int]]:
     """(start, end) line ranges of every ``with trace_span("device.block"
     ...)`` body in the file."""
@@ -59,21 +77,14 @@ def _span_ranges(sf: SourceFile) -> List[Tuple[int, int]]:
     for node in ast.walk(sf.tree):
         if not isinstance(node, ast.With):
             continue
-        for item in node.items:
-            call = item.context_expr
-            if not isinstance(call, ast.Call):
-                continue
-            fname = (call.func.id if isinstance(call.func, ast.Name)
-                     else call.func.attr
-                     if isinstance(call.func, ast.Attribute) else "")
-            if fname != "trace_span" or not call.args:
-                continue
-            first = call.args[0]
-            if isinstance(first, ast.Constant) and \
-                    first.value == "device.block":
-                ranges.append((node.lineno, node.end_lineno or node.lineno))
-                break
+        if any(_is_block_span(item.context_expr) for item in node.items):
+            ranges.append((node.lineno, node.end_lineno or node.lineno))
     return ranges
+
+
+def _literal_site(call: ast.Call) -> bool:
+    return any(kw.arg == "site" and isinstance(kw.value, ast.Constant)
+               and isinstance(kw.value.value, str) for kw in call.keywords)
 
 
 def _covered(line: int, ranges: List[Tuple[int, int]]) -> bool:
@@ -156,6 +167,14 @@ class SyncSpanRule(Rule):
                 continue
             np_aliases, jax_aliases = self._aliases(package, sf.rel)
             spans = _span_ranges(sf)
+            for node in ast.walk(sf.tree):
+                if _is_block_span(node) and not _literal_site(node):
+                    findings.append(make_finding(
+                        self.id, sf, node.lineno,
+                        "device.block span whose site= is not a string "
+                        "literal (the site is the span's key in "
+                        "span_totals(): a literal of the code, never a "
+                        "value)"))
             seen: Set[Tuple[int, int]] = set()  # nested defs walk twice
             for fn, _cls in walk_functions(sf):
                 prov = _Provenance(fn, np_aliases, jax_aliases)

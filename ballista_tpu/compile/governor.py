@@ -20,6 +20,17 @@ compile time. The governor replaces them all:
   ``elapsed_compile`` land on the caller's MetricsSet (so EXPLAIN
   ANALYZE shows them), ``BALLISTA_TRACE`` gets a ``compile.jit`` span,
   and :func:`compile_stats` exposes the process-wide totals.
+- **Every launch is a span.** ``call_with``, the one way to a program,
+  opens ``launch`` with ``site=<program name>`` around the jitted call:
+  counted in ``tracing.span_totals()`` under ``launch:jit_<family>`` and
+  an annotation of that name in a profiler trace, never a ring record.
+  Its seconds are the HOST's, from the call to the return of the jitted
+  function (jax's dispatch, output allocation, the enqueue); not the
+  program's run on the device, which is asynchronous, except where the
+  runtime blocks inside the call. A call that compiled or read the
+  persistent cache is tallied under ``launch.cold:jit_<family>``
+  instead, so a window's ``launch:*`` delta is warm dispatch only and
+  ``compile.jit`` stays the record of the rest.
 - **Bounded namespaces.** Mesh-path entries key on pytree structures
   that pin per-query ``Dictionary`` objects; their namespaces carry an
   LRU cap exactly like the bounded dicts they replaced.
@@ -30,9 +41,10 @@ from __future__ import annotations
 import functools
 import re
 import threading
-import time
 from collections import OrderedDict
 from typing import Any, Callable, Dict, Optional
+
+from ..observability.tracing import trace_event, trace_span
 
 __all__ = [
     "MESH_NS_CAP",
@@ -43,8 +55,6 @@ __all__ = [
     "compile_stats",
     "reset_compile_stats",
 ]
-
-_PERF = time.perf_counter
 
 # LRU bound for mesh-path namespaces (mesh.compact / mesh.chain /
 # mesh.agg_spmd / mesh.join_spmd / mesh.replicate / mesh.run_spmd):
@@ -139,12 +149,14 @@ class GovernedFunction:
     the same signature — jax's own trace cache (keyed on treedef/avals)
     handles shape and dictionary variation within the entry."""
 
-    __slots__ = ("key", "fn", "calls", "compiles", "compile_seconds",
-                 "pcache_hits")
+    __slots__ = ("key", "fn", "program", "calls", "compiles",
+                 "compile_seconds", "pcache_hits")
 
     def __init__(self, key: tuple, fn: Callable):
         self.key = key
         self.fn = fn
+        # the launch span's site: what a device trace calls the program
+        self.program = "jit_" + program_name(key)
         self.calls = 0
         self.compiles = 0
         self.compile_seconds = 0.0
@@ -176,32 +188,42 @@ class GovernedFunction:
 
     def call_with(self, metrics, *args, **kwargs):
         """Invoke, attributing any compile this call triggers to
-        ``metrics`` (an observability MetricsSet, or None)."""
+        ``metrics`` (an observability MetricsSet, or None). The call is
+        a ``launch`` span (module docstring): in the totals and the
+        profiler's trace, never in the ring, which a served query would
+        turn over with launches alone."""
         _STATS["governed_calls"] += 1
         self.calls += 1
         self._maybe_trim_traces()
         prev = getattr(_tls, "frame", None)
         frame = _Frame()
         _tls.frame = frame
-        t0 = _PERF()
+        span = trace_span("launch", site=self.program)
+        span.record = False
+        span.__enter__()
         try:
             return self.fn(*args, **kwargs)
         finally:
             _tls.frame = prev
-            if not _monitoring_ok and self.calls == 1:
-                # no monitoring events on this jax: approximate with the
-                # entry's first call (includes that call's execution,
-                # like the old PipelineOp measurement did)
+            # no monitoring events on this jax: approximate with the
+            # entry's first call (includes that call's execution, like
+            # the old PipelineOp measurement did)
+            guessed = not _monitoring_ok and self.calls == 1
+            cold = guessed or frame.compiles or frame.pcache_hits
+            if cold:
+                span.name = "launch.cold"
+            span.__exit__(None, None, None)
+            if guessed:
                 frame.compiles = 1
-                frame.compile_secs = _PERF() - t0
+                frame.compile_secs = span.dur
                 _STATS["backend_compiles"] += 1
                 _STATS["compile_seconds"] += frame.compile_secs
             # a pure disk-cache hit compiles nothing but still traced,
             # lowered and deserialized — record it too, or the warm-disk
             # cold start (the scenario this subsystem optimizes) shows
             # zero compile activity in EXPLAIN ANALYZE
-            if frame.compiles or frame.pcache_hits:
-                self._record(frame, _PERF() - t0, metrics)
+            if cold:
+                self._record(frame, span.dur, metrics)
 
     def _record(self, frame: _Frame, call_secs: float, metrics) -> None:
         self.compiles += frame.compiles
@@ -217,8 +239,6 @@ class GovernedFunction:
             if frame.pcache_hits:
                 metrics.add_counter("persistent_cache_hits",
                                     frame.pcache_hits)
-        from ..observability.tracing import trace_event
-
         trace_event("compile.jit", key=_render_key(self.key),
                     compiles=frame.compiles,
                     compile_seconds=round(frame.compile_secs, 6),

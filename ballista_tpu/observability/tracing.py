@@ -58,10 +58,24 @@ per name for every span and event emitted since the process started,
 kept at emit time under a lock of their own: no ring bounds them, and
 they are kept whether or not the ring or a file is on.
 
+**A span's ``site`` is its sub-key in the totals**: a span or event that
+carries a ``site`` attribute is tallied under its name AND under
+``name:site`` in the same critical section
+(``span_totals()["device.block:join.stats"]``), and while a profiler
+session records its annotation is named ``name:site`` too, so the host
+line of a trace says WHICH read blocked and WHICH program was launched.
+A site is a literal of the code or a governed program's family name
+(``launch:jit_join_ranges``), never a value from data, a job or a task:
+the keys are as many as the code has sites, whatever runs
+(``analysis/passes/sync_span.py`` holds ``device.block`` to a literal).
+
 **Spans kept out of the ring**: a span whose ``record`` attribute is
 set to False before it ends (the poll waits of an executor with no task
 in flight and no report pending) reaches the profiler annotation and
-the totals only; an idle cluster's polls never turn the ring over.
+the totals only; an idle cluster's polls never turn the ring over. Set
+before the block BEGINS (every governed launch, ``compile/governor.py``
+``call_with``) it also takes no span id, so what is emitted inside
+hangs from the enclosing recorded span.
 
 **Process identity**: :func:`set_process_identity` stamps a role
 (``scheduler`` / ``executor``) and short executor id onto every record
@@ -330,32 +344,37 @@ def _emit(record: dict) -> None:
             pass
 
 
-def _tally(name: str, seconds: float) -> None:
+def _tally(name: str, seconds: float, site=None) -> None:
+    keys = (name,) if site is None else (name, f"{name}:{site}")
     with _totals_lock:
-        t = _totals.get(name)
-        if t is None:
-            _totals[name] = [1, seconds]
-        else:
-            t[0] += 1
-            t[1] += seconds
+        for key in keys:
+            t = _totals.get(key)
+            if t is None:
+                _totals[key] = [1, seconds]
+            else:
+                t[0] += 1
+                t[1] += seconds
 
 
 def span_totals() -> dict:
     """``{name: {"count": n, "seconds": s}}`` for every span and event
-    emitted since the process started (events count with 0 seconds).
-    Kept at emit time, so neither the ring's size nor
-    ``BALLISTA_FLIGHT_RECORDER=0`` bounds or blinds them."""
+    emitted since the process started (events count with 0 seconds),
+    and ``name:site`` beside ``name`` for those that carry a ``site``
+    (the sub-keys of a name sum to it). Kept at emit time, so neither
+    the ring's size nor ``BALLISTA_FLIGHT_RECORDER=0`` bounds or blinds
+    them."""
     with _totals_lock:
         return {name: {"count": t[0], "seconds": t[1]}
                 for name, t in _totals.items()}
 
 
-def _annotate(name: str):
-    """An entered profiler annotation of this name while a profiler
-    session records, else None."""
+def _annotate(name: str, site=None):
+    """An entered profiler annotation of this name (``name:site`` for a
+    span that carries a site) while a profiler session records, else
+    None."""
     if not TraceAnnotation.is_enabled():
         return None
-    a = TraceAnnotation(name)
+    a = TraceAnnotation(name if site is None else f"{name}:{site}")
     a.__enter__()
     return a
 
@@ -375,8 +394,9 @@ def _base_record(name: str, attrs: dict) -> dict:
 def trace_event(name: str, **attrs) -> None:
     """Instant event (no duration). Carries the enclosing span's id as
     ``psid`` so it nests in the reconstructed tree."""
-    _tally(name, 0.0)
-    a = _annotate(name)
+    site = attrs.get("site")
+    _tally(name, 0.0, site)
+    a = _annotate(name, site)
     if a is not None:
         a.__exit__(None, None, None)
     if not _recording():
@@ -394,7 +414,10 @@ class trace_span:
     as ``error=<ExcType>`` and re-raised). Each span gets a process-
     local ``sid`` and its enclosing span's ``psid``. After the block
     ``dur`` holds its seconds; ``record = False``, set before the block
-    ends, keeps it out of the ring and the file (module docstring)."""
+    ends, keeps it out of the ring and the file (module docstring).
+    ``name`` is read when the block ends: a caller that learns inside
+    the block what the span was may rename it before then (a governed
+    launch that compiled is tallied as ``launch.cold``)."""
 
     __slots__ = ("name", "attrs", "record", "dur", "_t0", "_sid", "_psid",
                  "_ann")
@@ -406,9 +429,9 @@ class trace_span:
         self.dur = 0.0
 
     def __enter__(self):
-        self._ann = _annotate(self.name)
+        self._ann = _annotate(self.name, self.attrs.get("site"))
         self._sid = None
-        if _recording():
+        if self.record and _recording():
             st = _span_stack()
             self._psid = st[-1] if st else None
             self._sid = next(_span_ids)
@@ -420,7 +443,7 @@ class trace_span:
         self.dur = time.time() - self._t0
         if self._ann is not None:
             self._ann.__exit__(None, None, None)
-        _tally(self.name, self.dur)
+        _tally(self.name, self.dur, self.attrs.get("site"))
         if self._sid is not None:
             st = _span_stack()
             if st and st[-1] == self._sid:
