@@ -94,6 +94,12 @@ UNMAPPED_ALLOWLIST = {
     # probe, after its count was read (physical/join.py _expand_run);
     # the time is the device's, under jit_join_expand
     "join.expand",
+    # marker event (dur=0), one a partition an aggregate executed: how
+    # its input batches reached the program (physical/aggregate.py
+    # _execute_over: single | in_program | host_concat); the time is
+    # the device's, under jit_agg_grouped / jit_agg_mixed /
+    # jit_agg_scalar
+    "agg.inputs",
     # the mesh exchange (physical/mesh_input.py, mesh_agg.py,
     # distributed/scheduler.py _fuse_mesh_stages): marker events (dur=0)
     # for a side exchanged over the mesh and for a join or aggregate the

@@ -1,21 +1,30 @@
 """Buffer-donation eligibility tracking for fused execution.
 
 Steady-state execution moves every batch through exactly one governed
-XLA program (the fused pipeline chain, or the aggregation program fed
-by ``concat_batches``). XLA can reuse a donated input buffer for the
-output allocation (``donate_argnums``), turning the copy-in/copy-out
-round trip into an in-place update — but ONLY when the engine can
-prove the input has exactly one consumer and nothing else will ever
-read it again. This module is that proof:
+XLA program (the fused pipeline chain, or an aggregation program). XLA
+can reuse a donated input buffer for the output allocation
+(``donate_argnums``), turning the copy-in/copy-out round trip into an
+in-place update — but ONLY when the engine can prove the input has
+exactly one consumer and nothing else will ever read it again. This
+module is that proof:
 
 - A :class:`~ballista_tpu.columnar.ColumnBatch` carries a
   ``_transient`` flag, ``False`` by default. Only the sites that
   CREATE a single-owner batch mark it: scan emission when the batch is
   *not* being pinned by the device table cache, ``concat_batches`` for
-  ``len > 1`` (fresh ``jnp.concatenate`` output), and the fused
-  pipeline's per-batch output. Cached / pinned / materialized batches
-  are never marked, so they are never donation-eligible by
-  construction.
+  ``len > 1`` (fresh ``jnp.concatenate`` output: a join's build side, a
+  sort, a repartition, an aggregate that sorts or whose batches carry
+  different dictionaries), and the fused pipeline's and the aggregates'
+  per-batch output. Cached / pinned / materialized batches are never
+  marked, so they are never donation-eligible by construction.
+- An aggregate donates ONE batch that arrives alone and transient
+  (``PhysicalPlan.governed_call``). A partition that arrives as several
+  batches sharing their dictionaries is handed to the program as a
+  tuple and put together inside its trace
+  (``HashAggregateExec._partition_input``, ``how=in_program``): no
+  concatenated intermediate exists on the host's side to donate, the
+  pieces may be pinned, and XLA could not alias a concatenation onto
+  its inputs anyway, so that call donates nothing.
 - :func:`consume_transient` claims the flag exactly once. A call site
   that donates MUST consume first — a second alias of the same batch
   then sees ``False`` and takes the copying path instead of touching
@@ -23,8 +32,8 @@ read it again. This module is that proof:
 
 The ``num_rows`` scalar is NEVER donated even on transient batches:
 ``MetricsSet.record_output_batch`` holds it in ``_pending_rows`` long
-after the batch body is consumed (see ``governed_donating`` in
-``physical/base.py`` for the split-call wiring).
+after the batch body is consumed (see ``PhysicalPlan.governed_call``
+in ``physical/base.py`` for the split-call wiring).
 
 ``BALLISTA_DONATION=off`` disables the whole tier; marked flags are
 simply never consumed.

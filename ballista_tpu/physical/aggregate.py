@@ -38,7 +38,9 @@ from ..kernels.aggregate import (
 # this use the sort-free dense path
 DENSE_GROUP_LIMIT = 256
 from ..kernels.expr_eval import Evaluator
-from .base import PhysicalPlan, Partitioning, concat_batches
+from ..observability.tracing import trace_event
+from .base import (PhysicalPlan, Partitioning, concat_batches,
+                   gather_batches, gathered_shape, shares_dictionaries)
 
 DEFAULT_GROUP_CAPACITY = 1 << 12
 
@@ -201,20 +203,59 @@ class HashAggregateExec(PhysicalPlan):
         program). Traced."""
         return batch
 
-    def execute(self, partition: int) -> Iterator[ColumnBatch]:
+    def _program_input(self, inp) -> ColumnBatch:
+        """First line of every traced aggregation program: the
+        partition's batches laid end to end when it arrived as several
+        (``_partition_input``), then the prologue. Traced: the copy, if
+        XLA keeps one, is inside the program."""
+        if not isinstance(inp, ColumnBatch):
+            inp = gather_batches(inp)
+        return self._device_prologue(inp)
+
+    def _partition_input(self, schema: Schema, batches: List[ColumnBatch]):
+        """``(inp, how)``: what the aggregation programs are handed for
+        one partition, steered by what the batches show:
+
+        - ``single``: one batch, as it is (donated when transient).
+        - ``in_program``: several batches whose columns share their
+          dictionary instances (a scan, from the table cache or a file),
+          as a TUPLE: the program puts them together itself
+          (``_program_input``), so no eager jax operation runs between
+          the scan's last batch and the launch. jax's trace cache keys
+          on the tuple's capacities: k rungs and a tail a scan. Never
+          donated: the pieces may be pinned by the table cache.
+        - ``host_concat``: dictionaries differ (shuffle partitions from
+          independent producers): ``concat_batches`` unifies them on
+          the host, and its fresh buffer is donated. The sort path
+          (``_exec_grouped``) falls back to it too."""
+        if len(batches) == 1:
+            return batches[0], "single"
+        if shares_dictionaries(batches):
+            return tuple(batches), "in_program"
+        return concat_batches(schema, batches), "host_concat"
+
+    def _execute_over(self, schema: Schema, batches: List[ColumnBatch]
+                      ) -> Iterator[ColumnBatch]:
+        """One partition's batches through the aggregation programs, and
+        one ``agg.inputs`` event that says ``how`` they reached them."""
         from ..cache.donation import mark_transient
 
-        batches = list(self.child.execute(partition))
         if not batches:
             return
-        batch = concat_batches(self._in_schema, batches)
+        inp, how = self._partition_input(schema, batches)
         if not self.group_exprs:
-            out = self._exec_scalar(batch)
+            out = self._exec_scalar(inp)
         else:
-            out = self._exec_grouped(batch)
+            out, how = self._exec_grouped(inp, how)
+        trace_event("agg.inputs", site=how, how=how, batches=len(batches),
+                    capacity=sum(b.capacity for b in batches))
         # fresh program output, one downstream consumer: donatable
         mark_transient(out)
         yield out
+
+    def execute(self, partition: int) -> Iterator[ColumnBatch]:
+        yield from self._execute_over(
+            self._in_schema, list(self.child.execute(partition)))
 
     # grouped ---------------------------------------------------------------
 
@@ -375,7 +416,7 @@ class HashAggregateExec(PhysicalPlan):
         self._mixed_cache = (fp, layout)  # atomic pair publication
         return layout
 
-    def _mixed_stats(self, batch: ColumnBatch, layout):
+    def _mixed_stats(self, inp, layout):
         """(per-int-key (min, max) list, nlive): one jitted program,
         scalars only across the link."""
 
@@ -383,7 +424,7 @@ class HashAggregateExec(PhysicalPlan):
             tw = self.trace_twin()
 
             def stats(b):
-                b = tw._device_prologue(b)
+                b = tw._program_input(b)
                 kes, _ = tw._inputs_and_keys(b)
                 maxi = jnp.iinfo(jnp.int64).max
                 mm = []
@@ -407,24 +448,35 @@ class HashAggregateExec(PhysicalPlan):
         # launch OUTSIDE the span: a cold call compiles synchronously
         # and the governor already attributes that to the compile lane —
         # only the blocking fetch is device-blocked time
-        res = fn(batch)
+        res = fn(inp)
         with trace_span("device.block", site="agg.mstats"):
             mm, nlive = jax.device_get(res)
         return [(int(lo), int(hi)) for lo, hi in mm], int(nlive)
 
-    def _exec_grouped(self, batch: ColumnBatch) -> ColumnBatch:
+    def _exec_grouped(self, inp, how: str):
+        """``(out, how)``. ``inp`` is one batch or a tuple of them
+        (``_partition_input``); the host-side choice of a path reads the
+        shape of what the program will see, the dense and the ranged
+        programs take ``inp`` itself. The SORT path concatenates a tuple
+        on the host after all and says so in ``how``: its program holds
+        a ``lax.sort``, which takes the chip's compiler 23 s at 16,384
+        rows and minutes at a million, once a SHAPE, and the sums of
+        rungs are a far smaller family than their tuples; beside a sort
+        the eager copy is noise."""
+        batch = (inp if isinstance(inp, ColumnBatch)
+                 else gathered_shape(inp))
         cap = self.group_capacity
         bound = self._static_group_bound(batch)
         if bound is not None and bound <= min(DENSE_GROUP_LIMIT, cap):
             # one call, no overflow retry: safe to donate the batch
             out, _ng = self.governed_call(("agg.grouped", cap),
-                                          self._grouped_build(cap), batch)
-            return out  # dense path, can't overflow: no sync needed
+                                          self._grouped_build(cap), inp)
+            return out, how  # dense path, can't overflow: no sync needed
         # rejected once (hash-like sparse ids / huge products) -> rejected
         # for the operator's lifetime: don't pay the stats round-trip again
         layout = None if self._ranged_rejected else self._mixed_layout(batch)
         if layout is not None:
-            mm, nlive = self._mixed_stats(batch, layout)
+            mm, nlive = self._mixed_stats(inp, layout)
             if any(lo > hi for lo, hi in mm):
                 pass  # no live rows: sort path handles the empty batch
             else:
@@ -461,21 +513,23 @@ class HashAggregateExec(PhysicalPlan):
                     out, _ng = self.governed_call(
                         ("agg.mixed", tuple(spans), tuple(layout)),
                         self._mixed_build(tuple(spans), layout),
-                        batch, jnp.asarray(bases, jnp.int64))
-                    return out  # gid < G by construction: no overflow sync
+                        inp, jnp.asarray(bases, jnp.int64))
+                    return out, how  # gid < G by construction: no sync
                 self._ranged_rejected = True
+        if not isinstance(inp, ColumnBatch):
+            inp, how = concat_batches(batch.schema, list(inp)), "host_concat"
         # overflow-retry loop re-reads the SAME batch after an
         # undersized attempt — never donate here
         while True:
             fn = self._get_grouped_fn(cap, batch.capacity)
-            out, num_groups = fn(batch)
+            out, num_groups = fn(inp)
             ng = int(num_groups)
             if ng <= cap:
                 # persist the learned capacity: the operator instance is
                 # reused across partitions AND collects (plan cache), so
                 # later runs skip the undersized attempt + retry sync
                 self.group_capacity = max(self.group_capacity, cap)
-                return out
+                return out, how
             cap = round_capacity(ng)
 
     def _inputs_and_keys(self, batch: ColumnBatch):
@@ -522,8 +576,8 @@ class HashAggregateExec(PhysicalPlan):
         def build():
             tw = self.trace_twin()  # don't pin the input subtree
 
-            def run(batch: ColumnBatch):
-                batch = tw._device_prologue(batch)
+            def run(inp):
+                batch = tw._program_input(inp)
                 key_evals, aggs = tw._inputs_and_keys(batch)
                 res = tw._run_grouping(batch, key_evals, aggs, cap)
                 return tw._assemble(batch, key_evals, res, cap), \
@@ -560,8 +614,8 @@ class HashAggregateExec(PhysicalPlan):
             # below the exact strides product
             G = round_capacity(g_total)
 
-            def run(batch: ColumnBatch, bases):
-                batch = tw._device_prologue(batch)
+            def run(inp, bases):
+                batch = tw._program_input(inp)
                 key_evals, aggs = tw._inputs_and_keys(batch)
                 gid = jnp.zeros((batch.capacity,), jnp.int64)
                 bi = 0
@@ -622,8 +676,8 @@ class HashAggregateExec(PhysicalPlan):
         def build():
             tw = self.trace_twin()
 
-            def run(b: ColumnBatch):
-                b = tw._device_prologue(b)
+            def run(inp):
+                b = tw._program_input(inp)
                 if tw.mode == "partial":
                     aggs = tw._agg_inputs_partial(b)
                 else:
@@ -637,10 +691,10 @@ class HashAggregateExec(PhysicalPlan):
     def _get_scalar_fn(self):
         return self.governed_jit(("agg.scalar",), self._scalar_build())
 
-    def _exec_scalar(self, batch: ColumnBatch) -> ColumnBatch:
-        # single call, batch never touched again: donate when transient
+    def _exec_scalar(self, inp) -> ColumnBatch:
+        # single call, input never touched again: donate when transient
         vals, valids = self.governed_call(("agg.scalar",),
-                                          self._scalar_build(), batch)
+                                          self._scalar_build(), inp)
 
         cap = 8
         sel = np.zeros(cap, dtype=bool)

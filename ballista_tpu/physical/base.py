@@ -123,12 +123,13 @@ class PhysicalPlan:
         metrics = self.metrics() if metrics_enabled() else None
         return governed(key, build, metrics=metrics, **kw)
 
-    def governed_call(self, subkey: tuple, build, batch: ColumnBatch,
-                      *extra):
+    def governed_call(self, subkey: tuple, build, batch, *extra):
         """Run the governed program under ``subkey`` on ``batch``,
         donating the batch's device buffers when it is transient
         (single-consumer intermediate, cache/donation.py) and donation
-        is enabled. The donating variant is a SEPARATE governed entry
+        is enabled. A TUPLE of batches (an aggregate's partition handed
+        over as it arrived) is never transient and donates nothing.
+        The donating variant is a SEPARATE governed entry
         (``<namespace>.don``) because its call convention splits the
         batch: the treedef rides as a static argument,
         column/validity/selection leaves are the donated payload, and
@@ -418,13 +419,72 @@ class PipelineOp(PhysicalPlan):
 # ---------------------------------------------------------------------------
 
 
+def shares_dictionaries(batches: Sequence[ColumnBatch]) -> bool:
+    """True when every column carries ONE dictionary instance (or none)
+    across ``batches``: the pieces of one scan, from the table cache or
+    from a file. Shuffle partitions from independent producers do not."""
+    first = batches[0].columns
+    return all(c.dictionary is f.dictionary
+               for b in batches[1:] for c, f in zip(b.columns, first))
+
+
+def _columns_of(batches: Sequence[ColumnBatch]):
+    """Per column of ``batches``: its pieces, whether the gathered column
+    has a validity (any piece has one), and its dictionary. The one place
+    that decides the gathered batch's pytree structure."""
+    for i in range(len(batches[0].columns)):
+        pieces = [b.columns[i] for b in batches]
+        yield (pieces, any(c.validity is not None for c in pieces),
+               next((c.dictionary for c in pieces
+                     if c.dictionary is not None), None))
+
+
+def gather_batches(batches: Sequence[ColumnBatch]) -> ColumnBatch:
+    """One batch of the SUMMED capacity from pieces that share their
+    dictionaries (``shares_dictionaries``): values, validity (all-true
+    where a piece has none and another has one) and selection laid end
+    to end in order, the live counts added. Pure jnp, so it runs eagerly
+    for ``concat_batches`` and INSIDE a governed program's trace for an
+    aggregate handed a partition's batches as they are
+    (``HashAggregateExec._partition_input``), where the copy is XLA's to
+    place and nothing is launched between the scan and the program."""
+    cols = [
+        Column(jnp.concatenate([c.values for c in pieces]), pieces[0].dtype,
+               jnp.concatenate([c.valid_mask() for c in pieces])
+               if has_validity else None, dict_)
+        for pieces, has_validity, dict_ in _columns_of(batches)]
+    selection = jnp.concatenate([b.selection for b in batches])
+    num_rows = sum([b.num_rows for b in batches])
+    return ColumnBatch(batches[0].schema, cols, selection, num_rows)
+
+
+def gathered_shape(batches: Sequence[ColumnBatch]) -> ColumnBatch:
+    """What ``gather_batches`` would return, as shapes only (dictionaries
+    and validity presence ride the batch as they do the real one): for
+    the host-side choice of an aggregation path, which reads capacity,
+    dictionaries and which columns have a validity. No trace, no
+    launch."""
+    cap = sum(b.capacity for b in batches)
+
+    def shaped(x):
+        return jax.ShapeDtypeStruct((cap,) + tuple(x.shape[1:]), x.dtype)
+
+    live = shaped(batches[0].selection)
+    cols = [Column(shaped(pieces[0].values), pieces[0].dtype,
+                   live if has_validity else None, dict_)
+            for pieces, has_validity, dict_ in _columns_of(batches)]
+    return ColumnBatch(batches[0].schema, cols, live,
+                       jax.ShapeDtypeStruct((), jnp.int32))
+
+
 def concat_batches(schema: Schema, batches: List[ColumnBatch]) -> ColumnBatch:
     """Concatenate batches (device) into one larger-capacity batch.
 
     utf8 columns whose batches carry DIFFERENT dictionaries (e.g. shuffle
     partitions from independent producers) are unified: a sorted union
     dictionary is built host-side and each batch's codes are remapped.
-    Host-level only — never call inside a jit trace.
+    Host-level only — never call inside a jit trace (``gather_batches``
+    is the part of it that may be).
 
     Output capacity is the exact SUM of the inputs, deliberately NOT
     padded up to a bucket-ladder rung: inputs are already ladder-sized,
@@ -440,50 +500,9 @@ def concat_batches(schema: Schema, batches: List[ColumnBatch]) -> ColumnBatch:
         raise ExecutionError("concat of zero batches")
     if len(batches) == 1:
         return batches[0]
-    cols: List[Column] = []
-    for i, f in enumerate(schema.fields):
-        values_list = [b.columns[i].values for b in batches]
-        dicts = [b.columns[i].dictionary for b in batches]
-        dict_ = next((d for d in dicts if d is not None), None)
-        if dict_ is not None and any(
-            d is not None and d is not dict_ for d in dicts
-        ):
-            # unify through the dictionary registry: shared-entry
-            # dictionaries resolve to a no-op or a cached int32 remap
-            # (a device gather); unregistered dictionaries fall back
-            # to the legacy sorted union inside the registry module
-            from ..observability import trace_span
-            from .. import columnar_registry
-
-            with trace_span("host.dictionary", site="concat.unify",
-                            column=f.name, n_dicts=len(dicts)):
-                target, remaps = columnar_registry.unify(dicts)
-                dict_ = target
-                remapped = []
-                for v, remap in zip(values_list, remaps):
-                    if remap is None:
-                        remapped.append(v)
-                        continue
-                    remapped.append(
-                        jnp.take(jnp.asarray(remap),
-                                 v.astype(jnp.int32), mode="clip")
-                    )
-                values_list = remapped
-        vals = jnp.concatenate(values_list)
-        vs = [b.columns[i].validity for b in batches]
-        if any(v is not None for v in vs):
-            validity = jnp.concatenate(
-                [
-                    v if v is not None else jnp.ones((b.capacity,), jnp.bool_)
-                    for v, b in zip(vs, batches)
-                ]
-            )
-        else:
-            validity = None
-        cols.append(Column(vals, f.dtype, validity, dict_))
-    selection = jnp.concatenate([b.selection for b in batches])
-    num_rows = sum([b.num_rows for b in batches])
-    out = ColumnBatch(schema, cols, selection, num_rows)
+    if not shares_dictionaries(batches):
+        batches = _unify_dictionaries(schema, batches)
+    out = gather_batches(batches)
     # fresh jnp.concatenate buffers with exactly one consumer (the
     # aggregation/sort program the concat feeds): donation-eligible.
     # The len == 1 pass-through above deliberately inherits the input's
@@ -492,6 +511,35 @@ def concat_batches(schema: Schema, batches: List[ColumnBatch]) -> ColumnBatch:
 
     mark_transient(out)
     return out
+
+
+def _unify_dictionaries(schema: Schema, batches: List[ColumnBatch]
+                        ) -> List[ColumnBatch]:
+    """``batches`` with every utf8 column's codes remapped onto ONE
+    dictionary a column, through the dictionary registry: shared-entry
+    dictionaries resolve to a no-op or a cached int32 remap (a device
+    gather); unregistered dictionaries fall back to the legacy sorted
+    union inside the registry module. Host-level."""
+    from ..observability import trace_span
+    from .. import columnar_registry
+
+    columns = [list(b.columns) for b in batches]
+    for i, f in enumerate(schema.fields):
+        dicts = [b.columns[i].dictionary for b in batches]
+        dict_ = next((d for d in dicts if d is not None), None)
+        if dict_ is None or all(d is None or d is dict_ for d in dicts):
+            continue
+        with trace_span("host.dictionary", site="concat.unify",
+                        column=f.name, n_dicts=len(dicts)):
+            target, remaps = columnar_registry.unify(dicts)
+            for cols, remap in zip(columns, remaps):
+                c = cols[i]
+                values = c.values if remap is None else jnp.take(
+                    jnp.asarray(remap), c.values.astype(jnp.int32),
+                    mode="clip")
+                cols[i] = Column(values, c.dtype, c.validity, target)
+    return [ColumnBatch(b.schema, cols, b.selection, b.num_rows)
+            for b, cols in zip(batches, columns)]
 
 
 # Measured cost of a blocking scalar device->host read (seconds). Where

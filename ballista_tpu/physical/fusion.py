@@ -13,10 +13,11 @@ Three rewrites (all gated by ``BALLISTA_FUSION``, default on):
   feeding it. The chain's ``device_transform``s run INSIDE the
   aggregate's traced programs (``HashAggregateExec._device_prologue``),
   so the whole stage is one governed jit entry — and the stage executes
-  once per partition over the CONCATENATED source batches instead of
-  dispatching the chain per scan chunk (each chunk's fresh dictionaries
-  previously forced a re-trace per chunk; q1+q5 cold minted 122 XLA
-  programs, most of them these).
+  once per partition over ALL its source batches, laid end to end
+  inside that program (``HashAggregateExec._partition_input``), instead
+  of dispatching the chain per scan chunk (each chunk's fresh
+  dictionaries previously forced a re-trace per chunk; q1+q5 cold minted
+  122 XLA programs, most of them these).
 - **Probe-side join chains**: Filter/Projection chains feeding a
   ``JoinExec`` probe fold into the join's probe programs
   (``JoinExec.probe_chain``) when every probe key column passes through
@@ -196,19 +197,9 @@ class FusedStageExec(HashAggregateExec):
         return _chain_prologue(self.chain, batch)
 
     def execute(self, partition: int) -> Iterator[ColumnBatch]:
-        from ..cache.donation import mark_transient
-
-        batches = list(self.source.execute(partition))
-        if not batches:
-            return
-        batch = concat_batches(self.source.output_schema(), batches)
-        if not self.group_exprs:
-            out = self._exec_scalar(batch)
-        else:
-            out = self._exec_grouped(batch)
-        # fresh program output, one downstream consumer: donatable
-        mark_transient(out)
-        yield out
+        yield from self._execute_over(
+            self.source.output_schema(),
+            list(self.source.execute(partition)))
 
     def _post_chain_abstract(self, batch: ColumnBatch):
         """Abstract (eval_shape) post-chain batch for host-side path

@@ -331,3 +331,68 @@ def test_join_probe_after_compaction_at_16384_rows(one_chip, no_disk_cache,
         (table, bb, pb, key_tables, remaps))
     assert jax.eval_shape(jitted, *shapes).capacity == 16384
     jitted.lower(*shapes).compile()
+
+
+@pytest.mark.parametrize("shape", ["q1_grouped", "q6_scalar"])
+def test_aggregate_over_a_partition_of_five_batches(one_chip, no_disk_cache,
+                                                    shape):
+    """The scan-aggregate cells' partial stage since PR 40: a partition of
+    SF3's lineitem arrives from the table cache as four batches of 1<<20
+    rows and a tail rung of 1<<19, and the governed program takes the
+    TUPLE and lays the pieces end to end in its own trace
+    (``physical/base.py`` ``gather_batches``). The plan is made and the
+    path chosen on the CPU over a few rows; the program is then compiled
+    for the chip at the cell's capacities. It holds the assembled columns
+    as temporaries (XLA keeps the copy: PERF.md, PR 40), which must fit
+    beside the resident table."""
+    from ballista_tpu import (Date32, Decimal, Utf8, avg, col, count, lit,
+                              schema, sum_)
+    from ballista_tpu.columnar import ColumnBatch, Dictionary
+    from ballista_tpu.io import MemTableSource
+    from ballista_tpu.physical.aggregate import HashAggregateExec
+    from ballista_tpu.physical.fusion import fuse_plan
+    from ballista_tpu.physical.operators import FilterExec, ScanExec
+
+    s = schema(("l_quantity", Decimal(2)), ("l_extendedprice", Decimal(2)),
+               ("l_discount", Decimal(2)), ("l_tax", Decimal(2)),
+               ("l_returnflag", Utf8), ("l_linestatus", Utf8),
+               ("l_shipdate", Date32))
+    n = 64
+    small = ColumnBatch.from_numpy(
+        s, {"l_quantity": np.full(n, 1700), "l_extendedprice": np.full(n, 9),
+            "l_discount": np.full(n, 5), "l_tax": np.full(n, 2),
+            "l_returnflag": np.arange(n, dtype=np.int32) % 3,
+            "l_linestatus": np.arange(n, dtype=np.int32) % 2,
+            "l_shipdate": np.arange(n, dtype=np.int32) + 9000},
+        {"l_returnflag": Dictionary(["A", "N", "R"]),
+         "l_linestatus": Dictionary(["F", "O"])})
+    scan = ScanExec("lineitem", MemTableSource(s, [[small]]))
+    price, disc = col("l_extendedprice"), col("l_discount")
+    if shape == "q1_grouped":
+        st = fuse_plan(HashAggregateExec(
+            "partial", [col("l_returnflag"), col("l_linestatus")],
+            [sum_(col("l_quantity")), sum_(price),
+             sum_(price * (lit(1) - disc)),
+             sum_(price * (lit(1) - disc) * (lit(1) + col("l_tax"))),
+             avg(col("l_quantity")), avg(price), avg(disc), count()],
+            FilterExec(col("l_shipdate") <= lit(10471), scan)))
+        fn = st.governed_jit(("agg.grouped", st.group_capacity),
+                             st._grouped_build(st.group_capacity))
+    else:
+        st = fuse_plan(HashAggregateExec(
+            "partial", [], [sum_(price * disc)],
+            FilterExec((col("l_shipdate") >= lit(8766))
+                       & (col("l_shipdate") < lit(9131))
+                       & (col("l_quantity") < lit(24)), scan)))
+        fn = st.governed_jit(("agg.scalar",), st._scalar_build())
+    jitted = getattr(fn, "gf", fn).fn
+    caps = (1 << 20,) * 4 + (1 << 19,)
+    pieces = tuple(
+        jax.tree_util.tree_map(
+            lambda x, c=c: jax.ShapeDtypeStruct(
+                (c,) if x.ndim else (), x.dtype, sharding=one_chip), small)
+        for c in caps)
+    compiled = jitted.lower(pieces).compile()
+    mem = compiled.memory_analysis()
+    assert mem.argument_size_in_bytes > 100e6  # the five pieces themselves
+    assert mem.temp_size_in_bytes + mem.argument_size_in_bytes < 1e9
