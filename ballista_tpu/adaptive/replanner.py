@@ -30,9 +30,9 @@ from __future__ import annotations
 import logging
 from typing import List, Optional
 
-from ..observability import trace_event
 from .config import AdaptiveConfig
-from .rules import describe_layout, plan_shuffle_reads, should_broadcast
+from .rules import (describe_layout, layout_has_splits, note_rule,
+                    plan_shuffle_reads, should_broadcast)
 
 log = logging.getLogger("ballista.adaptive")
 
@@ -190,11 +190,11 @@ def _replan_ready_stage(state, job_id: str, sid: int,
         job_id, sid, plan_bytes=_dump_plan(plan),
         num_partitions=new_nparts, reader_layouts=layouts,
     )
-    trace_event("adaptive.replan", job=job_id, stage=sid,
-                rule="coalesce" if probe_dep is None else "coalesce+skew",
-                decision=note, reads_before=n_out, reads_after=len(layout),
-                tasks_before=row.num_partitions, tasks_after=new_nparts,
-                version=version)
+    note_rule("skew_split" if layout_has_splits(layout) else "coalesce",
+              "cluster", sum(combined), conf.target_partition_bytes,
+              n_out, len(layout), decision=note, job=job_id, stage=sid,
+              tasks_before=row.num_partitions, tasks_after=new_nparts,
+              version=version)
     log.info("adaptive: job %s stage %d: %s (%d -> %d tasks, v%d)",
              job_id, sid, note, row.num_partitions, new_nparts, version)
 
@@ -280,11 +280,11 @@ def _maybe_demote_join(state, job_id: str, consumer_sid: int,
     # probe producer stops hash-splitting: its tasks now write ONE
     # partition file each, which the demoted join streams 1:1
     state.update_stage_plan(job_id, probe_sid, shuffle_spec=None)
-    trace_event("adaptive.replan", job=job_id, stage=consumer_sid,
-                rule="broadcast", decision=note, build_stage=completed_sid,
-                probe_stage=probe_sid, build_bytes=total,
-                tasks_before=crow.num_partitions, tasks_after=new_nparts,
-                version=version)
+    note_rule("broadcast_build", "cluster", total,
+              conf.broadcast_threshold_bytes, crow.num_partitions,
+              new_nparts, decision=note, job=job_id, stage=consumer_sid,
+              build_stage=completed_sid, probe_stage=probe_sid,
+              version=version)
     log.info("adaptive: job %s stage %d: %s (probe stage %d unshuffled; "
              "%d -> %d tasks, v%d)", job_id, consumer_sid, note,
              probe_sid, crow.num_partitions, new_nparts, version)

@@ -28,6 +28,8 @@ from __future__ import annotations
 
 from typing import List, Optional, Sequence, Tuple
 
+from ..observability import trace_event
+
 ReadRange = Tuple[int, int, int, int]
 Layout = List[List[ReadRange]]
 
@@ -197,3 +199,16 @@ def describe_layout(n_before: int, layout: Layout) -> str:
         parts.append(f"split skewed partition{'s' if len(split_qs) > 1 else ''}"
                      f" [{qs}] into {n_split_tasks} reads")
     return ", ".join(parts) if parts else "unchanged"
+
+
+def note_rule(rule: str, where: str, nbytes: int, threshold: int,
+              n_from: int, n_to: int, **attrs) -> None:
+    """One ``adaptive.rule`` event a REWRITE (not a query: a cached plan
+    keeps its rewritten tree): ``rule`` is ``broadcast_build``,
+    ``coalesce`` or ``skew_split``, ``where`` ``standalone`` or
+    ``cluster``; ``bytes`` is the observed size the rule acted on beside
+    the ``threshold`` it was held to, ``from`` and ``to`` the partitions
+    (tasks, for a cluster's demoted join) before and after."""
+    trace_event("adaptive.rule", rule=rule, where=where, bytes=int(nbytes),
+                threshold=int(threshold), **{"from": n_from, "to": n_to},
+                **attrs)

@@ -28,11 +28,11 @@ import logging
 from typing import Iterator, List
 
 from ..physical.base import Partitioning, PhysicalPlan
-from ..observability import trace_event
 from .config import AdaptiveConfig
 from .rules import (
     describe_layout,
     layout_has_splits,
+    note_rule,
     plan_shuffle_reads,
     should_broadcast,
 )
@@ -145,8 +145,9 @@ def _adapt_partitioned_join(join, conf: AdaptiveConfig):
         total = sum(build_bytes)
         note = (f"broadcast build ({total / 1e6:.2f} MB < "
                 f"{conf.broadcast_threshold_bytes / 1e6:.0f} MB threshold)")
-        trace_event("adaptive.standalone", rule="broadcast",
-                    decision=note, build_bytes=total)
+        note_rule("broadcast_build", "standalone", total,
+                  conf.broadcast_threshold_bytes,
+                  join.build.num_partitions, 1, decision=note)
         log.info("adaptive (standalone): %s", note)
         # the probe's repartition is dropped entirely: its child streams
         # into the merged join untouched; the build keeps its (already
@@ -168,9 +169,9 @@ def _adapt_partitioned_join(join, conf: AdaptiveConfig):
     build_layout = [[(olo, ohi, 0, 0) for (olo, ohi, _, _) in ranges]
                     for ranges in layout]
     note = describe_layout(join.build.num_partitions, layout)
-    trace_event("adaptive.standalone", rule="coalesce+skew", decision=note,
-                buckets_before=join.build.num_partitions,
-                buckets_after=len(layout))
+    note_rule("skew_split" if layout_has_splits(layout) else "coalesce",
+              "standalone", sum(combined), conf.target_partition_bytes,
+              join.build.num_partitions, len(layout), decision=note)
     log.info("adaptive (standalone): %s", note)
     return join.with_new_children([
         AdaptiveShuffleReadExec(join.build, build_layout, note),
@@ -189,8 +190,8 @@ def _adapt_lone_repartition(repart, conf: AdaptiveConfig):
     if layout is None:
         return repart
     note = describe_layout(repart.num_partitions, layout)
-    trace_event("adaptive.standalone", rule="coalesce", decision=note,
-                buckets_before=repart.num_partitions,
-                buckets_after=len(layout))
+    note_rule("coalesce", "standalone", sum(bytes_q),
+              conf.target_partition_bytes, repart.num_partitions,
+              len(layout), decision=note)
     log.info("adaptive (standalone): %s", note)
     return AdaptiveShuffleReadExec(repart, layout, note)

@@ -121,10 +121,29 @@ UNMAPPED_ALLOWLIST = {
     "launch.cold",
     # cancellation marker event (dur=0): lifecycle, not latency
     "lifecycle.cancel",
-    # adaptive re-planning markers: they fire INSIDE windows that are
-    # already attributed (standalone collect / executor task)
-    "adaptive.standalone",
-    "adaptive.replan",
+    # adaptive re-planning marker (dur=0), one a REWRITE of a plan
+    # (adaptive/rules.py note_rule, from the standalone pass and the
+    # cluster replanner alike): it fires INSIDE windows that are
+    # already attributed (standalone collect / scheduler stage report)
+    "adaptive.rule",
+    # what a cached plan builds once and then keeps (physical/join.py
+    # _materialize_build, physical/operators.py RepartitionExec): the
+    # spans of a build side made and of a repartition's sources sorted
+    # by destination run inside the collect / task window and hold
+    # device.block children of their own; the dur=0 markers count a
+    # build or sorted sources found again, and one a destination
+    # partition gathered (the time is the device's, under
+    # jit_repart_take)
+    "join.build",
+    "join.build_reused",
+    "repart.materialize",
+    "repart.reused",
+    "repart.take",
+    # marker event (dur=0), one a scan partition executed
+    # (physical/operators.py ScanExec): its rows and how they were
+    # served (resident | filled | streamed); parse and upload time is
+    # the ingest spans'
+    "scan.serve",
     # whole-stage fusion runs inside the planning phase, which both
     # paths stamp wholesale (client ledger_phase / scheduler stamp)
     "compile.fuse",

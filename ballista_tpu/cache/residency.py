@@ -154,11 +154,13 @@ class DeviceMemoryGovernor:
 
 
 class _Entry:
-    __slots__ = ("batches", "nbytes", "hits", "filled_at", "last_access")
+    __slots__ = ("batches", "nbytes", "rows", "hits", "filled_at",
+                 "last_access")
 
     def __init__(self, batches: List, nbytes: int):
         self.batches = batches
         self.nbytes = nbytes
+        self.rows = None  # live rows, read once (live_rows)
         self.hits = 0
         self.filled_at = time.time()
         self.last_access = self.filled_at
@@ -257,6 +259,27 @@ class DeviceTableCache:
             return False
         with self._lock:
             return key in self._entries
+
+    def live_rows(self, key: Optional[tuple]) -> Optional[int]:
+        """Live rows of the partition pinned under ``key``, or None when
+        nothing is. The batches' counts live on the device: they are
+        read ONCE an entry and kept on the host beside it, so a scan
+        served from the device says how many rows it served without
+        blocking on them again."""
+        with self._lock:
+            e = self._entries.get(key)
+        if e is None:
+            return None
+        if e.rows is None:
+            import jax
+
+            from ..observability import trace_span
+
+            with trace_span("device.block", site="cache.live_rows",
+                            n=len(e.batches)):
+                e.rows = int(sum(jax.device_get(
+                    [b.num_rows for b in e.batches])))
+        return e.rows
 
     def begin_fill(self, key: Optional[tuple]) -> Optional[_Filler]:
         """A filler for ``key``, or None when the tier is off, the key

@@ -277,6 +277,21 @@ class PhysicalPlan:
         return out
 
 
+def side_name(plan: PhysicalPlan) -> str:
+    """What a subtree is made from, for the spans of the operators that
+    keep its output (a join's build, a repartition's sources): the tables
+    of the scans under it in name order, ``customer+orders`` for the
+    output of their join; the operator's own name where it scans none."""
+    tables, stack = set(), [plan]
+    while stack:
+        node = stack.pop()
+        name = getattr(node, "table_name", None)
+        if name:
+            tables.add(name)
+        stack.extend(node.children())
+    return "+".join(sorted(tables)) or type(plan).__name__
+
+
 class SchemaLeaf(PhysicalPlan):
     """Schema-only placeholder standing in for a severed child on a
     trace twin (mirrors mesh_agg's _SchemaOnly, but importable from

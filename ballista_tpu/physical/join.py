@@ -33,8 +33,9 @@ from ..errors import ExecutionError, NotImplementedError_
 from ..kernels import join as join_k
 from ..kernels.search import depth as search_depth
 from ..observability.metrics import metrics_enabled
-from ..observability.tracing import trace_event
-from .base import PhysicalPlan, Partitioning, concat_batches
+from ..observability.tracing import trace_event, trace_span
+from .base import (PhysicalPlan, Partitioning, concat_batches,
+                   side_name)
 
 JOIN_TYPES = ("inner", "left", "semi", "anti", "full")
 
@@ -356,15 +357,26 @@ class JoinExec(PhysicalPlan):
         return empty_batch(self.build.output_schema())
 
     def _materialize_build(self, partition: int = 0):
+        """The build side of ``partition`` (of the whole join unless
+        ``partitioned``), built once: a cached plan keeps it between
+        executions, so a later call (``join.build_reused``) runs nothing
+        of the build subtree."""
         key = partition if self.partitioned else 0
-        if key in self._build_data:  # fast path, no lock once built
-            return self._build_data[key]
-        with self._build_locks.get(key):
-            return self._materialize_build_locked(key, partition)
+        if key not in self._build_data:  # fast path, no lock once built
+            with self._build_locks.get(key):
+                if key not in self._build_data:
+                    with trace_span("join.build", side=side_name(self.build),
+                                    partitioned=self.partitioned) as span:
+                        self._build_data[key] = self._build_side(
+                            partition, span.attrs)
+                    return self._build_data[key]
+        trace_event("join.build_reused", partitioned=self.partitioned)
+        return self._build_data[key]
 
-    def _materialize_build_locked(self, key: int, partition: int):
-        if key in self._build_data:
-            return self._build_data[key]
+    def _build_side(self, partition: int, note: dict):
+        """Run the build subtree and make its lookup table; ``note``
+        takes what was built (the ``join.build`` span's attributes, all
+        of them host values the build has read anyway)."""
         if self.partitioned:
             batches = list(self.build.execute(partition))
         else:
@@ -424,9 +436,11 @@ class JoinExec(PhysicalPlan):
                 metrics=self.metrics() if metrics_enabled() else None)
             table, uniq = sorted_fn(keys, live)
             unique = bool(uniq)
-        self._build_data[key] = (table, bb, unique, has_null_key, mode,
-                                 key_tables, keys, live)
-        return self._build_data[key]
+        note.update(rows=nlive, capacity=bb.capacity, pieces=len(batches),
+                    mode="sorted" if table.sorted_keys is not None
+                    else "dense", unique=unique)
+        return (table, bb, unique, has_null_key, mode, key_tables, keys,
+                live)
 
     def execute(self, partition: int) -> Iterator[ColumnBatch]:
         (table, build_batch, unique, has_null_key, mode, key_tables,

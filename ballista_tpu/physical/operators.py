@@ -27,8 +27,9 @@ from ..kernels.expr_eval import Evaluator
 from ..kernels.sort import sort_permutation
 from ..kernels.hashing import splitmix64
 from ..logical import TableSource
+from ..observability.tracing import trace_event, trace_span
 from .base import (PhysicalPlan, PipelineOp, Partitioning, concat_batches,
-                   pad_batch, take_batch)
+                   pad_batch, side_name, take_batch)
 
 
 def compute_partition_ids(batch: ColumnBatch, hash_exprs, num_partitions: int,
@@ -145,6 +146,13 @@ class ScanExec(PhysicalPlan):
             h.cancel()
 
     def execute(self, partition: int) -> Iterator[ColumnBatch]:
+        counts = []  # the batches' live rows, still on the device
+        for batch in self._batches(partition):
+            counts.append(batch.num_rows)
+            yield batch
+        self._note_served(partition, counts)
+
+    def _batches(self, partition: int) -> Iterator[ColumnBatch]:
         from ..ingest import prefetch_batches
         from ..ingest.phases import bound_iter
 
@@ -164,16 +172,35 @@ class ScanExec(PhysicalPlan):
                 # stop the producer instead of leaving it blocked on a
                 # full queue
                 handle.cancel()
-        self._record_cache_outcome(partition)
 
-    def _record_cache_outcome(self, partition: int) -> None:
+    def _note_served(self, partition: int, counts: list) -> None:
+        """One ``scan.serve`` event a partition scanned to its end: how
+        it was served (``resident``: from the device table cache;
+        ``filled``: parsed and pinned there by this scan; ``streamed``:
+        made for this scan alone) and its live ``rows``. A resident
+        partition's rows are kept on the host beside its cache entry; a
+        streamed one's are read here, a read that a parse dwarfs; a
+        source outside the residency layer (a memory table) is not
+        asked, so that no small query blocks for the sake of a count."""
+        from ..cache.residency import process_table_cache
         from ..observability.metrics import metrics_enabled
 
-        if not metrics_enabled():
-            return
         fn = getattr(self.source, "scan_cache_outcome", None)
-        if fn is not None and fn(partition) == "hit":
+        outcome = fn(partition) if fn is not None else None
+        if outcome == "hit" and metrics_enabled():
             self.metrics().add_counter("table_cache_hits")
+        rows = None
+        if outcome in ("hit", "filled"):
+            rows = process_table_cache().live_rows(
+                self.source.residency_key(partition, self.projection))
+        if rows is None and outcome is not None:
+            with trace_span("device.block", site="scan.rows",
+                            n=len(counts)):
+                rows = int(sum(jax.device_get(counts)))
+        trace_event("scan.serve", table=self.table_name, rows=rows,
+                    batches=len(counts),
+                    how={"hit": "resident",
+                         "filled": "filled"}.get(outcome, "streamed"))
 
     def estimated_rows(self):
         return self.source.estimated_rows()
@@ -412,9 +439,13 @@ class RepartitionExec(PhysicalPlan):
     """Re-partition input into N output partitions by hash or round-robin
     (reference: RepartitionExecNode, ballista.proto:415-422).
 
-    Single-process implementation: child partitions are materialized once and
-    each output partition applies a selection mask (pid == p) — no compaction
-    on device. The distributed path uses shuffle writes instead.
+    Single-process implementation: child partitions are materialized once
+    and each batch is sorted by destination once (``repart.materialize``);
+    an output partition then gathers its rows out of every source batch
+    into one compacted batch (``repart.take``, one program a source). A
+    cached plan keeps the sorted sources between executions
+    (``repart.reused``), so a warm query runs only the takes. The
+    distributed path uses shuffle writes instead.
     """
 
     def __init__(self, child: PhysicalPlan, num_partitions: int,
@@ -473,7 +504,25 @@ class RepartitionExec(PhysicalPlan):
     def _materialize_parts(self):
         """Materialize once and sort each batch by destination partition
         ONCE (not once per output partition): partition p is then a
-        contiguous slice of the permutation. [(batch, perm, counts)]
+        contiguous slice of the permutation. [(batch, perm, counts)],
+        kept for as long as the plan lives."""
+        if getattr(self, "_parts", None) is not None:
+            trace_event("repart.reused")
+            return self._parts
+        with self._mat_lock:
+            if getattr(self, "_parts", None) is None:
+                with trace_span("repart.materialize",
+                                side=side_name(self.child)) as span:
+                    parts = self._sorted_sources()
+                    span.attrs.update(
+                        sources=len(parts),
+                        rows=int(sum(c.sum() for _, _, c in parts)))
+                self._parts = parts
+            return self._parts
+
+    def _sorted_sources(self):
+        """[(source batch, its rows' permutation by destination, rows a
+        destination)]: one ``lax.sort`` a child batch.
 
         With the ingest pipeline on, the per-batch host syncs are
         DEFERRED: every batch's sort is dispatched back-to-back and the
@@ -483,68 +532,54 @@ class RepartitionExec(PhysicalPlan):
         round-robin does, and it needs the per-batch row count on
         host). ``BALLISTA_PREFETCH_BATCHES=0`` restores the serial
         sync-per-batch loop."""
-        with self._mat_lock:
-            return self._materialize_parts_locked()
+        from ..ingest import prefetch_batches
 
-    def _materialize_parts_locked(self):
-        if getattr(self, "_parts", None) is None:
-            from ..ingest import prefetch_batches
+        def build():
+            tw = self.trace_twin()  # don't pin materialized batches
+            n_out = tw.num_partitions
 
-            def build():
-                tw = self.trace_twin()  # don't pin materialized batches
-                n_out = tw.num_partitions
+            def sort_by_pid(b: ColumnBatch, offset):
+                pids = tw.partition_ids(b, offset)
+                d = jnp.where(b.selection, pids, n_out)  # dead last
+                idx = jnp.arange(b.capacity, dtype=jnp.int32)
+                _, perm = jax.lax.sort((d, idx), num_keys=1,
+                                       is_stable=True)
+                counts = jnp.bincount(d, length=n_out + 1)[:n_out]
+                return perm, counts
 
-                def sort_by_pid(b: ColumnBatch, offset):
-                    pids = tw.partition_ids(b, offset)
-                    d = jnp.where(b.selection, pids, n_out)  # dead last
-                    idx = jnp.arange(b.capacity, dtype=jnp.int32)
-                    _, perm = jax.lax.sort((d, idx), num_keys=1,
-                                           is_stable=True)
-                    counts = jnp.bincount(d, length=n_out + 1)[:n_out]
-                    return perm, counts
+            return sort_by_pid
 
-                return sort_by_pid
+        mask_fn = self.governed_jit(("repart.sort_by_pid",), build)
+        batches = self._materialize()
+        if prefetch_batches() > 0 and self.hash_exprs:
+            from ..ingest import parallel_map
 
-            mask_fn = self.governed_jit(("repart.sort_by_pid",), build)
-            pipelined = prefetch_batches() > 0 and self.hash_exprs
-            batches = self._materialize()
-            if pipelined:
-                from ..ingest import parallel_map
-
-                # offset is unread by hash partitioning, so batches are
-                # independent: the first sorts inline (the governed
-                # entry traces exactly once), the rest dispatch from
-                # pool workers — independent XLA executions genuinely
-                # overlap across cores — and every count scalar
-                # resolves in ONE device_get
-                zero = jnp.int32(0)
-                pairs = ([mask_fn(batches[0], zero)] if batches else [])
-                pairs += parallel_map(lambda b: mask_fn(b, zero),
-                                      batches[1:])
-                from ..observability import trace_span
-
-                with trace_span("device.block", site="repart.counts",
-                                n=len(pairs)):
-                    resolved = jax.device_get([c for _, c in pairs])
-                parts = [(b, perm, np.asarray(c))
-                         for b, (perm, _), c in zip(batches, pairs,
-                                                    resolved)]
-            else:
-                from ..observability import trace_span
-
-                parts = []
-                offset = 0
-                for batch in batches:
-                    perm, counts = mask_fn(batch, jnp.int32(offset))
-                    # offset-dependent batches serialize: one sync per
-                    # batch, each attributed to the blocked lane
-                    with trace_span("device.block", site="repart.counts",
-                                    n=1):
-                        host_counts = np.asarray(counts)
-                    parts.append((batch, perm, host_counts))
-                    offset += batch.num_rows_host()
-            self._parts = parts
-        return self._parts
+            # offset is unread by hash partitioning, so batches are
+            # independent: the first sorts inline (the governed
+            # entry traces exactly once), the rest dispatch from
+            # pool workers — independent XLA executions genuinely
+            # overlap across cores — and every count scalar
+            # resolves in ONE device_get
+            zero = jnp.int32(0)
+            pairs = ([mask_fn(batches[0], zero)] if batches else [])
+            pairs += parallel_map(lambda b: mask_fn(b, zero),
+                                  batches[1:])
+            with trace_span("device.block", site="repart.counts",
+                            n=len(pairs)):
+                resolved = jax.device_get([c for _, c in pairs])
+            return [(b, perm, np.asarray(c))
+                    for b, (perm, _), c in zip(batches, pairs, resolved)]
+        parts = []
+        offset = 0
+        for batch in batches:
+            perm, counts = mask_fn(batch, jnp.int32(offset))
+            # offset-dependent batches serialize: one sync per
+            # batch, each attributed to the blocked lane
+            with trace_span("device.block", site="repart.counts", n=1):
+                host_counts = np.asarray(counts)
+            parts.append((batch, perm, host_counts))
+            offset += batch.num_rows_host()
+        return parts
 
     def execute(self, partition: int) -> Iterator[ColumnBatch]:
         """Yields ONE COMPACTED batch: rows of the requested partition are
@@ -581,7 +616,7 @@ class RepartitionExec(PhysicalPlan):
 
     def _execute_fragments(self, partition: int, frag_lo: int,
                            frag_hi) -> Iterator[ColumnBatch]:
-        pieces = []
+        pieces, rows = [], 0
         for batch, perm, counts in self._materialize_parts()[
                 frag_lo:frag_hi]:
             n = int(counts[partition])
@@ -603,9 +638,11 @@ class RepartitionExec(PhysicalPlan):
 
             take = self.governed_jit(("repart.take", cap), build)
             pieces.append(take(batch, idx, jnp.int32(n)))
-        if len(pieces) == 1:
-            yield pieces[0]
-        elif pieces:
+            rows += n
+        if not pieces:
+            return
+        out = pieces[0]
+        if len(pieces) > 1:
             out = concat_batches(self.output_schema(), pieces)
             # concat of ladder-sized pieces isn't itself a ladder rung
             # (128+64=192); pad up so downstream per-capacity jit caches
@@ -613,7 +650,9 @@ class RepartitionExec(PhysicalPlan):
             target = bucket_capacity(out.capacity)
             if target != out.capacity:
                 out = pad_batch(out, target)
-            yield out
+        trace_event("repart.take", side=side_name(self.child), rows=rows,
+                    pieces=len(pieces), capacity=out.capacity)
+        yield out
 
     def display(self) -> str:
         k = "hash" if self.hash_exprs else "round-robin"
