@@ -257,6 +257,7 @@ def _maybe_demote_join(state, job_id: str, consumer_sid: int,
                               prow.num_partitions),
         target.on, target.how, null_aware=target.null_aware,
         partitioned=False, adaptive_note=note,
+        out_columns=target.out_columns,
     )
     new_plan = _replace_node(plan, target, demoted)
     new_nparts = new_plan.output_partitioning().num_partitions

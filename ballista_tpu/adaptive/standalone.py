@@ -154,7 +154,7 @@ def _adapt_partitioned_join(join, conf: AdaptiveConfig):
         # materialized) repartition and is concatenated across buckets
         return JoinExec(join.build, join.probe.child, join.on, join.how,
                         null_aware=join.null_aware, partitioned=False,
-                        adaptive_note=note)
+                        adaptive_note=note, out_columns=join.out_columns)
     if not (conf.coalesce_enabled or conf.skew_enabled):
         return join
     probe_bytes, probe_frag = _observed_bytes(join.probe)

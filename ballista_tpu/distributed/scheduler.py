@@ -170,7 +170,8 @@ def _fuse_mesh_stages(stages, n_mesh: int):
                             node.how)
                         return MeshJoinExec(bprod.child, pprod.child,
                                             node.on, node.how, n_mesh,
-                                            null_aware=node.null_aware)
+                                            null_aware=node.null_aware,
+                                            out_columns=node.out_columns)
                 kids = node.children()
                 if not kids:
                     return node

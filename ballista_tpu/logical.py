@@ -269,6 +269,11 @@ class Join(LogicalPlan):
     # SQL NOT IN lowering: anti join where any NULL build key empties the
     # result and NULL probe keys are excluded
     null_aware: bool = False
+    # the columns this join emits, in schema order: set by the optimizer's
+    # pruning pass from what the parent reads, as TableScan.projection is.
+    # None = every column of both inputs. The INPUTS keep their join keys
+    # either way
+    columns: Optional[Tuple[str, ...]] = None
 
     def __post_init__(self):
         if self.how not in JOIN_TYPES:
@@ -282,14 +287,20 @@ class Join(LogicalPlan):
         lf = list(ls.fields)
         seen = {f.name for f in lf}
         rf = [f for f in rs.fields if f.name not in seen]
-        return Schema(lf + rf)
+        fields = lf + rf
+        if self.columns is not None:
+            keep = set(self.columns)
+            fields = [f for f in fields if f.name in keep]
+        return Schema(fields)
 
     def children(self) -> List[LogicalPlan]:
         return [self.left, self.right]
 
     def display(self) -> str:
         on = ", ".join(f"{l}={r}" for l, r in self.on)
-        return f"Join: how={self.how} on=[{on}]"
+        out = ("" if self.columns is None
+               else f" out=[{', '.join(self.columns)}]")
+        return f"Join: how={self.how} on=[{on}]{out}"
 
 
 # ---------------------------------------------------------------------------

@@ -299,6 +299,22 @@ def _cols_of(exprs) -> Set[str]:
     return out
 
 
+def _join_columns(plan: Join, required: Optional[Set[str]], left: LogicalPlan):
+    """What a join emits of its schema when its parent reads only
+    ``required``: the join keys and a pushed-down filter's columns stay
+    in its INPUTS and stop there. Semi/anti joins emit their probe side
+    under a selection and gather nothing, so they carry no list.
+    ``left`` is the left input as pruned."""
+    if required is None or plan.how in ("semi", "anti"):
+        return plan.columns
+    names = plan.schema().names()
+    keep = tuple(n for n in names if n in required)
+    if not keep:  # count(*)-style parent: one column carries the rows,
+        # and it has to be one the pruned inputs still emit
+        keep = (left.schema().names()[0],)
+    return plan.columns if len(keep) == len(names) else keep
+
+
 def prune_columns(plan: LogicalPlan, required: Optional[Set[str]]) -> LogicalPlan:
     """required=None means every column of this node's schema is needed."""
     if isinstance(plan, TableScan):
@@ -340,9 +356,10 @@ def prune_columns(plan: LogicalPlan, required: Optional[Set[str]]) -> LogicalPla
         else:
             lneed = (set(required) & lnames) | on_l
             rneed = (set(required) & rnames) | on_r
-        return dataclasses.replace(plan,
-                                   left=prune_columns(plan.left, lneed),
-                                   right=prune_columns(plan.right, rneed))
+        left = prune_columns(plan.left, lneed)
+        return dataclasses.replace(plan, left=left,
+                                   right=prune_columns(plan.right, rneed),
+                                   columns=_join_columns(plan, required, left))
     if isinstance(plan, Explain):
         return Explain(prune_columns(plan.input, None), plan.verbose,
                        plan.analyze)

@@ -560,7 +560,8 @@ def fuse_plan(phys: PhysicalPlan, *, fuse_joins: bool = True,
                         null_aware=node.null_aware,
                         partitioned=node.partitioned,
                         adaptive_note=node.adaptive_note,
-                        probe_chain=chain, probe_key_raw=key_map)
+                        probe_chain=chain, probe_key_raw=key_map,
+                        out_columns=node.out_columns)
                     trace_event("compile.fuse", kind="join_probe",
                                 ops=fused_join.display()[:160])
                     return fused_join

@@ -78,6 +78,7 @@ class JoinExec(PhysicalPlan):
         adaptive_note: Optional[str] = None,
         probe_chain: Optional[List] = None,
         probe_key_raw: Optional[dict] = None,
+        out_columns: Optional[Tuple[str, ...]] = None,
     ):
         if how not in JOIN_TYPES:
             raise NotImplementedError_(f"join type {how}")
@@ -106,6 +107,18 @@ class JoinExec(PhysicalPlan):
         # column (for the host-side dictionary remap).
         self.probe_chain = tuple(probe_chain or ())
         self.probe_key_raw = dict(probe_key_raw or {})
+        # the columns this join EMITS, in output order (the logical
+        # join's pruned schema, ``optimizer.prune_columns``): assembly
+        # gathers one column a field of ``output_schema()``, so a join
+        # key or a filter's column that nothing above reads costs no
+        # gather. None = every build field, then the probe's (a list
+        # that says just that is kept as None). The inputs are not
+        # narrowed. Semi/anti joins emit the probe batch under a
+        # selection and take no list
+        self.out_columns = None
+        if out_columns is not None and how not in ("semi", "anti") \
+                and tuple(out_columns) != self.output_schema().names():
+            self.out_columns = tuple(out_columns)
         # partition -> (table, batch, unique, has_null, key mode,
         #               codec tables, build keys, build live)
         self._build_data = {}
@@ -123,10 +136,13 @@ class JoinExec(PhysicalPlan):
         # partitioned/adaptive_note steer HOST orchestration only — no
         # traced closure reads them, so a demoted (adaptive) join reuses
         # the original join's compiled probes. A fused probe chain IS
-        # traced, so its signatures ride the key.
+        # traced, so its signatures ride the key, and so does what the
+        # assembly emits: two joins over equal inputs that emit different
+        # columns never share a program.
         return (self.how, tuple(self.on), self.null_aware,
                 self.build.output_schema(), self._probe_out_schema(),
-                tuple(op.compile_signature() for op in self.probe_chain))
+                tuple(op.compile_signature() for op in self.probe_chain),
+                self.out_columns)
 
     def _probe_out_schema(self) -> Schema:
         """Schema of probe batches AFTER the fused chain (equals the
@@ -307,8 +323,10 @@ class JoinExec(PhysicalPlan):
         seen = {f.name for f in bs.fields}
         extra = [f for f in ps.fields if f.name not in seen]
         # build fields become nullable under probe-preserving (left) joins
-        bf = list(bs.fields)
-        return Schema(bf + extra)
+        full = Schema(list(bs.fields) + extra)
+        if self.out_columns is None:
+            return full
+        return full.project(self.out_columns)
 
     def estimated_rows(self):
         """Semi/anti joins emit a SUBSET of the probe side — the base
@@ -334,7 +352,7 @@ class JoinExec(PhysicalPlan):
         return JoinExec(children[0], children[1], self.on, self.how,
                         self.null_aware, self.partitioned,
                         self.adaptive_note, list(self.probe_chain),
-                        self.probe_key_raw)
+                        self.probe_key_raw, self.out_columns)
 
     def display(self) -> str:
         on = ", ".join(f"{l}={r}" for l, r in self.on)
@@ -346,7 +364,14 @@ class JoinExec(PhysicalPlan):
             ops = "→".join(type(op).__name__.replace("Exec", "")
                            for op in self.probe_chain)
             fused = f" [fused probe: {ops}]"
-        return f"JoinExec: how={self.how} on=[{on}]{part}{note}{fused}"
+        return (f"JoinExec: how={self.how} on=[{on}]{self._out_label()}"
+                f"{part}{note}{fused}")
+
+    def _out_label(self) -> str:
+        """`` out=[...]`` for plan text when the join emits a list."""
+        if self.out_columns is None:
+            return ""
+        return f" out=[{', '.join(self.out_columns)}]"
 
     # -- execution ----------------------------------------------------------
 
@@ -436,7 +461,9 @@ class JoinExec(PhysicalPlan):
                 metrics=self.metrics() if metrics_enabled() else None)
             table, uniq = sorted_fn(keys, live)
             unique = bool(uniq)
-        note.update(rows=nlive, capacity=bb.capacity, pieces=len(batches),
+        note.update(rows=nlive, capacity=bb.capacity,
+                    out=len(self.output_schema().fields),
+                    pieces=len(batches),
                     mode="sorted" if table.sorted_keys is not None
                     else "dense", unique=unique)
         return (table, bb, unique, has_null_key, mode, key_tables, keys,
@@ -794,7 +821,8 @@ class JoinExec(PhysicalPlan):
             return run
 
         fn = self.governed_jit(("join.expand", cap), build)
-        trace_event("join.expand", rows=t, probes=kept.capacity, to=cap)
+        trace_event("join.expand", rows=t, probes=kept.capacity, to=cap,
+                    cols=len(self.output_schema().fields))
         return fn(table, build_batch, kept, lo, ends, total)
 
     def _unmatched_batch(self, table, build_batch, pb, mode, key_tables,

@@ -317,7 +317,8 @@ class MeshJoinExec(PhysicalPlan):
 
     def __init__(self, build_producer: PhysicalPlan,
                  probe_producer: PhysicalPlan, on, how: str,
-                 n_devices: int, null_aware: bool = False):
+                 n_devices: int, null_aware: bool = False,
+                 out_columns=None):
         if how not in ("inner", "left", "semi", "anti", "full"):
             raise ExecutionError(f"MeshJoinExec join type {how}")
         self.null_aware = null_aware
@@ -332,7 +333,7 @@ class MeshJoinExec(PhysicalPlan):
         self._join = JoinExec(
             _SchemaOnly(build_producer.output_schema()),
             _SchemaOnly(probe_producer.output_schema()),
-            self.on, how,
+            self.on, how, out_columns=out_columns,
         )
         self._build_ev = Evaluator(build_producer.output_schema())
         self._probe_ev = Evaluator(probe_producer.output_schema())
@@ -342,6 +343,11 @@ class MeshJoinExec(PhysicalPlan):
     def output_schema(self) -> Schema:
         return self._join.output_schema()
 
+    @property
+    def out_columns(self):
+        """The columns the join it replaced emitted (``JoinExec``)."""
+        return self._join.out_columns
+
     def output_partitioning(self) -> Partitioning:
         return Partitioning("unknown", 1)
 
@@ -350,17 +356,18 @@ class MeshJoinExec(PhysicalPlan):
 
     def with_new_children(self, children):
         return MeshJoinExec(children[0], children[1], self.on, self.how,
-                            self.n_devices, self.null_aware)
+                            self.n_devices, self.null_aware,
+                            self.out_columns)
 
     def display(self) -> str:
         on = ", ".join(f"{l}={r}" for l, r in self.on)
         return (f"MeshJoinExec: {self.n_devices}-device ICI all_to_all "
-                f"join how={self.how} on=[{on}]")
+                f"join how={self.how} on=[{on}]{self._join._out_label()}")
 
     def _signature_parts(self) -> tuple:
         return (self.how, tuple(self.on), self.null_aware, self.n_devices,
                 self.build_producer.output_schema(),
-                self.probe_producer.output_schema())
+                self.probe_producer.output_schema(), self.out_columns)
 
     def _detach(self) -> None:
         from .base import SchemaLeaf

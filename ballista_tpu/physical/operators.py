@@ -651,7 +651,8 @@ class RepartitionExec(PhysicalPlan):
             if target != out.capacity:
                 out = pad_batch(out, target)
         trace_event("repart.take", side=side_name(self.child), rows=rows,
-                    pieces=len(pieces), capacity=out.capacity)
+                    pieces=len(pieces), capacity=out.capacity,
+                    cols=len(out.columns))
         yield out
 
     def display(self) -> str:

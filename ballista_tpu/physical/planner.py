@@ -184,9 +184,9 @@ def _create(plan: LogicalPlan, opts: PlannerOptions) -> PhysicalPlan:
         # per-bucket build would miss nulls that hashed elsewhere
         partitionable = (not plan.null_aware and threshold is not None
                          and how != "full")
-        # Inner joins are symmetric and the projection below restores
-        # column order, so orient by cost (measured on TPC-H on the CPU
-        # backend). Co-partitioned mode: build the LARGER
+        # Inner joins are symmetric and the join emits its columns in
+        # logical order either way, so orient by cost (measured on TPC-H
+        # on the CPU backend). Co-partitioned mode: build the LARGER
         # side — output capacities ride the probe side, so probing the
         # small side keeps every downstream shape small. Merged mode:
         # build the SMALLER side — the build is concatenated and tabled
@@ -218,21 +218,18 @@ def _create(plan: LogicalPlan, opts: PlannerOptions) -> PhysicalPlan:
             probe = RepartitionExec(
                 probe, n, [ex.ColumnRef(p) for _, p in on]
             )
-            joined: PhysicalPlan = JoinExec(build, probe, on, how,
-                                            null_aware=plan.null_aware,
-                                            partitioned=True)
+            partitioned = True
         else:
             if build.output_partitioning().num_partitions > 1:
                 build = MergeExec(build)
-            joined = JoinExec(build, probe, on, how,
-                              null_aware=plan.null_aware)
-        # restore logical column order if the physical (build-first) order
-        # differs (e.g. preserved-left joins probe the left side)
-        want = plan.schema().names()
-        got = joined.output_schema().names()
-        if want != got:
-            joined = ProjectionExec([ex.ColumnRef(n) for n in want], joined)
-        return joined
+            partitioned = False
+        # the join emits the logical schema itself: the columns the
+        # pruning pass left it (``Join.columns``), in logical order where
+        # the physical (build-first) order differs (a swapped inner join,
+        # a preserved-left join that probes the left side)
+        return JoinExec(build, probe, on, how, null_aware=plan.null_aware,
+                        partitioned=partitioned,
+                        out_columns=plan.schema().names())
 
     if isinstance(plan, EmptyRelation):
         return EmptyExec(plan.produce_one_row)

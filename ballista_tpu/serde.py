@@ -417,6 +417,7 @@ def physical_to_proto(plan) -> pb.PhysicalPlanNode:
         n.join.null_aware = plan.null_aware
         n.join.partitioned = plan.partitioned
         n.join.adaptive_note = plan.adaptive_note or ""
+        n.join.out_columns.extend(plan.out_columns or ())
     elif isinstance(plan, MeshJoinExec):
         n.mesh_join.build_producer.CopyFrom(
             physical_to_proto(plan.build_producer))
@@ -429,6 +430,7 @@ def physical_to_proto(plan) -> pb.PhysicalPlanNode:
         n.mesh_join.how = plan.how
         n.mesh_join.n_devices = plan.n_devices
         n.mesh_join.null_aware = plan.null_aware
+        n.mesh_join.out_columns.extend(plan.out_columns or ())
     elif isinstance(plan, MeshAggExec):
         n.mesh_agg.producer.CopyFrom(physical_to_proto(plan.producer))
         for e in plan.group_exprs:
@@ -522,6 +524,8 @@ def physical_from_proto(n: pb.PhysicalPlanNode):
             null_aware=n.join.null_aware,
             partitioned=n.join.partitioned,
             adaptive_note=n.join.adaptive_note or None,
+            # never empty when set: a join emits at least one column
+            out_columns=tuple(n.join.out_columns) or None,
         )
     if kind == "mesh_join":
         from .physical.mesh_agg import MeshJoinExec as _MeshJoinExec
@@ -533,6 +537,7 @@ def physical_from_proto(n: pb.PhysicalPlanNode):
             n.mesh_join.how,
             n.mesh_join.n_devices,
             null_aware=n.mesh_join.null_aware,
+            out_columns=tuple(n.mesh_join.out_columns) or None,
         )
     if kind == "mesh_agg":
         from .physical.aggregate import DEFAULT_GROUP_CAPACITY

@@ -349,6 +349,15 @@ def apply_handoff(fdp: dp.FileDescriptorProto) -> None:
               F.TYPE_DOUBLE)
 
 
+def apply_join_columns(fdp: dp.FileDescriptorProto) -> None:
+    """PR 42: a physical join carries the columns it emits (mirrored by
+    hand in ballista.proto). Empty = everything, as before the field."""
+    add_field(get_message(fdp, "PhysicalJoinNode"), "out_columns", 8,
+              F.TYPE_STRING, repeated=True)
+    add_field(get_message(fdp, "PhysicalMeshJoinNode"), "out_columns", 7,
+              F.TYPE_STRING, repeated=True)
+
+
 TEMPLATE = '''# -*- coding: utf-8 -*-
 # Generated by dev/gen_proto_patch.py (no protoc in this image). DO NOT EDIT!
 # source: ballista.proto
@@ -386,6 +395,7 @@ def main() -> None:
     apply_admission(fdp)
     apply_controlplane(fdp)
     apply_handoff(fdp)
+    apply_join_columns(fdp)
     out = TEMPLATE.format(blob=fdp.SerializeToString())
     with open(PB2, "w") as f:
         f.write(out)
