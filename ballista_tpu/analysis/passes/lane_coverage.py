@@ -144,6 +144,19 @@ UNMAPPED_ALLOWLIST = {
     # served (resident | filled | streamed); parse and upload time is
     # the ingest spans'
     "scan.serve",
+    # the shuffle data plane at a task's edges (distributed/executor.py
+    # _write_shuffled, physical/shuffle.py _load_group, executor.py
+    # _cleanup_job_outputs): a marker event (dur=0) a task that wrote
+    # shuffled output, with its fan-out, batches and slices (the write's
+    # time is dataplane.write's); the span around a reader's partition
+    # loaded (its producers' files decoded and the upload enqueued),
+    # which runs inside the executor's task window and holds the
+    # shuffle.fetch spans of the pieces that came over the data plane;
+    # and the span around a released or cancelled job's files removed
+    # (a released job's on a thread of its own, after the query ended)
+    "shuffle.write",
+    "shuffle.read",
+    "dataplane.release",
     # whole-stage fusion runs inside the planning phase, which both
     # paths stamp wholesale (client ledger_phase / scheduler stamp)
     "compile.fuse",

@@ -7,6 +7,7 @@ submit -> 100ms GetJobStatus poll -> Flight-fetch every result partition.)
 from __future__ import annotations
 
 import os
+import threading
 import time
 from typing import Dict, Optional
 
@@ -382,7 +383,9 @@ def remote_collect(host: str, port: int, logical_plan,
         cancel_fn=lambda jid, reason: cancel_job(host, port, jid,
                                                  reason))
     _deliver_metrics(result, metrics_out)
-    return _fetch_result_frames(result)
+    frames = _fetch_result_frames(result)
+    release_job(host, port, _job_id)
+    return frames
 
 
 def remote_sql_collect(host: str, port: int, sql: str, catalog,
@@ -402,7 +405,9 @@ def remote_sql_collect(host: str, port: int, sql: str, catalog,
         cancel_fn=lambda jid, reason: cancel_job(host, port, jid,
                                                  reason))
     _deliver_metrics(result, metrics_out)
-    return _fetch_result_frames(result)
+    frames = _fetch_result_frames(result)
+    release_job(host, port, _job_id)
+    return frames
 
 
 def fetch_job_progress(host: str, port: int, job_id: str
@@ -482,6 +487,27 @@ def _deliver_metrics(result: pb.GetJobStatusResult,
         from ..observability.metrics import QueryMetrics
 
         metrics_out.append(QueryMetrics(serde.stage_metrics_from_proto(sm)))
+
+
+def release_job(host: str, port: int, job_id: str) -> None:
+    """Tell the scheduler this client has fetched the job's result, so
+    the executors remove the job's shuffle and result files at their next
+    poll. Best effort and off the caller's thread: a query does not wait
+    for it, and one that is lost leaves the files to the executors'
+    shutdown."""
+    def tell():
+        try:
+            client = SchedulerClient(host, port)
+            try:
+                client.GetJobStatus(pb.GetJobStatusParams(
+                    job_id=job_id, fetched=True))
+            finally:
+                client.close()
+        except Exception:  # noqa: BLE001 - best effort
+            pass
+
+    threading.Thread(target=tell, daemon=True,
+                     name="ballista-release").start()
 
 
 def _fetch_result_frames(result: pb.GetJobStatusResult):

@@ -231,12 +231,22 @@ class CostFeedbackStore:
         # EXPLAIN annotation rides the options into the planner: the
         # Explain branch renders a cost_feedback row from these notes
         changes["cost_notes"] = tuple(notes)
-        opts = dataclasses.replace(opts, **changes)
+        prev, opts = opts, dataclasses.replace(opts, **changes)
         try:
             from ...observability.tracing import trace_event
 
+            # the notes' numbers beside their text, whichever changed
+            moved = {}
+            if "join_partitions" in changes:
+                moved.update(join_partitions_from=prev.join_partitions,
+                             join_partitions=changes["join_partitions"])
+            if "join_partition_threshold" in changes:
+                moved.update(
+                    join_threshold_from=prev.join_partition_threshold,
+                    join_threshold=changes["join_partition_threshold"])
             trace_event("controlplane.costs", digest=digest[:16],
-                        runs=rec.get("runs"), notes="; ".join(notes))
+                        runs=rec.get("runs"), notes="; ".join(notes),
+                        shuffle_bytes=int(shuffle_bytes), **moved)
         except Exception:  # noqa: BLE001 - observability only
             pass
         return opts, notes

@@ -437,14 +437,20 @@ class Executor:
         idle cluster must not turn the ring over with its waits."""
         if seconds <= 0:
             return
+        # the oldest report pending as the wait begins (reports leave
+        # from the front, in order)
+        oldest = next(iter(self._pending_status), None)
         with trace_span("executor.poll_wait",
                         executor=self.id[:8]) as span:
             ended_early = until.wait(seconds)
             late = bool(self._pending_status)
             span.record = self._inflight > 0 or late
-        if late and not ended_early:
-            # a report sat out a wait that no event ended: the report
-            # thread's poll failed (should read 0 a query)
+        if oldest is not None and not ended_early and \
+                next(iter(self._pending_status), None) is oldest:
+            # THAT report sat out a whole wait that no event ended: the
+            # report thread's poll failed (should read 0 a query). One
+            # filed while this wait ran is the report thread's to send,
+            # and is on its way when the wait times out beside it
             trace_event("executor.report_waited", executor=self.id[:8])
 
     def _drop_profile(self, st) -> None:
@@ -569,6 +575,13 @@ class Executor:
             raise
         for job_id in result.cancelled_jobs:
             self._handle_job_cancelled(job_id)
+        if result.released_jobs:
+            # the client has these jobs' results: nothing will read their
+            # files again. Off this thread: the next hand-off must not
+            # wait for the unlinks (1.1 GB a q3 at SF10)
+            threading.Thread(
+                target=self._remove_released, daemon=True,
+                args=(list(result.released_jobs),)).start()
         if result.drain and not self._draining:
             # autoscaler scale-down piggyback: stop accepting work; the
             # poll loop keeps reporting until in-flight tasks finish
@@ -669,11 +682,19 @@ class Executor:
             self._cleaned_jobs.append(job_id)
             self._cleanup_job_outputs(job_id)
 
-    def _cleanup_job_outputs(self, job_id: str):
+    def _cleanup_job_outputs(self, job_id: str, why: str = "cancelled"):
+        """Remove the job's shuffle and result files
+        (``dataplane.release`` a job that had files here)."""
         path = os.path.join(self.config.work_dir, job_id)
         if os.path.isdir(path):
-            shutil.rmtree(path, ignore_errors=True)
-            log.info("removed cancelled job outputs: %s", path)
+            with trace_span("dataplane.release", job=job_id, why=why):
+                shutil.rmtree(path, ignore_errors=True)
+            log.info("removed %s job outputs: %s", why, path)
+
+    def _remove_released(self, job_ids):
+        """Finished jobs whose client has fetched the result."""
+        for job_id in job_ids:
+            self._cleanup_job_outputs(job_id, "released")
 
     # -- task execution (in-process; reference: run_received_tasks) ----------
 
@@ -913,7 +934,10 @@ class Executor:
         write_row = {
             "operator": "ShuffleWrite" if shuffled else "PartitionWrite",
             "depth": 0,
-            "metrics": {"bytes_written": int(stats.get("num_bytes", 0))},
+            "metrics": {"bytes_written": int(stats.get("num_bytes", 0)),
+                        # the shuffle.write event's numbers: counters, so
+                        # a stage's row sums them over its tasks
+                        **stats.get("shuffle_write", {})},
         }
         if write_secs:
             write_row["metrics"]["elapsed_write"] = write_secs
@@ -948,6 +972,7 @@ class Executor:
             writers.append(ipc.PartitionWriter(path, schema=schema))
         totals = {"num_rows": 0, "num_batches": 0, "num_bytes": 0}
         offset = 0
+        produced = 0
         try:
             with trace_span("dataplane.write", task=pid.key(),
                             fan_out=n_out):
@@ -959,6 +984,7 @@ class Executor:
                         writers[q].write_batch(b.with_selection(
                             jnp.logical_and(b.selection, pids == q)))
                     offset += b.num_rows_host()
+                    produced += 1
                 # per-output-partition byte histogram: the signal
                 # adaptive re-planning coalesces/splits the consuming
                 # stage on. Writers that saw no batches (or no rows)
@@ -974,9 +1000,18 @@ class Executor:
                 w.abort()
             raise
         totals["shuffle_partition_bytes"] = qbytes
+        # every produced batch went to ipc.batch_to_arrow once a
+        # destination: slices = batches x fan-out, each a blocking read of
+        # its mask and of every column
+        slices = produced * n_out
+        wrote = {"shuffle_fan_out": n_out, "shuffle_batches": produced,
+                 "shuffle_slices": slices}
+        trace_event("shuffle.write", task=pid.key(), fan_out=n_out,
+                    batches=produced, slices=slices,
+                    rows=totals["num_rows"], bytes=totals["num_bytes"])
         log.info("executed %s (shuffle x%d) in %.1fs (%d rows)", pid.key(),
                  n_out, time.time() - t0, totals["num_rows"])
-        return {**totals, "path": base}
+        return {**totals, "path": base, "shuffle_write": wrote}
 
     def _report_completed(self, pid: PartitionId, stats: dict,
                           stage_version: int = 0, profile=None):

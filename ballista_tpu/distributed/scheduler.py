@@ -1259,6 +1259,8 @@ class SchedulerService:
         # piggyback recently-cancelled job ids: executors abort matching
         # running tasks at batch boundaries and clean partial outputs
         result.cancelled_jobs.extend(self.state.cancelled_job_ids())
+        # and, once an executor, the jobs whose client has its result
+        result.released_jobs.extend(self.state.released_job_ids(meta.id))
         for job_id in jobs_touched:
             self.state.synchronize_job_status(job_id)
         return result
@@ -1376,6 +1378,8 @@ class SchedulerService:
                 trace_event("scheduler.status_woken", job=request.job_id)
         if st is not None and st.state in _TERMINAL:
             self._note_terminal_read(request.job_id)
+        if request.fetched:
+            self.state.release_job(request.job_id)
         result = pb.GetJobStatusResult()
         if st is None:
             result.status.failed.error = f"unknown job {request.job_id}"

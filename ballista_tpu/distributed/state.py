@@ -23,6 +23,7 @@ import pickle
 import sqlite3
 import threading
 import time
+from collections import deque
 from typing import Dict, List, Optional, Tuple
 
 from ..errors import ClusterError
@@ -242,6 +243,12 @@ class SchedulerState:
         # the deadline-scan throttle stamp — all guarded by self._lock
         self._cancelled_jobs: Dict[str, float] = {}
         self._cancels = 0  # jobs cancelled so far (ends held polls)
+        # jobs whose client has fetched the result, numbered as they
+        # come, and per executor the last number it was sent: each id
+        # rides ONE PollWorkResult an executor (release_job)
+        self._released: deque = deque(maxlen=self.RELEASED_KEPT)
+        self._released_n = 0
+        self._released_sent: Dict[str, int] = {}
         self._job_deadlines: Dict[str, float] = {}
         self._last_deadline_scan = 0.0
         # hand-off phases of the latency ledger (observability/ledger.py
@@ -560,6 +567,31 @@ class SchedulerState:
             for j in stale:
                 del self._cancelled_jobs[j]
             return sorted(self._cancelled_jobs)
+
+    # released ids kept for executors that have not polled yet; one that
+    # falls further behind keeps those jobs' files until it shuts down
+    RELEASED_KEPT = 4096
+
+    def release_job(self, job_id: str) -> bool:
+        """The client has fetched this completed job's result: queue the
+        id for every executor's next poll, which removes the job's files
+        (False when the job is unknown or not completed)."""
+        status = self.get_job_status(job_id)
+        if status is None or status.state != "completed":
+            return False
+        with self._lock:
+            self._released_n += 1
+            self._released.append((self._released_n, job_id))
+        return True
+
+    def released_job_ids(self, executor_id: str) -> List[str]:
+        """The released ids this executor has not been sent yet."""
+        with self._lock:
+            sent = self._released_sent.get(executor_id, 0)
+            if sent == self._released_n:
+                return []
+            self._released_sent[executor_id] = self._released_n
+            return [j for n, j in self._released if n > sent]
 
     def save_job_deadline(self, job_id: str, deadline_ts: float):
         """Absolute wall time after which reap_expired_jobs cancels the

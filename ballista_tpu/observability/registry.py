@@ -65,6 +65,11 @@ OPERATOR_METRICS = {
                                  "watermark"),
     "bytes_written": ("counter", "partition/shuffle output bytes"),
     "elapsed_write": ("timer", "partition IPC write time"),
+    "shuffle_fan_out": ("counter", "destinations a shuffling task wrote "
+                                   "to (a stage's row sums its tasks')"),
+    "shuffle_batches": ("counter", "batches a shuffling task produced"),
+    "shuffle_slices": ("counter", "batches x fan-out a shuffling task "
+                                  "handed to the Arrow encoder"),
     "selectivity": ("gauge", "filter pass fraction"),
     "table_cache_hits": ("counter", "partition scans served from the "
                                     "device-resident table cache "

@@ -246,9 +246,23 @@ class ShuffleReaderExec(PhysicalPlan):
                 return self._cache[q]
             from ..io import ipc
             from ..ingest import parallel_map
+            from ..observability.tracing import trace_span
 
-            parts = parallel_map(self._load_location, self._groups[q])
-            batches = ipc.batches_from_parts(self._schema, parts)
+            # decode and the ENQUEUE of the upload: the copy's completion
+            # is not waited for here
+            group = self._groups[q]
+            with trace_span("shuffle.read", pieces=len(group)) as span:
+                parts = parallel_map(self._load_location, group)
+                batches = ipc.batches_from_parts(self._schema, parts)
+                columns = [list(arrays.values()) for arrays, _, _ in parts]
+                span.attrs.update(
+                    rows=sum(len(c[0]) for c in columns if c),
+                    bytes=sum(int(a.nbytes) for c in columns for a in c),
+                    capacity=sum(b.capacity for b in batches),
+                    # pieces that are files of this host, read directly
+                    local=0 if self.FORCE_REMOTE else sum(
+                        1 for l in group
+                        if l.path and os.path.exists(l.path)))
             self._cache[q] = batches
             return batches
 

@@ -358,6 +358,17 @@ def apply_join_columns(fdp: dp.FileDescriptorProto) -> None:
               F.TYPE_STRING, repeated=True)
 
 
+def apply_release(fdp: dp.FileDescriptorProto) -> None:
+    """PR 43: a finished job's files go once its client has fetched the
+    result (mirrored by hand in ballista.proto): the client says so on a
+    last GetJobStatus, and the scheduler passes the id to each executor
+    once, on its next poll."""
+    add_field(get_message(fdp, "GetJobStatusParams"), "fetched", 3,
+              F.TYPE_BOOL)
+    add_field(get_message(fdp, "PollWorkResult"), "released_jobs", 4,
+              F.TYPE_STRING, repeated=True)
+
+
 TEMPLATE = '''# -*- coding: utf-8 -*-
 # Generated by dev/gen_proto_patch.py (no protoc in this image). DO NOT EDIT!
 # source: ballista.proto
@@ -396,6 +407,7 @@ def main() -> None:
     apply_controlplane(fdp)
     apply_handoff(fdp)
     apply_join_columns(fdp)
+    apply_release(fdp)
     out = TEMPLATE.format(blob=fdp.SerializeToString())
     with open(PB2, "w") as f:
         f.write(out)
