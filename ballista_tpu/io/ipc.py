@@ -75,20 +75,16 @@ def _arrow():
     return pa
 
 
-def batch_to_arrow(batch: ColumnBatch):
-    """Compact a ColumnBatch to a pyarrow RecordBatch (live rows only).
-
-    Every D2H fetch (selection, then each column's buffers) runs under
-    a ``device.block`` span, so shuffle-write sync time lands in the
-    profiler's ``device_blocked`` lane instead of hiding inside the
-    write lane. Fetches stay per-column (not one batched hoist): at
-    most ONE full-capacity host copy is live beside the masked
-    outputs, the pre-span memory shape."""
+def _rows_to_arrow(batch: ColumnBatch, rows: np.ndarray):
+    """Rows ``rows`` (an index vector, in the order given) of ``batch``
+    as ONE pyarrow RecordBatch: each column is read once (values and
+    validity under one ``device.block`` span), gathered once and encoded
+    once; field metadata, the registry stamp and a utf8 column's Arrow
+    dictionary are built here, so once a call. At most ONE
+    full-capacity host copy is live beside the gathered columns."""
     pa = _arrow()
     from ..observability.tracing import trace_span
 
-    with trace_span("device.block", site="ipc.batch_to_arrow"):
-        mask = np.asarray(batch.selection)
     arrays = []
     fields = []
     # bounded per-batch column conversion; the chunk loop in
@@ -100,11 +96,11 @@ def batch_to_arrow(batch: ColumnBatch):
             hv = np.asarray(col.values)
             hval = (None if col.validity is None
                     else np.asarray(col.validity))
-        vals = hv[mask]
+        vals = hv.take(rows, axis=0)
         del hv
         nulls = None
         if hval is not None:
-            nulls = ~hval[mask]
+            nulls = ~hval.take(rows)
         del hval
         meta = {b"ballista.kind": f.dtype.kind.encode(),
                 b"ballista.scale": str(f.dtype.scale).encode()}
@@ -119,12 +115,11 @@ def batch_to_arrow(batch: ColumnBatch):
             stamp = columnar_registry.REGISTRY.stamp_of(col.dictionary)
             if stamp is not None:
                 meta[b"ballista.dict"] = stamp.encode()
-            codes = pa.array(vals.astype(np.int32), mask=nulls)
+            codes = pa.array(vals.astype(np.int32, copy=False), mask=nulls)
             dict_vals = pa.array(
                 [str(v) for v in col.dictionary.values], type=pa.string()
             )
             arr = pa.DictionaryArray.from_arrays(codes, dict_vals)
-            fields.append(pa.field(f.name, arr.type, True, meta))
         elif f.dtype.kind == "list":
             # fixed-size list: (rows, length) physical array -> real Arrow
             # FixedSizeListArray (element kind/scale ride in metadata so
@@ -134,13 +129,60 @@ def batch_to_arrow(batch: ColumnBatch):
                 f.dtype.element.scale).encode()
             flat = pa.array(vals.reshape(-1))
             arr = pa.FixedSizeListArray.from_arrays(
-                flat, f.dtype.length, mask=nulls)
-            fields.append(pa.field(f.name, arr.type, True, meta))
+                flat, f.dtype.length,
+                mask=None if nulls is None else pa.array(nulls))
         else:
             arr = pa.array(vals, mask=nulls)
-            fields.append(pa.field(f.name, arr.type, True, meta))
+        fields.append(pa.field(f.name, arr.type, True, meta))
         arrays.append(arr)
     return pa.record_batch(arrays, schema=pa.schema(fields))
+
+
+def batch_to_arrow(batch: ColumnBatch):
+    """Compact a ColumnBatch to a pyarrow RecordBatch (live rows only).
+
+    Every D2H fetch (selection, then each column's buffers) runs under
+    a ``device.block`` span, so shuffle-write sync time lands in the
+    profiler's ``device_blocked`` lane instead of hiding inside the
+    write lane. Fetches stay per-column (not one batched hoist): at
+    most ONE full-capacity host copy is live beside the compacted
+    outputs, the pre-span memory shape. 1 + columns blocking reads."""
+    from ..observability.tracing import trace_span
+
+    with trace_span("device.block", site="ipc.batch_to_arrow"):
+        mask = np.asarray(batch.selection)
+    return _rows_to_arrow(batch, np.flatnonzero(mask))
+
+
+def partition_to_arrow(batch: ColumnBatch, dest, n_out: int):
+    """``batch`` split ``n_out`` ways in ONE pass: ``n_out`` record
+    batches, record batch ``q`` equal to
+    ``batch_to_arrow(batch.with_selection(selection & (pids == q)))``.
+
+    ``dest`` is the batch's destination vector, a device array of its
+    capacity: a live row's destination, ``n_out`` for a dead row (the
+    executor's ``shuffle.dest`` program). One blocking read of ``dest``
+    and one of each column (:func:`_rows_to_arrow`), so 1 + columns
+    whatever ``n_out`` is; ONE permutation shared by all columns, a STABLE sort of ``dest``
+    (numpy's radix path for the one- and two-byte types), so the rows of
+    a destination keep the batch's order as a mask would keep it; rows
+    a destination counted from the same vector. Every column is
+    gathered once over the live rows and destination ``q`` is the
+    contiguous slice ``[start_q, start_q + count_q)`` of the one record
+    batch, sharing its buffers (the IPC writer truncates a slice to its
+    window, as it does for ``_iter_chunked``'s). Host memory: one
+    column's full-capacity copy plus ONE batch's gathered columns, held
+    until the caller has written the last slice."""
+    from ..observability.tracing import trace_span
+
+    with trace_span("device.block", site="ipc.batch_to_arrow", n_out=n_out):
+        dest = np.asarray(dest)
+    counts = np.bincount(dest, minlength=n_out + 1)[:n_out]
+    ends = np.cumsum(counts)
+    live = int(ends[-1])
+    whole = _rows_to_arrow(batch, np.argsort(dest, kind="stable")[:live])
+    return [whole.slice(int(end - n), int(n))
+            for end, n in zip(ends, counts)]
 
 
 def _iter_chunked(rb, chunk_bytes: int):

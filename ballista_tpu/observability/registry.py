@@ -68,8 +68,12 @@ OPERATOR_METRICS = {
     "shuffle_fan_out": ("counter", "destinations a shuffling task wrote "
                                    "to (a stage's row sums its tasks')"),
     "shuffle_batches": ("counter", "batches a shuffling task produced"),
-    "shuffle_slices": ("counter", "batches x fan-out a shuffling task "
-                                  "handed to the Arrow encoder"),
+    "shuffle_slices": ("counter", "batches x fan-out: the record "
+                                  "batches a shuffling task wrote, "
+                                  "before chunking"),
+    "shuffle_reads": ("counter", "blocking device-to-host reads a "
+                                 "shuffling task's write made (1 + "
+                                 "columns a batch)"),
     "selectivity": ("gauge", "filter pass fraction"),
     "table_cache_hits": ("counter", "partition scans served from the "
                                     "device-resident table cache "

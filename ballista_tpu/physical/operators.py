@@ -19,7 +19,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..columnar import Column, ColumnBatch
-from ..compile import bucket_capacity, fingerprint
+from ..compile import bucket_capacity, fingerprint, governed
 from ..datatypes import Schema
 from ..errors import ExecutionError, NotImplementedError_
 from .. import expr as ex
@@ -53,6 +53,33 @@ def compute_partition_ids(batch: ColumnBatch, hash_exprs, num_partitions: int,
         return (h % jnp.uint64(num_partitions)).astype(jnp.int32)
     idx = row_offset + jnp.arange(batch.capacity, dtype=jnp.int32)
     return idx % num_partitions
+
+
+def shuffle_dest_program(schema: Schema, hash_exprs, num_partitions: int):
+    """The governed program behind a shuffle write's ONE device step a
+    batch: ``dest(batch, num_partitions, row_offset)`` is every row's
+    destination as :func:`compute_partition_ids` gives it, with
+    ``num_partitions`` for a dead row (dead rows sort last), in the
+    narrowest unsigned type that holds ``num_partitions`` (one byte up
+    to 255: the host's stable sort of it is a radix pass). Elementwise
+    only. ``num_partitions`` and ``row_offset`` are operands, so one
+    program a (schema, hash expressions, width) serves every fan-out the
+    cost feedback moves through; jax specializes it a capacity."""
+    dtype = next(t for t in (jnp.uint8, jnp.uint16, jnp.uint32)
+                 if num_partitions <= jnp.iinfo(t).max)
+
+    def build():
+        ev = Evaluator(schema)
+
+        def dest(batch: ColumnBatch, n_out, row_offset):
+            pids = compute_partition_ids(batch, hash_exprs, n_out,
+                                         row_offset, ev)
+            return jnp.where(batch.selection, pids, n_out).astype(dtype)
+
+        return dest
+
+    return governed(("shuffle.dest", schema, fingerprint(hash_exprs),
+                     jnp.dtype(dtype).name), build)
 
 
 class ScanExec(PhysicalPlan):

@@ -11,7 +11,8 @@ coalesces the first join's readers. Every execution equals the
 benchmark's plain reference under the queries' own limits, and the spans say
 what a served query pays every time: every build made, none reused; one
 ``shuffle.write`` a shuffling task with ``slices`` = batches x fan-out; a
-blocking read of the mask and of every column for each slice; every scan
+blocking read of the destination vector and of every column for each batch,
+whatever the fan-out; every scan
 served from the device; and the finished jobs' files gone once the client
 has their results."""
 
@@ -248,10 +249,11 @@ def test_one_shuffle_write_a_shuffling_task_and_the_row_sums_them(served):
         sum(e["fan_out"] for e in events)
 
 
-def test_a_slice_costs_a_read_of_its_mask_and_one_of_each_column(served):
-    """reads = batches x fan-out x (1 + columns), by task; what is over is
-    the one empty batch a writer that saw no row closes with (a read of
-    its mask and of each column again), under 5% here."""
+def test_a_batch_costs_a_read_of_its_destinations_and_one_of_each_column(
+        served):
+    """reads = batches x (1 + columns), by task, whatever the fan-out: the
+    one-pass write (ISSUE 44) reads a batch's destination vector and each
+    of its columns once, and the event's ``reads`` is the spans' count."""
     _, runs, _ = served
     run = runs["q3"][-1]
     blocked = [r for r in run["events"] if r["name"] == "device.block"
@@ -262,13 +264,19 @@ def test_a_slice_costs_a_read_of_its_mask_and_one_of_each_column(served):
     for event in writes:
         mine = [r for r in blocked if r.get("task") == event["task"]]
         columns = len({r["col"] for r in mine if "col" in r})
-        want = event["slices"] * (1 + columns)
-        assert want <= len(mine) <= 1.05 * want + (1 + columns), \
-            (event, len(mine), columns)
-    # a query's reads, nearly all of them the shuffle writers'
+        assert event["fan_out"] > 8 and event["batches"]
+        assert len(mine) == event["reads"] == \
+            event["batches"] * (1 + columns), (event, len(mine), columns)
+        assert event["slices"] == event["batches"] * event["fan_out"]
+    rows = [op["metrics"] for st in run["stages"].values()
+            for op in st["operators"] if op["operator"] == "ShuffleWrite"]
+    assert sum(m["shuffle_reads"] for m in rows) == \
+        sum(e["reads"] for e in writes)
+    # a query's reads, most of them the shuffle writers' (the stages that
+    # write ONE partition, unshuffled, read a mask and each column too)
     shuffling = {e["task"] for e in writes}
     theirs = sum(1 for r in blocked if r.get("task") in shuffling)
-    assert theirs > 0.95 * len(blocked)
+    assert theirs > 0.5 * len(blocked)
 
 
 def test_no_report_sat_out_a_wait(served):

@@ -396,3 +396,39 @@ def test_aggregate_over_a_partition_of_five_batches(one_chip, no_disk_cache,
     mem = compiled.memory_analysis()
     assert mem.argument_size_in_bytes > 100e6  # the five pieces themselves
     assert mem.temp_size_in_bytes + mem.argument_size_in_bytes < 1e9
+
+
+@pytest.mark.parametrize("keys", [("l_orderkey",), ("c_mktsegment",), ()],
+                         ids=["hash_int64", "hash_utf8", "round_robin"])
+def test_shuffle_dest_at_1m_rows_is_elementwise(one_chip, no_disk_cache,
+                                                keys):
+    """The shuffle write's one device step a batch since PR 44
+    (``physical/operators.py`` ``shuffle_dest_program``): every row's
+    destination, at a served scan's 1<<20-row batch, with the fan-out
+    and the round-robin offset as operands. Elementwise only: no sort,
+    no scatter, no loop over the capacity (a ``cumsum`` over 1<<20 rows
+    is 22-33 s of compiling, PERF.md PR 29), one byte a row out."""
+    from ballista_tpu import Date32, Decimal, Int64, Utf8, col, schema
+    from ballista_tpu.columnar import ColumnBatch, Dictionary
+    from ballista_tpu.physical.operators import shuffle_dest_program
+
+    s = schema(("l_orderkey", Int64), ("l_extendedprice", Decimal(2)),
+               ("l_discount", Decimal(2)), ("c_mktsegment", Utf8),
+               ("l_shipdate", Date32))
+    n = 64
+    small = ColumnBatch.from_numpy(
+        s, {"l_orderkey": np.arange(n), "l_extendedprice": np.full(n, 9),
+            "l_discount": np.full(n, 5),
+            "c_mktsegment": np.arange(n, dtype=np.int32) % 3,
+            "l_shipdate": np.arange(n, dtype=np.int32) + 9000},
+        {"c_mktsegment": Dictionary(["AUTOMOBILE", "BUILDING", "MACHINERY"])})
+    fn = shuffle_dest_program(s, [col(k) for k in keys], 17)
+    batch = jax.tree_util.tree_map(
+        lambda x: jax.ShapeDtypeStruct((1 << 20,) if x.ndim else (), x.dtype,
+                                       sharding=one_chip), small)
+    scalar = jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip)
+    compiled = getattr(fn, "gf", fn).fn.lower(batch, scalar, scalar).compile()
+    text = compiled.as_text()
+    assert " sort(" not in text and " scatter(" not in text
+    assert " while(" not in text
+    assert compiled.memory_analysis().output_size_in_bytes == 1 << 20
