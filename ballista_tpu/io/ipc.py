@@ -29,11 +29,12 @@ import io as _io
 import os
 import threading
 import time
-from typing import Dict, Iterable, List, Optional, Tuple
+from typing import Dict, Iterable, List, NamedTuple, Optional, Tuple
 
 import numpy as np
 
-from ..columnar import Column, ColumnBatch, Dictionary
+from ..columnar import (
+    DEFAULT_BATCH_CAPACITY, Column, ColumnBatch, Dictionary)
 from ..compile import bucket_capacity
 from ..datatypes import Field, Schema
 from ..errors import IoError
@@ -413,6 +414,29 @@ def _norm_stat(v):
     return v
 
 
+def _fixed_width_view(arr) -> np.ndarray:
+    """An Arrow integer/float array without nulls as a zero-copy numpy
+    view of its data buffer (which the view keeps alive), made WITHOUT
+    leaving the GIL. ``Array.to_numpy`` gives the same view but drops
+    the GIL for it, and a thread that drops the GIL takes it back behind
+    every other thread that runs Python (up to the 5 ms switch interval
+    each): beside three other tasks that was 84% of a shuffle read
+    (PERF.md, PR 45), once a column of every record batch. Anything else
+    (booleans are bit-packed; a column with nulls) goes through
+    ``to_numpy``."""
+    pa = _arrow()
+    t = arr.type
+    if arr.null_count or not (pa.types.is_integer(t)
+                              or pa.types.is_floating(t)):
+        return arr.to_numpy(zero_copy_only=False)
+    dtype = np.dtype(t.to_pandas_dtype())
+    data = arr.buffers()[1]
+    if data is None or not len(arr):
+        return np.zeros(0, dtype=dtype)
+    return np.frombuffer(data, dtype=dtype, count=len(arr),
+                         offset=arr.offset * dtype.itemsize)
+
+
 def decode_fixed_size_list(chunk) -> np.ndarray:
     """FixedSizeListArray chunk -> (rows, width) ndarray of flat values.
 
@@ -424,10 +448,20 @@ def decode_fixed_size_list(chunk) -> np.ndarray:
     slices); the offset handling protects direct/zero-copy producers.
     """
     width = chunk.type.list_size
-    flat = chunk.values.to_numpy(zero_copy_only=False)
+    flat = _fixed_width_view(chunk.values)
     off = chunk.offset
     flat = flat[off * width:(off + len(chunk)) * width]
     return flat.reshape(len(chunk), width)
+
+
+def _same_buffers(a, b) -> bool:
+    """Two Arrow arrays over the very same memory (a stream reader hands
+    every record batch its dictionary in a new wrapper): equal without
+    ``equals``, which compares the values outside the GIL."""
+    if len(a) != len(b) or a.offset != b.offset:
+        return False
+    return [x and x.address for x in a.buffers()] == \
+        [x and x.address for x in b.buffers()]
 
 
 class _ChunkStream(_io.RawIOBase):
@@ -480,6 +514,48 @@ def open_arrow_reader(source):
     return pa.ipc.open_stream(src)
 
 
+class FilePieces(NamedTuple):
+    """One shuffle file decoded WITHOUT assembling it: a column's values
+    stay one numpy array a record batch, zero-copy views over the file's
+    (memory-mapped) buffers wherever Arrow allows (fixed-width, no
+    nulls). ``nulls[name][i]`` is record batch ``i``'s null mask, or
+    ``None`` where Arrow's own ``null_count`` says it has none. utf8
+    columns hold codes valid in ``dicts[name]``, the file's one
+    dictionary."""
+
+    names: List[str]
+    rows: int
+    #: bytes of the decoded values, all columns
+    nbytes: int
+    values: Dict[str, List[np.ndarray]]
+    nulls: Dict[str, List[Optional[np.ndarray]]]
+    dicts: Dict[str, "Dictionary | np.ndarray"]
+    kinds: Dict[str, Tuple[str, int]]
+    #: (dtype, row shape) a column, for a file that holds no record batch
+    empties: Dict[str, Tuple[np.dtype, Tuple[int, ...]]]
+
+
+def read_partition_pieces(path_or_buf) -> FilePieces:
+    """Decode an IPC partition to per-record-batch views
+    (:class:`FilePieces`); accepts both IPC layouts (see
+    :func:`open_arrow_reader`). The shuffle reader's entry: it places
+    the pieces of a whole group once (:func:`batches_from_pieces`)."""
+    in_memory = isinstance(path_or_buf, (str, os.PathLike, bytes,
+                                         bytearray, memoryview))
+    return _decode_pieces(open_arrow_reader(path_or_buf), in_memory)
+
+
+def read_partition_pieces_from_chunks(chunks: Iterable[bytes]) -> FilePieces:
+    """:func:`read_partition_pieces` fed by an iterator of stream-format
+    byte chunks (the flow-controlled data plane fetch, or a ChunkBuffer
+    replay spanning RAM + spill files). Chunks are pulled — and can be
+    released by the producer — as the decoder advances; a truncated
+    stream raises pyarrow's invalid-IPC error, which shuffle readers tag
+    into ShuffleFetchError."""
+    pa = _arrow()
+    return _decode_pieces(pa.ipc.open_stream(_ChunkStream(chunks)))
+
+
 def read_partition_arrays(
     path_or_buf,
 ) -> Tuple[List[str], Dict[str, np.ndarray], Dict[str, np.ndarray],
@@ -493,29 +569,39 @@ def read_partition_arrays(
     incremental per record batch, so a memory-mapped stream file never
     materializes its wire bytes as one blob.
     """
-    return _decode_reader(open_arrow_reader(path_or_buf))
+    return _whole_arrays(read_partition_pieces(path_or_buf))
 
 
 def read_partition_arrays_from_chunks(chunks: Iterable[bytes]):
-    """Incremental variant of :func:`read_partition_arrays` fed by an
-    iterator of stream-format byte chunks (the flow-controlled data
-    plane fetch, or a ChunkBuffer replay spanning RAM + spill files).
-    Chunks are pulled — and can be released by the producer — as the
-    decoder advances; a truncated stream raises pyarrow's invalid-IPC
-    error, which shuffle readers tag into ShuffleFetchError."""
-    pa = _arrow()
-    return _decode_reader(pa.ipc.open_stream(_ChunkStream(chunks)))
+    """:func:`read_partition_arrays` over
+    :func:`read_partition_pieces_from_chunks`."""
+    return _whole_arrays(read_partition_pieces_from_chunks(chunks))
 
 
-def _batch_iter(reader):
+def _batch_iter(reader, in_memory: bool = False):
+    """The reader's record batches, each as the list of its columns, the
+    thread's cancel token checked before each (per-record-batch
+    cancellation at the producer, so every consumer of this iterator
+    inherits it). ``in_memory``: the source is a memory map or a buffer,
+    where ``read_all`` copies nothing and leaves the GIL once a FILE;
+    ``read_next_batch`` leaves it once a record batch (see
+    :func:`_fixed_width_view` for what that costs). A stream of wire
+    chunks is pulled batch by batch, so its chunks can be released as
+    the decoder advances."""
     from ..lifecycle import check_cancel
 
+    if in_memory:
+        # chunk k of every column is record batch k (an empty one too,
+        # which ``Table.to_batches`` would drop with its dictionary)
+        columns = reader.read_all().columns
+        for k in range(columns[0].num_chunks if columns else 0):
+            check_cancel()
+            yield [c.chunk(k) for c in columns]
+        return
     if hasattr(reader, "num_record_batches"):  # legacy FILE format
         for i in range(reader.num_record_batches):
-            # per-record-batch cancellation at the producer, so every
-            # consumer of this iterator inherits it
             check_cancel()
-            yield reader.get_batch(i)
+            yield reader.get_batch(i).columns
         return
     while True:
         check_cancel()
@@ -523,130 +609,139 @@ def _batch_iter(reader):
             rb = reader.read_next_batch()
         except StopIteration:
             return
-        yield rb
+        yield rb.columns
 
 
-def _decode_reader(reader):
-    """Shared incremental decode core: accumulate per-record-batch
-    numpy pieces (checking the thread's cancel token at every batch
-    boundary) and concatenate once — peak host memory is the decoded
-    arrays plus ONE batch's wire window, never decoded + whole blob."""
+def _decode_pieces(reader, in_memory: bool = False) -> FilePieces:
+    """The one decode core: per-record-batch numpy pieces (checking the
+    thread's cancel token at every batch boundary), nothing concatenated.
+    A column of a record batch whose ``null_count`` is 0 costs no mask
+    and, for the fixed-width types, no copy: its piece is a view of the
+    reader's buffer, which the view keeps alive."""
     pa = _arrow()
     from ..lifecycle import check_cancel
 
     schema = reader.schema
     names = list(schema.names)
     metas = [schema.field(i).metadata or {} for i in range(len(names))]
-    pieces: Dict[str, List[np.ndarray]] = {n: [] for n in names}
-    null_pieces: Dict[str, List[np.ndarray]] = {n: [] for n in names}
+    values: Dict[str, List[np.ndarray]] = {n: [] for n in names}
+    nulls: Dict[str, List[Optional[np.ndarray]]] = {n: [] for n in names}
     # utf8 columns: per-batch (codes, dictionary) with replacement
     # detection — a stream is allowed to swap dictionaries mid-flight
     dict_state: Dict[str, dict] = {}
-    n_batches = 0
-    for rb in _batch_iter(reader):
+    rows = 0
+    for columns in _batch_iter(reader, in_memory):
         # chunk-level cancellation: ctx.cancel()/deadlines abort
         # mid-partition decodes (local mmap reads included)
         check_cancel()
-        n_batches += 1
-        for i, name in enumerate(names):
-            col = rb.column(i)
+        rows += len(columns[0]) if columns else 0
+        for name, col in zip(names, columns):
+            nm = None
             if pa.types.is_dictionary(col.type):
-                codes = col.indices.to_numpy(
-                    zero_copy_only=False).astype(np.int32)
-                nm = np.asarray(col.indices.is_null())
+                idx = col.indices
+                if idx.null_count:
+                    nm = np.asarray(idx.is_null())
+                    codes = np.where(nm, 0, idx.fill_null(0).to_numpy(
+                        zero_copy_only=False)).astype(np.int32)
+                else:
+                    codes = _fixed_width_view(idx).astype(
+                        np.int32, copy=False)
                 st = dict_state.setdefault(
                     name, {"first": col.dictionary, "multi": False,
-                           "parts": []})
+                           "dicts": []})
                 if not st["multi"] and not (
-                        col.dictionary is st["first"]
+                        _same_buffers(col.dictionary, st["first"])
                         or col.dictionary.equals(st["first"])):
                     st["multi"] = True
-                zeroed = np.where(nm, 0, codes).astype(np.int32)
-                # dict columns assemble from st["parts"] alone (see
-                # _finish_dict_column); pieces[name] stays unused
-                st["parts"].append((zeroed, col.dictionary))
+                st["dicts"].append(col.dictionary)
+                values[name].append(codes)
             elif pa.types.is_fixed_size_list(col.type):
-                nm = np.asarray(col.is_null())
-                pieces[name].append(decode_fixed_size_list(col))
+                if col.null_count:
+                    nm = np.asarray(col.is_null())
+                values[name].append(decode_fixed_size_list(col))
+            elif not col.null_count:
+                values[name].append(_fixed_width_view(col))
             else:
                 nm = np.asarray(col.is_null())
                 if pa.types.is_integer(col.type):
                     # stay in integer domain: to_numpy on a nullable int
                     # array converts to float64, corrupting scaled-
-                    # decimal/int64 values above 2^53; fill_null copies,
-                    # so only when needed
-                    src = col.fill_null(0) if nm.any() else col
-                    pieces[name].append(src.to_numpy(zero_copy_only=False))
+                    # decimal/int64 values above 2^53
+                    values[name].append(
+                        col.fill_null(0).to_numpy(zero_copy_only=False))
                 else:
                     vals = col.to_numpy(zero_copy_only=False)
-                    if nm.any():
-                        vals = np.where(nm, 0, np.nan_to_num(vals))
-                    pieces[name].append(vals)
-            null_pieces[name].append(nm)
+                    values[name].append(
+                        np.where(nm, 0, np.nan_to_num(vals)))
+            nulls[name].append(nm)
 
-    arrays: Dict[str, np.ndarray] = {}
-    nulls: Dict[str, np.ndarray] = {}
     dicts: Dict[str, np.ndarray] = {}
     kinds: Dict[str, Tuple[str, int]] = {}
+    empties: Dict[str, Tuple[np.dtype, Tuple[int, ...]]] = {}
     for i, name in enumerate(names):
         meta = metas[i]
         kind = meta.get(b"ballista.kind", b"").decode() or None
         scale = int(meta.get(b"ballista.scale", b"0") or 0)
         ftype = schema.field(i).type
         if pa.types.is_dictionary(ftype):
-            arrays[name], dicts[name] = _finish_dict_column(
-                name, dict_state.get(name), meta)
+            values[name], dicts[name] = _finish_dict_column(
+                values[name], dict_state.get(name), meta)
             kinds[name] = ("utf8", 0)
+            empties[name] = (np.dtype(np.int32), ())
         elif pa.types.is_fixed_size_list(ftype):
-            width = ftype.list_size
-            edtype = np.dtype(ftype.value_type.to_pandas_dtype())
-            arrays[name] = (
-                np.concatenate(pieces[name])
-                if pieces[name] else np.zeros((0, width), dtype=edtype))
             ekind = (meta.get(b"ballista.element_kind", b"").decode()
                      or str(ftype.value_type))
             escale = int(meta.get(b"ballista.element_scale", b"0") or 0)
             kinds[name] = (f"list:{ekind}", escale)
+            empties[name] = (np.dtype(ftype.value_type.to_pandas_dtype()),
+                             (ftype.list_size,))
         else:
-            arrays[name] = _concat_pieces(pieces[name], ftype)
             kinds[name] = (kind or str(ftype), scale)
-        nps = null_pieces[name]
+            empties[name] = (np.dtype(ftype.to_pandas_dtype()), ())
+    nbytes = sum(int(a.nbytes) for ps in values.values() for a in ps)
+    return FilePieces(names, rows, nbytes, values, nulls, dicts, kinds,
+                      empties)
+
+
+def _whole_arrays(fp: FilePieces):
+    """:class:`FilePieces` laid end to end a column: the
+    ``(names, arrays, null_masks, dictionaries, kinds)`` of
+    :func:`read_partition_arrays` (result fetch, tests). A piece without
+    a mask reads as all-valid."""
+    arrays: Dict[str, np.ndarray] = {}
+    nulls: Dict[str, np.ndarray] = {}
+    for name in fp.names:
+        ps = fp.values[name]
+        dtype, tail = fp.empties[name]
+        arrays[name] = (ps[0] if len(ps) == 1
+                        else np.concatenate(ps) if ps
+                        else np.zeros((0,) + tail, dtype=dtype))
+        nps = [nm if nm is not None else np.zeros(len(p), dtype=bool)
+               for p, nm in zip(ps, fp.nulls[name])]
         nulls[name] = (nps[0] if len(nps) == 1
                        else np.concatenate(nps) if nps
                        else np.zeros(0, dtype=bool))
-    return names, arrays, nulls, dicts, kinds
+    return fp.names, arrays, nulls, fp.dicts, fp.kinds
 
 
-def _concat_pieces(ps: List[np.ndarray], ftype) -> np.ndarray:
-    if len(ps) == 1:
-        return ps[0]
-    if not ps:
-        return np.zeros(0, dtype=np.dtype(ftype.to_pandas_dtype()))
-    return np.concatenate(ps)
-
-
-def _finish_dict_column(name: str, st: Optional[dict], meta: dict):
-    """Assemble one utf8 column from its per-batch (codes, dictionary)
-    pieces. Single-dictionary streams (the writers' contract) resolve
-    the registry stamp or adopt the values once, exactly like the old
-    whole-table path; replacement dictionaries remap every batch onto
-    the registry's sorted union before concatenating."""
+def _finish_dict_column(codes: List[np.ndarray], st: Optional[dict],
+                        meta: dict):
+    """One utf8 column of a file: its per-record-batch codes and the ONE
+    dictionary they are valid in. Single-dictionary streams (the
+    writers' contract) resolve the registry stamp or adopt the values
+    once and keep their codes as decoded; replacement dictionaries remap
+    every batch onto the registry's sorted union."""
     from .. import columnar_registry as _reg
 
-    if st is None or not st["parts"]:
-        return np.zeros(0, dtype=np.int32), np.asarray([], dtype=object)
+    if st is None or not codes:
+        return [], np.asarray([], dtype=object)
     if st["multi"]:
         parts = [
-            (codes, np.asarray(d.to_pylist(), dtype=object))
-            for codes, d in st["parts"]
+            (c, np.asarray(d.to_pylist(), dtype=object))
+            for c, d in zip(codes, st["dicts"])
         ]
         unified, remapped = _reg.unify_parts(parts)
-        codes = (remapped[0] if len(remapped) == 1
-                 else np.concatenate(remapped)).astype(np.int32)
-        return codes, unified
-    codes_list = [codes for codes, _ in st["parts"]]
-    codes = (codes_list[0] if len(codes_list) == 1
-             else np.concatenate(codes_list))
+        return [r.astype(np.int32, copy=False) for r in remapped], unified
     # a registry stamp resolves to the live interned Dictionary
     # (content-verified by epoch) without touching the shipped values;
     # otherwise adopt them once per content epoch so every part/read of
@@ -664,94 +759,161 @@ def _finish_dict_column(name: str, st: Optional[dict], meta: dict):
 
 
 def unify_dictionaries(
-    parts: List[Tuple[np.ndarray, "Dictionary | np.ndarray"]]
-) -> Tuple[Dictionary, List[np.ndarray]]:
-    """[(codes, Dictionary-or-raw-values)] from several producers ->
-    (shared Dictionary, remapped codes per part). Sorted union keeps
-    codes ordinal. Routed through the dictionary registry: producers
-    of one table resolve to ONE interned instance (no remap at all),
-    version chains remap through cached integer tables, and only
-    unregistered content pays a (cached) sorted union."""
+    dicts: List["Dictionary | np.ndarray"]
+) -> Tuple[Dictionary, List[Optional[np.ndarray]]]:
+    """The dictionaries of several producers' files (Dictionary, or raw
+    values from a legacy file) -> (shared Dictionary, one int32 remap
+    table a file, ``None`` where the file's codes are already valid in
+    it). Sorted union keeps codes ordinal. Routed through the dictionary
+    registry: producers of one table resolve to ONE interned instance
+    (no remap at all), version chains remap through cached integer
+    tables, and only unregistered content pays a (cached) sorted
+    union."""
     from ..observability.tracing import trace_span
-    from .. import columnar_registry
+    from .. import columnar_registry as _reg
 
-    if not parts:
+    if not dicts:
         return Dictionary([]), []
-    with trace_span("host.dictionary", site="ipc.unify", n_parts=len(parts)):
-        return columnar_registry.unify_parts(parts)
+    with trace_span("host.dictionary", site="ipc.unify", n_parts=len(dicts)):
+        # raw value arrays are adopted first so equal producers still
+        # collapse to one instance; registry off, the legacy union
+        # inside ``unify`` takes plain Dictionaries
+        target, remaps = _reg.unify([
+            d if isinstance(d, Dictionary)
+            else _reg.REGISTRY.adopt(None, d) if _reg.enabled()
+            else Dictionary(d)
+            for d in dicts])
+        return (target if target is not None else Dictionary([])), remaps
 
 
-def batches_from_parts(
-    schema: Schema,
-    parts: List[Tuple[Dict[str, np.ndarray], Dict[str, np.ndarray],
-                      Dict[str, np.ndarray]]],
-    capacity: Optional[int] = None,
-) -> List[ColumnBatch]:
-    """Assemble ColumnBatches from several read_partition_arrays results
-    (arrays, nulls, dicts per part), unioning utf8 dictionaries."""
-    import jax.numpy as jnp
+# a copy up to this size is made holding the GIL (some 0.1 ms)
+_COPY_UNDER_GIL_BYTES = 1 << 20
 
+
+def _copy_rows(dst: np.ndarray, src: np.ndarray) -> None:
+    """``dst[...] = src`` for two arrays of one shape. numpy leaves the
+    GIL for a copy, and a task thread takes it back behind every other
+    thread that runs Python (see :func:`_fixed_width_view`): a record
+    batch's column is some 100 KB, so where no cast is needed it is
+    copied as bytes under the GIL, which a memoryview assignment is."""
+    if (src.dtype == dst.dtype and src.nbytes <= _COPY_UNDER_GIL_BYTES
+            and src.flags.c_contiguous and dst.flags.c_contiguous):
+        if src.nbytes:
+            dst.data.cast("B")[:] = src.data.cast("B")
+    else:
+        dst[...] = src
+
+
+def _fill(bufs: List[np.ndarray], sizes: List[int],
+          pieces: List[np.ndarray]) -> None:
+    """``pieces`` laid end to end fill ``bufs[b][:sizes[b]]``, batch
+    after batch: each piece copied once, cut where a batch ends."""
+    b, at = 0, 0
+    # a copy of at most a record batch's column a turn; the caller's
+    # column loop checks the token
+    # ballista: ignore[cancel-coverage]
+    for piece in pieces:
+        # ballista: ignore[cancel-coverage]
+        while len(piece):
+            take = min(len(piece), sizes[b] - at)
+            if take:
+                _copy_rows(bufs[b][at:at + take], piece[:take])
+                piece, at = piece[take:], at + take
+            else:   # the batch is full: the piece goes on in the next
+                b, at = b + 1, 0
+
+
+def batches_from_pieces(
+    schema: Schema, files: List[FilePieces],
+) -> Tuple[List[ColumnBatch], int]:
+    """A shuffle group's files (:func:`read_partition_pieces`, producer
+    order) -> (its ColumnBatches, the arrays uploaded for them).
+
+    ONE pass: the group's rows, file after file and row after row, are
+    cut into batches of at most ``DEFAULT_BATCH_CAPACITY`` rows (what a
+    scan cuts at: no operator sees a larger shape from a reader than
+    from a scan), the last at its ladder rung (at least half a batch
+    where there are several); every batch column is ONE
+    buffer of the batch's capacity in the device dtype, each piece
+    copied (and cast, where the file's type differs) straight into its
+    place, utf8 codes through their file's remap table onto the group's
+    one dictionary; then every array of the group goes to the device in
+    ONE ``jax.device_put``. A group with pieces but no rows is one empty
+    batch, so the schema and its dictionaries still travel."""
+    import jax
+
+    from ..lifecycle import check_cancel
     from ..observability.memory import track_host_bytes
 
-    if not parts:
-        return []
+    if not files:
+        return [], 0
+    CAP = DEFAULT_BATCH_CAPACITY
+    rows = sum(fp.rows for fp in files)
+    # rows a batch, and each batch's capacity: full batches at CAP, the
+    # rest at the rung shuffle reads have always entered at. The rest of
+    # a group of SEVERAL batches takes at least half a batch: what is
+    # laid end to end downstream (a join's build) then has k or k + 1/2
+    # batches of slots and no other size, and every size is a program
+    # to compile (a ``lax.sort`` 17-190 s each; PERF.md, PR 45: the
+    # served SF10 joins keep the five sort capacities they had)
+    sizes = [min(CAP, rows - lo) for lo in range(0, rows, CAP)] or [0]
+    least = CAP // 2 if len(sizes) > 1 else 1
+    caps = [n if n == CAP else min(bucket_capacity(max(n, least)), CAP)
+            for n in sizes]
+
     # shuffle-read host buffers: transient, but the peak matters — the
     # memory plane attributes them separately from scan parse buffers
-    shuffle_bytes = sum(
-        int(getattr(a, "nbytes", 0))
-        for arrays, _nulls, _dicts in parts for a in arrays.values()
-    )
-    with track_host_bytes("shuffle", shuffle_bytes):
-        return _batches_from_parts_inner(schema, parts, capacity, jnp)
-
-
-def _batches_from_parts_inner(schema, parts, capacity, jnp):
-    # union dictionaries per utf8 column — split from batches_from_parts
-    # only so the shuffle-byte accounting brackets the whole assembly
-    from ..lifecycle import check_cancel
-
-    union_dicts: Dict[str, Dictionary] = {}
-    remaps: Dict[str, List[np.ndarray]] = {}
-    for f in schema.fields:
-        if f.dtype.kind == "utf8":
-            pieces = [(p[0][f.name], p[2][f.name]) for p in parts]
-            d, remapped = unify_dictionaries(pieces)
-            union_dicts[f.name] = d
-            remaps[f.name] = remapped
-    out = []
-    for pi, (arrays, nulls, dicts) in enumerate(parts):
-        # per-part cancellation: assembly pads + uploads every part
-        # (H2D), real work a fired token must be able to stop
-        check_cancel()
-        n = len(next(iter(arrays.values()))) if arrays else 0
-        # shuffle-read batches enter at canonical ladder capacities:
-        # unevenly-sized shuffle partitions share compiled signatures
-        cap = capacity or bucket_capacity(max(n, 1))
-        cols = []
+    with track_host_bytes("shuffle", sum(fp.nbytes for fp in files)):
+        # a column: (a buffer a batch, a validity a batch or None)
+        placed: List[Tuple[List[np.ndarray],
+                           Optional[List[np.ndarray]]]] = []
+        dicts: List[Optional[Dictionary]] = []
         for f in schema.fields:
+            dtype = f.dtype.device_dtype()
+            tail = (f.dtype.length,) if f.dtype.kind == "list" else ()
+            bufs = [np.empty((cap,) + tail, dtype=dtype) for cap in caps]
+            # validity only for a column that has a null somewhere in the
+            # group: padding and unmasked pieces read as valid
+            valid = None
+            if any(nm is not None for fp in files
+                   for nm in fp.nulls[f.name]):
+                valid = [np.ones(cap, dtype=bool) for cap in caps]
+            remaps: List[Optional[np.ndarray]] = [None] * len(files)
+            union = None
             if f.dtype.kind == "utf8":
-                vals = remaps[f.name][pi]
-            else:
-                vals = arrays[f.name].astype(f.dtype.device_dtype())
-            vals = vals.astype(f.dtype.device_dtype())
-            # pad along the row axis only (list columns are 2-D)
-            pad = np.zeros((cap - n,) + vals.shape[1:],
-                           dtype=f.dtype.device_dtype())
-            vals = np.concatenate([vals, pad])
-            nm = nulls.get(f.name)
-            validity = None
-            if nm is not None and nm.any():
-                v = np.ones(cap, dtype=bool)
-                v[:n] = ~nm
-                validity = jnp.asarray(v)
-            cols.append(
-                Column(jnp.asarray(vals), f.dtype, validity,
-                       union_dicts.get(f.name))
-            )
-        sel = np.zeros(cap, dtype=bool)
-        sel[:n] = True
-        out.append(
-            ColumnBatch(schema, cols, jnp.asarray(sel),
-                        jnp.asarray(np.int32(n)))
-        )
-    return out
+                union, remaps = unify_dictionaries(
+                    [fp.dicts[f.name] for fp in files])
+            # per-column cancellation: the placement copies the group's
+            # bytes, real work a fired token must be able to stop
+            check_cancel()
+            _fill(bufs, sizes, [
+                piece if remap is None else remap[piece]
+                for fp, remap in zip(files, remaps)
+                for piece in fp.values[f.name]])
+            if valid is not None:
+                _fill(valid, sizes, [
+                    np.ones(len(piece), dtype=bool) if nm is None else ~nm
+                    for fp in files
+                    for piece, nm in zip(fp.values[f.name],
+                                         fp.nulls[f.name])])
+            for buf, n in zip(bufs, sizes):
+                buf[n:] = 0
+            placed.append((bufs, valid))
+            dicts.append(union)
+        # the group as one tree of host arrays, a batch: (its columns'
+        # (values, validity or None), selection, row count)
+        host = []
+        for b, (cap, n) in enumerate(zip(caps, sizes)):
+            sel = np.zeros(cap, dtype=bool)
+            sel[:n] = True
+            host.append(([(bufs[b], None if valid is None else valid[b])
+                          for bufs, valid in placed], sel, np.int32(n)))
+        dev = jax.device_put(host)
+    return [
+        ColumnBatch(schema,
+                    [Column(values, f.dtype, validity, d)
+                     for (values, validity), f, d
+                     in zip(cols, schema.fields, dicts)],
+                    sel, n)
+        for cols, sel, n in dev
+    ], len(jax.tree_util.tree_leaves(host))

@@ -63,6 +63,8 @@ OPERATOR_METRICS = {
     "spilled_bytes": ("counter", "fetched shuffle chunk bytes diverted "
                                  "to disk past the memory budget "
                                  "watermark"),
+    "uploads": ("counter", "arrays a shuffle reader handed to the "
+                           "device, in one call a group"),
     "bytes_written": ("counter", "partition/shuffle output bytes"),
     "elapsed_write": ("timer", "partition IPC write time"),
     "shuffle_fan_out": ("counter", "destinations a shuffling task wrote "

@@ -203,10 +203,11 @@ class ShuffleReaderExec(PhysicalPlan):
 
     def _load_location(self, loc: PartitionLocation):
         """Fetch+decode ONE shuffle file (local filesystem or data-plane
-        socket). Runs on ingest pool workers when a group has several
+        socket) to its pieces; returns (pieces, read from this host's
+        disk?). Runs on ingest pool workers when a group has several
         producers — the fetches overlap instead of serializing one
         network round-trip per producer. Local reads decode the
-        memory-mapped stream file incrementally; remote fetches stream
+        memory-mapped stream file to views; remote fetches stream
         bounded chunks through the governed ChunkBuffer (disk spill past
         the budget watermark). Metric increments from worker threads
         ride the usual benign-race policy."""
@@ -216,8 +217,7 @@ class ShuffleReaderExec(PhysicalPlan):
         if not self.FORCE_REMOTE and loc.path:
             try:
                 size = os.path.getsize(loc.path)
-                _, arrays, nulls, dicts, _ = \
-                    ipc.read_partition_arrays(loc.path)
+                pieces = ipc.read_partition_pieces(loc.path)
             except FileNotFoundError:
                 # not a file of this host, or one that went away under
                 # the read (its executor was lost and its work_dir with
@@ -228,8 +228,8 @@ class ShuffleReaderExec(PhysicalPlan):
             else:
                 m.add_counter("bytes_read", size)
                 m.add_counter("local_reads")
-                return arrays, nulls, dicts
-        return self._fetch_with_retry(loc)
+                return pieces, True
+        return self._fetch_with_retry(loc), False
 
     def _load_group(self, q: int) -> List[ColumnBatch]:
         """Fetch only THIS output partition's files (a consumer task reads
@@ -248,21 +248,23 @@ class ShuffleReaderExec(PhysicalPlan):
             from ..ingest import parallel_map
             from ..observability.tracing import trace_span
 
-            # decode and the ENQUEUE of the upload: the copy's completion
-            # is not waited for here
+            # decode (views of the files), ONE placement of the group's
+            # rows and the ENQUEUE of its one upload: the copy's
+            # completion is not waited for here
             group = self._groups[q]
             with trace_span("shuffle.read", pieces=len(group)) as span:
-                parts = parallel_map(self._load_location, group)
-                batches = ipc.batches_from_parts(self._schema, parts)
-                columns = [list(arrays.values()) for arrays, _, _ in parts]
+                loaded = parallel_map(self._load_location, group)
+                files = [fp for fp, _ in loaded]
+                batches, uploads = ipc.batches_from_pieces(
+                    self._schema, files)
+                self.metrics().add_counter("uploads", uploads)
                 span.attrs.update(
-                    rows=sum(len(c[0]) for c in columns if c),
-                    bytes=sum(int(a.nbytes) for c in columns for a in c),
+                    rows=sum(fp.rows for fp in files),
+                    bytes=sum(fp.nbytes for fp in files),
                     capacity=sum(b.capacity for b in batches),
+                    batches=len(batches), uploads=uploads,
                     # pieces that are files of this host, read directly
-                    local=0 if self.FORCE_REMOTE else sum(
-                        1 for l in group
-                        if l.path and os.path.exists(l.path)))
+                    local=sum(local for _, local in loaded))
             self._cache[q] = batches
             return batches
 
@@ -379,15 +381,14 @@ class ShuffleReaderExec(PhysicalPlan):
                 fault_point("shuffle.stream.chunk", stage=loc.stage_id,
                             partition=loc.partition_id, attempt=attempt)
                 buf.put(chunk)
-            _, arrays, nulls, dicts, _ = \
-                ipc.read_partition_arrays_from_chunks(buf.chunks())
+            pieces = ipc.read_partition_pieces_from_chunks(buf.chunks())
         finally:
             buf.close()
         m.add_counter("bytes_read", buf.total_bytes)
         m.add_counter("remote_fetches")
         if buf.spilled_bytes:
             m.add_counter("spilled_bytes", buf.spilled_bytes)
-        return arrays, nulls, dicts
+        return pieces
 
     def execute(self, partition: int) -> Iterator[ColumnBatch]:
         batches = self._take_group(partition)

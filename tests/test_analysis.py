@@ -570,18 +570,18 @@ def test_ipc_batch_iter_checks_cancel(tmp_path):
             ipc.read_partition_arrays(path)
 
 
-def test_batches_from_parts_checks_cancel(tmp_path):
-    """io/ipc.py: shuffle-read assembly (pad + H2D per part) stops
-    between parts once cancelled."""
+def test_batches_from_pieces_checks_cancel(tmp_path):
+    """io/ipc.py: shuffle-read assembly (placement + H2D of a group)
+    stops between files once cancelled."""
     s, b = _mkbatch(64)
     path = str(tmp_path / "p" / "data.arrow")
     ipc.write_partition(path, [b])
-    _, arrays, nulls, dicts, _ = ipc.read_partition_arrays(path)
+    fp = ipc.read_partition_pieces(path)
     token = CancelToken()
     token.cancel("test")
     with bind_token(token):
         with pytest.raises(QueryCancelled):
-            ipc.batches_from_parts(s, [(arrays, nulls, dicts)])
+            ipc.batches_from_pieces(s, [fp])
 
 
 def test_dataplane_fetch_checks_cancel(tmp_path):

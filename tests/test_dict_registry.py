@@ -239,8 +239,9 @@ def test_ipc_roundtrip_resolves_to_interned_instance(registry_env,
     ipc.write_partition(path, [b])
     names, arrays, nulls, dicts, kinds = ipc.read_partition_arrays(path)
     assert dicts["k"] is d  # stamp resolved, values never re-hydrated
-    batches = ipc.batches_from_parts(
-        b.schema, [(arrays, nulls, dicts)])
+    fp = ipc.read_partition_pieces(path)
+    assert fp.dicts["k"] is d
+    batches, _ = ipc.batches_from_pieces(b.schema, [fp])
     assert batches[0].column("k").dictionary is d
 
 

@@ -130,7 +130,7 @@ def test_array_scalar_function(tmp_path):
 def test_array_crosses_stage_boundary(tmp_path):
     """List column through an intermediate shuffle stage (ORDER BY forces
     a merge stage, so the array travels via IPC shuffle files and is
-    rebuilt by batches_from_parts — the 2-D padding path)."""
+    rebuilt by batches_from_pieces — the 2-D placement path)."""
     from ballista_tpu.distributed.executor import LocalCluster
 
     p = tmp_path / "n.tbl"

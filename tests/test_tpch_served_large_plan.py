@@ -194,13 +194,37 @@ def test_a_settled_q3_partitions_both_joins_and_coalesces_the_first(served):
         assert len(builds) == rule["to"] + fan_out
         assert all(b["partitioned"] and b["mode"] == "sorted"
                    and b["side"] == "ShuffleReaderExec" for b in builds)
-        # a coalesced reader builds from the pieces of several buckets:
-        # four producers wrote each of the fan-out's buckets
+        # a coalesced reader reads the files of several buckets (four
+        # producers wrote each of the fan-out's buckets, of either side)
+        # and hands them on as ONE batch (ISSUE 45: the group's rows
+        # placed once; a batch a FILE before), so a build is made of one
+        # piece whatever its files
         first_join = [b for b in builds if b["stage"] == rule["stage"]]
         assert len(first_join) == rule["to"]
-        assert sum(b["pieces"] for b in first_join) == 4 * fan_out
-        assert all(b["pieces"] == 4 for b in builds
-                   if b["stage"] != rule["stage"])
+        reads = named(run, "shuffle.read")
+        first_reads = [r for r in reads if r["stage"] == rule["stage"]]
+        assert len(first_reads) == 2 * rule["to"]
+        assert sum(r["pieces"] for r in first_reads) == 2 * 4 * fan_out
+        second_reads = [r for r in reads if r["stage"] == rule["stage"] + 1]
+        assert len(second_reads) == 2 * fan_out
+        assert all(r["pieces"] == 4 for r in second_reads)
+        assert all(b["pieces"] == 1 for b in builds)
+        # the law of a group: ceil(rows / DEFAULT_BATCH_CAPACITY) batches,
+        # their arrays (this query's shuffled columns carry no null: a
+        # batch's columns, its selection and its row count) in one call
+        assert all(r["batches"] == 1 and r["uploads"] >= 3 for r in reads)
+        by_task = Counter(r["task"] for r in first_reads + second_reads)
+        assert set(by_task.values()) == {2}
+        # the second join's build is still ONE lax.sort a task, at the
+        # capacity the parent sorted at: 16,384 slots for some 9,000
+        # lineitem rows a partition (the parent laid its four files'
+        # rungs end to end: 4 x 4,096 in 16 of 18 tasks and 14,336 in
+        # two, a second sort shape the one-pass reader no longer makes)
+        second_join = [b for b in builds if b["stage"] != rule["stage"]]
+        assert len(second_join) == fan_out
+        assert len({b["task"] for b in second_join}) == fan_out
+        assert {b["capacity"] for b in second_join} == {16384}
+        assert all(8000 < b["rows"] < 10000 for b in second_join)
 
 
 @pytest.mark.parametrize("query", ["q3", "q14"])
@@ -272,11 +296,17 @@ def test_a_batch_costs_a_read_of_its_destinations_and_one_of_each_column(
             for op in st["operators"] if op["operator"] == "ShuffleWrite"]
     assert sum(m["shuffle_reads"] for m in rows) == \
         sum(e["reads"] for e in writes)
-    # a query's reads, most of them the shuffle writers' (the stages that
-    # write ONE partition, unshuffled, read a mask and each column too)
+    # the shuffling tasks' reads are the events' sum (the stages that
+    # write ONE partition, unshuffled, read a mask and each column too).
+    # The first join hands its writer one batch a task since ISSUE 45,
+    # where it handed one a producer FILE (12-20 here): its tasks' reads
+    # fell with its batches, 80 -> 4 a task
     shuffling = {e["task"] for e in writes}
     theirs = sum(1 for r in blocked if r.get("task") in shuffling)
-    assert theirs > 0.5 * len(blocked)
+    assert theirs == sum(e["reads"] for e in writes) > 0
+    (rule,) = named(run, "adaptive.rule")
+    first_join = [e for e in writes if e["stage"] == rule["stage"]]
+    assert first_join and all(e["batches"] == 1 for e in first_join)
 
 
 def test_no_report_sat_out_a_wait(served):
